@@ -818,7 +818,7 @@ def bench_calib_shard():
         return
     _write_bench_db({"calib_shard": rec})
     row("calib_shard", rec["sharded_s"] * 1e6,
-        f"single={rec['single_device_s']*1e3:.0f}ms "
+        f"CPU run: single={rec['single_device_s']*1e3:.0f}ms "
         f"sharded={rec['sharded_s']*1e3:.0f}ms "
         f"speedup={rec['speedup']:.2f}x relerr={rec['hessian_rel_err']:.1e}")
 
@@ -1006,7 +1006,7 @@ def bench_gradual_family():
     _write_bench_db(
         {("gradual_family_smoke" if _SMOKE else "gradual_family"): rec})
     sp = shard.get("speedup")
-    shard_txt = f"shard_speedup={sp:.2f}x" if sp is not None \
+    shard_txt = f"shard_speedup(CPU run)={sp:.2f}x" if sp is not None \
         else "shard FAILED"
     row("gradual_family", t_full * 1e6,
         f"overlap={t_full:.1f}s serial={t_serial:.1f}s "
@@ -1205,7 +1205,7 @@ def bench_family_sharded():
         row("family_sharded", 0.0, f"FAILED {out['error'][-80:]}")
         return
     row("family_sharded", out["parallel_overlap_s"] * 1e6,
-        f"serial={out['serial_single_device_s']:.1f}s "
+        f"CPU run: serial={out['serial_single_device_s']:.1f}s "
         f"parallel={out['parallel_overlap_s']:.1f}s "
         f"speedup={out['speedup']:.2f}x "
         f"bitident={out['bit_identical']}")
@@ -1492,6 +1492,8 @@ def main(argv=None) -> None:
         raise SystemExit(f"unknown benchmark(s) {unknown}; "
                          f"available: {sorted(BENCHES)}")
     selected = names or list(BENCHES)
+    from repro.runtime.device import use_compile_cache
+    use_compile_cache()
     print("name,us_per_call,derived")
     import contextlib
 

@@ -22,11 +22,13 @@ from repro.core.pipeline import gradual_prune
 from repro.data import calibration_batches, synthetic_stream
 from repro.models import model_init
 from repro.runtime.costmodel import InferenceEnv
+from repro.runtime.device import use_compile_cache
 from repro.train.trainer import Trainer
 from repro.train.train_step import make_train_state
 
 
 def main():
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--full", action="store_true",
                     help="~100M params, 200 pretrain steps")

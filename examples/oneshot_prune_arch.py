@@ -20,9 +20,11 @@ from repro.core.structures import registry
 from repro.data import calibration_batches
 from repro.models import model_init
 from repro.runtime.costmodel import InferenceEnv
+from repro.runtime.device import use_compile_cache
 
 
 def main():
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen2-72b", choices=ASSIGNED)
     ap.add_argument("--target", type=float, default=2.0)

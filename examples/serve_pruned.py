@@ -19,10 +19,12 @@ from repro.core.oneshot import oneshot_prune
 from repro.data import calibration_batches, synthetic_stream
 from repro.models import generate, model_init, serve_prefill, serve_step
 from repro.runtime.costmodel import InferenceEnv
+from repro.runtime.device import use_compile_cache
 from repro.train.train_step import make_train_state, make_train_step
 
 
 def main():
+    use_compile_cache()
     cfg = GPT2_SMALL.replace(name="gpt2-tiny", num_layers=4, d_model=96,
                              d_ff=384, num_heads=6, num_kv_heads=6,
                              head_dim=16, vocab_size=384, dtype="float32")

@@ -34,7 +34,7 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 import numpy as np
 
 import jax
-from jax import core as jcore
+from jax.extend import core as jcore
 
 from repro.analysis.findings import Finding
 from repro.runtime.hlo_analysis import analyze_hlo_text

@@ -42,7 +42,7 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-from jax import core as jcore
+from jax.extend import core as jcore
 
 from repro.analysis.findings import Finding
 from repro.analysis.jaxpr_audit import iter_eqns
@@ -214,9 +214,9 @@ def _pallas_eqns(spec: KernelSpec):
 
 def _check_one_mapping(spec: KernelSpec, grid, bm) -> List[Finding]:
     findings = []
-    arr_shape = tuple(bm.array_shape_dtype.shape)
-    block = tuple(d if d is not None else arr_shape[i]
-                  for i, d in enumerate(bm.block_shape))
+    arr_shape = tuple(bm.array_aval.shape)
+    # Blocked/Element carry block_size; a Squeezed dim is one element
+    block = tuple(getattr(d, "block_size", 1) for d in bm.block_shape)
     # tile alignment (minor two dims)
     for off, tile in ((1, TILE_MINOR), (2, TILE_SECOND_MINOR)):
         if len(block) >= off:
@@ -241,8 +241,7 @@ def _check_one_mapping(spec: KernelSpec, grid, bm) -> List[Finding]:
     starts: List[set] = [set() for _ in arr_shape]
     import itertools
     for point in itertools.product(*(range(g) for g in grid)):
-        idx = jcore.eval_jaxpr(cj.jaxpr, cj.consts,
-                               *(jnp.int32(p) for p in point))
+        idx = jcore.jaxpr_as_fun(cj)(*(jnp.int32(p) for p in point))
         for d, (i, b) in enumerate(zip(idx, block)):
             starts[d].add(int(i) * b)
     for d, (a, b) in enumerate(zip(arr_shape, block)):

@@ -28,6 +28,7 @@ import numpy as np
 from ..robustness import faults as _faults
 from ..robustness.healing import damp_schedule
 from ..robustness.report import current_report
+from ..runtime.device import free_device_bytes
 from .obs import (build_hessian, module_drop_error, module_drop_errors,
                   prune_structured, prune_structured_batched,
                   prune_structured_batched_compact, prune_structured_compact,
@@ -38,6 +39,36 @@ from .structures import (UNITS, PrunableModule, get_matrix, level_grid,
 # damping-escalation ladder: retries beyond the caller's damp, each one
 # decade up (damp * 10**k) — bounded so a hopeless Hessian fails loudly
 DAMP_RETRIES = 4
+
+# share of the device's free bytes one vmapped Algorithm-1 chunk may take
+DEVICE_MEM_FRACTION = 0.8
+
+
+def module_bytes(d_in: int, d_out: int, n_levels: int) -> int:
+    """Device bytes one module adds to a vmapped Algorithm-1 chunk: fp32
+    W and Hinv in, carried and updated (x3), and the (n_levels + 1)-deep
+    fp32 snapshot stack carried through the loop and returned (x4). An
+    upper bound on what the TPU compiler reports for GPT2-small's FFN
+    (1.71 vs 1.62 GiB per module, tests/test_tpu_compile.py)."""
+    return 4 * (3 * d_in * (d_in + d_out)
+                + 4 * (n_levels + 1) * d_in * d_out)
+
+
+def chunk_size(n_mods: int, per_module: int, max_batch: int,
+               n_shards: int = 1, free: Optional[int] = None) -> int:
+    """Modules per vmapped chunk: as many as ``DEVICE_MEM_FRACTION`` of
+    the free device bytes (``free``, read from the device when None)
+    holds on each of ``n_shards`` devices, at most ``max_batch``, spread
+    evenly so that every chunk of a group compiles to one shape. Without
+    a reported device limit (the CPU backend) only ``max_batch`` binds."""
+    if free is None:
+        free = free_device_bytes()
+    cap = max_batch
+    if free is not None:
+        fit = int(DEVICE_MEM_FRACTION * free) // max(per_module, 1)
+        cap = max(1, min(max_batch, fit * n_shards))
+    n_chunks = -(-n_mods // cap)
+    return -(-n_mods // n_chunks)
 
 
 def _prune_healed(prune_fn, Ws, Hraw, *, group_size, n_remove, levels,
@@ -58,6 +89,13 @@ def _prune_healed(prune_fn, Ws, Hraw, *, group_size, n_remove, levels,
 
     Rung 0 is bit-identical to the un-healed code: same damp, and the
     finite check reads values that were going to be fetched anyway.
+
+    The inverse is taken on the host in float64 and Algorithm 1 runs at
+    full fp32 matmul precision: on a v5e the TPU's own fp32 inverse of
+    GPT2-small's attention Hessians was off by up to 8e-3 relative, and
+    the last removal steps of those modules went non-finite at the
+    requested damping; from the float64 inverse every module stayed
+    finite.
     """
     rep = current_report()
     uk = use_kernel
@@ -65,12 +103,15 @@ def _prune_healed(prune_fn, Ws, Hraw, *, group_size, n_remove, levels,
     attempt = 0
     while True:
         H = build_hessian(Hraw, rungs[attempt])
-        Hinv = jnp.linalg.inv(H)
+        # sync: once per chunk per damping rung, outside the removal loop
+        Hinv = jnp.asarray(np.linalg.inv(np.asarray(H, np.float64)),
+                           jnp.float32)
         Hinv = _faults.poison_array("obs.cholesky", Hinv)
         try:
-            res = prune_fn(Ws, Hinv, group_size=group_size,
-                           n_remove=n_remove, levels=levels,
-                           use_kernel=uk)
+            with jax.default_matmul_precision("highest"):
+                res = prune_fn(Ws, Hinv, group_size=group_size,
+                               n_remove=n_remove, levels=levels,
+                               use_kernel=uk)
             # sync: DB materialization — the float16 snapshots are
             # fetched exactly once per chunk per damping rung, and the
             # finite check below reads values headed to host anyway
@@ -166,9 +207,10 @@ def build_database(cfg, params, hessians: Dict[str, jnp.ndarray], *,
                    batched: bool = True, use_kernel: bool = False,
                    compact: bool = False, max_batch: int = 16,
                    mesh=None, shard_axes=None) -> Dict[str, ModuleDB]:
-    """max_batch bounds how many modules of one shape group run under a
-    single vmap, capping device memory at max_batch x (Hinv + snapshot
-    stack) instead of the whole group (L, or L*E for MoE).
+    """Modules of one shape group run under a single vmap in chunks sized
+    from the device's free memory and ``module_bytes`` (`chunk_size`),
+    at most ``max_batch`` per chunk, instead of the whole group (L, or
+    L*E for MoE) at once.
 
     ``compact=True`` routes Algorithm 1 through the live-set-compacted
     core (obs.prune_structured[_batched]_compact): identical pruning
@@ -201,9 +243,13 @@ def build_database(cfg, params, hessians: Dict[str, jnp.ndarray], *,
             prune_structured_sharded, mesh=mesh, axes=shard_axes,
             compact=compact)
         for key, gmods in group_modules(cfg, params, mods):
-            gs, n, _, levels = key
-            for lo in range(0, len(gmods), max_batch):
-                chunk = gmods[lo:lo + max_batch]
+            gs, n, d_out, levels = key
+            step = chunk_size(len(gmods),
+                              module_bytes(gmods[0].d_in, d_out,
+                                           len(levels)),
+                              max_batch, n_shards)
+            for lo in range(0, len(gmods), step):
+                chunk = gmods[lo:lo + step]
                 Ws = jnp.stack([get_matrix(cfg, params, m)
                                 .astype(jnp.float32) for m in chunk])
                 Hraw = jnp.stack([jnp.asarray(hessians[m.name],
@@ -362,6 +408,11 @@ class SnapshotCache:
                                       e["layer_idx"])
             layers[grp][leaf_key] = leaf
         return new
+
+    def stitched_bytes(self, params) -> int:
+        """Bytes of the leaves `apply_batched` copies per candidate."""
+        paths = {_PARAM_PATH[e["kind"]] for e in self._groups.values()}
+        return sum(int(params["layers"][g][k].nbytes) for g, k in paths)
 
     def batch_axes(self, params):
         """``jax.vmap`` in_axes tree for an `apply_batched` result: 0 on

@@ -36,7 +36,6 @@ from typing import Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
@@ -139,9 +138,9 @@ def _fused_step_sharded(cfg, use_kernel: bool, mesh, data_axes: Tuple[str]):
                 + jnp.where(ok, jax.lax.psum(n, data_axes), 0.0)
         return new_h, new_c, ok
 
-    f = shard_map(_step, mesh=mesh,
-                  in_specs=(P(), P(), P(), batch_spec, batch_spec, P()),
-                  out_specs=(P(), P(), P()), check_rep=False)
+    f = jax.shard_map(_step, mesh=mesh,
+                      in_specs=(P(), P(), P(), batch_spec, batch_spec, P()),
+                      out_specs=(P(), P(), P()), check_vma=False)
     return jax.jit(f, donate_argnums=_donate())
 
 
@@ -168,7 +167,8 @@ def collect_hessians(cfg, params, batches: List[Dict], *,
 
     With a mesh (explicit or from the activation context) whose data-axis
     size divides every batch, calibration runs data-parallel; otherwise it
-    falls back to the single-device reference path.
+    falls back to the single-device reference path, and a multi-device
+    mesh that fell back is counted as a ``calib.sharded`` demotion.
     """
     if not batches:
         raise ValueError("collect_hessians needs at least one calibration "
@@ -178,6 +178,10 @@ def collect_hessians(cfg, params, batches: List[Dict], *,
     ndev = axis_size(mesh, data_axes) if mesh is not None else 1
     sharded = ndev > 1 and all(
         b["tokens"].shape[0] % ndev == 0 for b in batches)
+    if ndev > 1 and not sharded:
+        current_report().count("demotions", "calib.sharded")
+        print(f"[robustness] calib: a batch does not divide the {ndev} "
+              f"data shards; calibrating on one device")
 
     hessians = {m.name: jnp.zeros((m.d_in, m.d_in), jnp.float32)
                 for m in mods}

@@ -413,7 +413,6 @@ def _sharded_prune_jit(mesh, axes: Tuple[str, ...], group_size: int,
     Algorithm-1 core over the leading module axis, with ragged module
     counts padded up to the device count inside the jit (padded lanes
     replicate module 0 and are sliced off after the gather)."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from ..distributed.sharding import axis_size, pad_leading
@@ -437,8 +436,8 @@ def _sharded_prune_jit(mesh, axes: Tuple[str, ...], group_size: int,
 
     spec = P(axes)
     ndev = axis_size(mesh, axes)
-    f = shard_map(_body, mesh=mesh, in_specs=(spec, spec),
-                  out_specs=(spec, spec, spec), check_rep=False)
+    f = jax.shard_map(_body, mesh=mesh, in_specs=(spec, spec),
+                      out_specs=(spec, spec, spec), check_vma=False)
 
     def _padded(W, Hinv):
         b = W.shape[0]

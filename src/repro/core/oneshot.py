@@ -22,8 +22,9 @@ import numpy as np
 from ..models.model import loss_fn
 from ..robustness import faults as _faults
 from ..runtime.costmodel import InferenceEnv
-from .database import (ModuleDB, SnapshotCache, apply_assignment,
-                       build_database)
+from ..runtime.device import free_device_bytes
+from .database import (DEVICE_MEM_FRACTION, ModuleDB, SnapshotCache,
+                       apply_assignment, build_database)
 from .hessian import collect_hessians
 from .latency import LatencyTable, build_table
 from .spdy import SearchResult, search_family
@@ -48,6 +49,7 @@ class OneShotResult:
     db: Dict[str, ModuleDB]
     dense_runtime: float
     dense_loss: float
+    hessians: Dict[str, jnp.ndarray]
 
 
 def _stack_batch_groups(batches):
@@ -112,6 +114,14 @@ def batched_calib_loss_fn(cfg, batches, axes):
     return fn
 
 
+def candidate_bytes(cfg, params, cache: SnapshotCache, stacked) -> int:
+    """Device bytes one candidate adds to a population eval: its stitched
+    copy of the pruned leaves, plus the fp32 logits of one calibration
+    batch and loss temporaries of the same size (x4)."""
+    tokens = max(int(np.prod(g["tokens"].shape[1:])) for g in stacked)
+    return cache.stitched_bytes(params) + 16 * tokens * cfg.vocab_size
+
+
 def make_batched_eval(cfg, params, cache: SnapshotCache, batches,
                       chunk: int = 32, loss_b=None
                       ) -> Callable[[List[Dict[str, int]]], np.ndarray]:
@@ -119,9 +129,10 @@ def make_batched_eval(cfg, params, cache: SnapshotCache, batches,
     device-side (`apply_batched`) and score them with one vmapped loss —
     a single host sync per search round.
 
-    Work is chunked at ``chunk`` candidates (bounding device memory for
-    big populations) and padded to power-of-two sizes within a chunk, so
-    the vmapped jit compiles a handful of shapes instead of one per dedup
+    Work is chunked at ``chunk`` candidates, lowered on first use to the
+    largest power of two whose `candidate_bytes` fit the device's free
+    memory, and padded to power-of-two sizes within a chunk, so the
+    vmapped jit compiles a handful of shapes instead of one per dedup
     count.  Pass ``loss_b`` (a `batched_calib_loss_fn` result) to reuse
     one compiled loss across scorers whose cfg/batches/axes agree — e.g.
     `gradual_prune` rebuilding the cache per target.
@@ -138,6 +149,17 @@ def make_batched_eval(cfg, params, cache: SnapshotCache, batches,
         loss_b = batched_calib_loss_fn(cfg, batches,
                                        cache.batch_axes(params))
     _replicas: Dict[object, tuple] = {}
+    _chunks: Dict[object, int] = {}
+
+    def _chunk(device):
+        if device not in _chunks:
+            free = free_device_bytes(device)
+            fit = chunk
+            if free is not None:
+                per = candidate_bytes(cfg, params, cache, loss_b._stacked)
+                fit = max(1, int(DEVICE_MEM_FRACTION * free) // per)
+            _chunks[device] = min(chunk, 1 << (fit.bit_length() - 1))
+        return _chunks[device]
 
     def _replica(device):
         if device is None:
@@ -153,12 +175,13 @@ def make_batched_eval(cfg, params, cache: SnapshotCache, batches,
         # injected OOM/failure point for the spdy degradation ladder
         _faults.hit("spdy.batched_eval")
         p, c, stacked = _replica(device)
+        step = _chunk(device)
         n = len(assignments)
         out = np.empty((n,), np.float64)
-        for lo in range(0, n, chunk):
-            part = assignments[lo:lo + chunk]
+        for lo in range(0, n, step):
+            part = assignments[lo:lo + step]
             k = len(part)
-            padded = min(1 << (k - 1).bit_length(), chunk)
+            padded = min(1 << (k - 1).bit_length(), step)
             part = part + [part[0]] * (padded - k)
             pb = c.apply_batched(p, part)
             # sync: THE one host pull per SPDY eval round — the invariant
@@ -240,4 +263,5 @@ def oneshot_prune(cfg, params, calib_batches: List[dict],
                   f"loss {variants[t].calib_loss:.4f} "
                   f"(dense {dense_loss:.4f})")
     return OneShotResult(variants=variants, table=table, db=db,
-                         dense_runtime=dense_rt, dense_loss=dense_loss)
+                         dense_runtime=dense_rt, dense_loss=dense_loss,
+                         hessians=hessians)

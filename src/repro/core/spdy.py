@@ -364,6 +364,11 @@ def search_family(db: Dict[str, ModuleDB], table: LatencyTable,
                                               for key in new_keys]),
                                 np.float64)
                     except Exception as e:
+                        # out of device memory is a sizing fault the
+                        # population chunking must prevent: fail the run
+                        # instead of demoting to the serial path
+                        if "RESOURCE_EXHAUSTED" in str(e):
+                            raise
                         rep.trip("spdy.batched_eval",
                                  reason=f"batched eval failed: {e!r}")
                 if vals is None:

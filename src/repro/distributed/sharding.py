@@ -20,20 +20,9 @@ from ..configs.base import MeshConfig
 
 
 def make_mesh(shape, axes) -> Mesh:
-    """``jax.make_mesh`` with explicit-Auto axis types where supported.
-
-    ``axis_types`` / ``jax.sharding.AxisType`` only exist on newer jax;
-    older releases treat every axis as Auto already, so omitting the
-    argument there is semantically identical.
-    """
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(shape, axes)
-    try:
-        return jax.make_mesh(
-            shape, axes, axis_types=(axis_type.Auto,) * len(axes))
-    except TypeError:  # AxisType exists but make_mesh lacks the kwarg
-        return jax.make_mesh(shape, axes)
+    """``jax.make_mesh`` with every axis of type Auto."""
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_mesh_from_config(mc: MeshConfig) -> Mesh:

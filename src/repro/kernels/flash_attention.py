@@ -75,7 +75,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
 
 def flash_attention_kernel(q, k, v, *, causal: bool = True, window: int = 0,
                            block_q: int = 128, block_k: int = 128,
-                           q_offset: int = None, interpret: bool = True):
+                           q_offset: int = None, interpret: bool):
     """q: (BH, Sq, D), k/v: (BHKV, Sk, D). BH = BHKV * group. fp32/bf16."""
     bh, sq, d = q.shape
     bhkv, sk, _ = k.shape

@@ -55,7 +55,7 @@ def _xtx_acc_kernel(xi_ref, xj_ref, a_ref, o_ref, acc_ref, *, nn: int):
 
 
 def hessian_accum_kernel(x: jnp.ndarray, acc=None, *, block_d: int = 256,
-                         block_n: int = 512, interpret: bool = True
+                         block_n: int = 512, interpret: bool
                          ) -> jnp.ndarray:
     """(N, D) -> (D, D) fp32 = X^T X, or ``acc + X^T X`` when ``acc`` is a
     (D, D) running Hessian (the calibration streaming update)."""

@@ -14,8 +14,13 @@ mask, and writes the strips back — one read + one write of each operand,
 no intermediates.
 
 The grid is 1-D over row strips; the right-hand factors (gs rows) and the
-column mask are broadcast to every step, so VMEM holds ~2 strips + the
-gs-row factors (block_d=256, d=4096 fp32 => ~8.5 MB, within a v5e core).
+column mask are broadcast to every step. The pipeline double-buffers both
+input strips and both output strips, so one step holds
+``2 x 2 x block_d x (d_out + d) x 4`` bytes plus the gs-row factors: at
+block_d=256 and d = d_out = 4096 fp32 that is 32 MiB, twice the 16 MiB of
+scoped VMEM the TPU compiler grants a kernel by default. ``block_d`` is
+therefore capped from ``d`` (``_strip_rows``) so the strips stay within
+``VMEM_BUDGET``.
 """
 from __future__ import annotations
 
@@ -24,6 +29,25 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+# bytes of the default 16 MiB scoped VMEM given to the four row strips;
+# the rest is headroom for the gs-row factors and the column mask
+VMEM_BUDGET = 12 * 2**20
+
+
+def _strip_rows(d_in: int, d_out: int, block_d: int) -> int:
+    """Row-strip height: at most ``block_d``, small enough that the
+    double-buffered W and Hinv strips (in and out) fit ``VMEM_BUDGET``,
+    a multiple of 8 (the sublane tile), and a divisor of ``d_in`` where
+    one exists so that Hinv needs no padding."""
+    fit = VMEM_BUDGET // (16 * (d_in + d_out))
+    rows = max(8, min(block_d, fit) // 8 * 8)
+    if rows >= d_in:
+        return d_in
+    for r in range(rows, 7, -8):
+        if d_in % r == 0:
+            return r
+    return rows
 
 
 def _downdate_kernel(w_ref, h_ref, a_ref, kw_ref, kh_ref, krow_ref,
@@ -42,7 +66,7 @@ def _downdate_kernel(w_ref, h_ref, a_ref, kw_ref, kh_ref, krow_ref,
 def obs_downdate_kernel(W: jnp.ndarray, Hinv: jnp.ndarray,
                         HcolS: jnp.ndarray, KsWS: jnp.ndarray,
                         KsHcolT: jnp.ndarray, keep: jnp.ndarray, *,
-                        block_d: int = 256, interpret: bool = True,
+                        block_d: int = 256, interpret: bool,
                         d_live: int | None = None):
     """(W, Hinv, HcolS, KsWS, KsHcolT, keep) -> (W_new, Hinv_new).
 
@@ -63,7 +87,7 @@ def obs_downdate_kernel(W: jnp.ndarray, Hinv: jnp.ndarray,
             W, Hinv, HcolS, KsWS, KsHcolT, keep, d_live)
     d_in, d_out = W.shape
     gs = HcolS.shape[1]
-    block_d = min(block_d, d_in)
+    block_d = _strip_rows(d_in, d_out, block_d)
     nb = pl.cdiv(d_in, block_d)
     dp = nb * block_d
     pad = dp - d_in

@@ -1,7 +1,8 @@
 """Public wrappers around the Pallas kernels, with a guarded fallback.
 
-On CPU (this container) kernels run in interpret mode; on TPU set
-``interpret=False`` (the default flips on backend detection).
+``interpret=None`` resolves from the backend (``_default_interpret``):
+compiled on TPU, interpret mode elsewhere. The raw kernel functions take
+``interpret`` as a required argument, so only these wrappers decide it.
 
 Graceful degradation: every public op routes through ``_run_guarded`` —
 a kernel failure (trace/compile error, or an injected ``kernel.pallas``

@@ -3,7 +3,7 @@
 Per (batch, chunk, head-block) grid step the kernel computes, entirely in
 VMEM:
   scores  = C_chunk @ B_chunk^T                       (Q, Q)  MXU
-  L       = exp(segsum(dA)) (causal decay matrix)     (Q, Q, hb)
+  L       = exp(segsum(dA)) (causal decay matrix)     (Q, Q)  per head
   y_diag  = (scores * L) @ (x*dt)                     per head
   states  = (B * decay_to_end)^T @ (x*dt)             chunk -> state
 The O(Q^2) decay/score tiles never reach HBM. The (cheap, sequential)
@@ -16,47 +16,50 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
 
-def _ssd_kernel(xdt_ref, dacs_ref, b_ref, c_ref, y_ref, st_ref, *,
-                q: int, hb: int):
-    # blocks: xdt (1,1,Q,hb,P) dacs (1,1,Q,hb) b/c (1,1,Q,N)
-    xdt = xdt_ref[0, 0].astype(jnp.float32)        # (Q, hb, P)
-    dacs = dacs_ref[0, 0].astype(jnp.float32)      # (Q, hb)
+def _ssd_kernel(xdt_ref, dcol_ref, drow_ref, b_ref, c_ref, y_ref, st_ref,
+                *, q: int, hb: int):
+    # blocks: xdt/y (1,1,hb,Q,P)  dcol (1,1,hb,Q,1)  drow (1,1,hb,1,Q)
+    #         b/c (1,1,Q,N)  st (1,1,hb,P,N) — every block's minor two
+    #         dims are (Q or 1 or P, N or P or Q): whole array dims or
+    #         tile multiples, never a slice of the head axis
     B = b_ref[0, 0].astype(jnp.float32)            # (Q, N)
     C = c_ref[0, 0].astype(jnp.float32)            # (Q, N)
-
     scores = jax.lax.dot_general(C, B, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)  # (Q,Q)
-    # causal decay matrix per head: L[i,j,h] = exp(dacs[i,h] - dacs[j,h]) i>=j
-    diff = dacs[:, None, :] - dacs[None, :, :]     # (Q, Q, hb)
     ii = jax.lax.broadcasted_iota(jnp.int32, (q, q), 0)
     jj = jax.lax.broadcasted_iota(jnp.int32, (q, q), 1)
-    tril = (jj <= ii)[:, :, None]
-    L = jnp.exp(jnp.where(tril, diff, NEG_INF))    # (Q, Q, hb)
-    M = scores[:, :, None] * L                     # (Q, Q, hb)
-    # y_diag[i,h,p] = sum_j M[i,j,h] xdt[j,h,p]
-    y = jnp.einsum("ijh,jhp->ihp", M, xdt)
-    y_ref[0, 0] = y.astype(y_ref.dtype)
-
-    # chunk state: sum_j exp(dacs[-1,h]-dacs[j,h]) B[j,n] xdt[j,h,p]
-    decay_end = jnp.exp(dacs[-1:, :] - dacs)       # (Q, hb)
-    xw = xdt * decay_end[:, :, None]               # (Q, hb, P)
-    st = jnp.einsum("qn,qhp->hpn", B, xw)
-    st_ref[0, 0] = st.astype(st_ref.dtype)
+    tril = jj <= ii
+    for h in range(hb):
+        xdt = xdt_ref[0, 0, h].astype(jnp.float32)     # (Q, P)
+        dcol = dcol_ref[0, 0, h].astype(jnp.float32)   # (Q, 1)
+        drow = drow_ref[0, 0, h].astype(jnp.float32)   # (1, Q)
+        # causal decay L[i,j] = exp(dacs[i] - dacs[j]) for i >= j
+        L = jnp.exp(jnp.where(tril, dcol - drow, NEG_INF))
+        y = jnp.dot(scores * L, xdt, preferred_element_type=jnp.float32)
+        y_ref[0, 0, h] = y.astype(y_ref.dtype)
+        # chunk state: sum_j exp(dacs[-1] - dacs[j]) xdt[j,p] B[j,n]
+        xw = xdt * jnp.exp(dcol[q - 1:, :] - dcol)     # (Q, P)
+        st = jax.lax.dot_general(xw, B, (((0,), (0,)), ((), ())),
+                                 preferred_element_type=jnp.float32)
+        st_ref[0, 0, h] = st.astype(st_ref.dtype)     # (P, N)
 
 
 def ssd_intra_chunk_kernel(xdt, dacs, B, C, *, head_block: int = 8,
-                           interpret: bool = True):
+                           interpret: bool):
     """Intra-chunk SSD.
 
     xdt:  (b, nc, q, h, p) — dt-scaled inputs
     dacs: (b, nc, q, h)    — cumulative sum of dt*A within chunk
     B, C: (b, nc, q, n)
     Returns (y_diag: (b,nc,q,h,p) fp32, states: (b,nc,h,p,n) fp32).
+
+    The kernel runs head-major: heads move ahead of the chunk positions
+    so that a head block is never the minor dimension of a block (the TPU
+    compiler tiles the minor two dims by (8, 128) or takes them whole).
     """
     b, nc, q, h, p = xdt.shape
     n = B.shape[-1]
@@ -65,24 +68,27 @@ def ssd_intra_chunk_kernel(xdt, dacs, B, C, *, head_block: int = 8,
         hb -= 1
     nh = h // hb
 
+    xh = xdt.transpose(0, 1, 3, 2, 4)                  # (b, nc, h, q, p)
+    dh = dacs.transpose(0, 1, 3, 2)                    # (b, nc, h, q)
     kernel = functools.partial(_ssd_kernel, q=q, hb=hb)
     y, st = pl.pallas_call(
         kernel,
         grid=(b, nc, nh),
         in_specs=[
-            pl.BlockSpec((1, 1, q, hb, p), lambda i, c, j: (i, c, 0, j, 0)),
-            pl.BlockSpec((1, 1, q, hb), lambda i, c, j: (i, c, 0, j)),
+            pl.BlockSpec((1, 1, hb, q, p), lambda i, c, j: (i, c, j, 0, 0)),
+            pl.BlockSpec((1, 1, hb, q, 1), lambda i, c, j: (i, c, j, 0, 0)),
+            pl.BlockSpec((1, 1, hb, 1, q), lambda i, c, j: (i, c, j, 0, 0)),
             pl.BlockSpec((1, 1, q, n), lambda i, c, j: (i, c, 0, 0)),
             pl.BlockSpec((1, 1, q, n), lambda i, c, j: (i, c, 0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, q, hb, p), lambda i, c, j: (i, c, 0, j, 0)),
+            pl.BlockSpec((1, 1, hb, q, p), lambda i, c, j: (i, c, j, 0, 0)),
             pl.BlockSpec((1, 1, hb, p, n), lambda i, c, j: (i, c, j, 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((b, nc, q, h, p), jnp.float32),
+            jax.ShapeDtypeStruct((b, nc, h, q, p), jnp.float32),
             jax.ShapeDtypeStruct((b, nc, h, p, n), jnp.float32),
         ],
         interpret=interpret,
-    )(xdt, dacs, B, C)
-    return y, st
+    )(xh, dh[..., None], dh[..., None, :], B, C)
+    return y.transpose(0, 1, 3, 2, 4), st

@@ -22,6 +22,8 @@ def main(argv=None):
     ap.add_argument("--smoke", action="store_true")
     args = ap.parse_args(argv)
 
+    from ..runtime.device import use_compile_cache
+    use_compile_cache()
     import jax
 
     from ..configs import get_config, smoke_config

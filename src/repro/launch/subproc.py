@@ -24,10 +24,12 @@ def run_forced_devices(script: str, n_devices: int, *,
     """Run ``script`` in a fresh interpreter with ``n_devices`` forced
     host-platform devices; returns the parsed RESULT-line JSON.
 
-    The child's ``XLA_FLAGS`` is overwritten (the forced count must win),
-    ``PYTHONPATH`` is prepended to, not replaced. Raises RuntimeError
-    with stdout/stderr tails on a non-zero exit, a missing RESULT line,
-    or a timeout — the timeout case includes whatever partial output the
+    The child's ``XLA_FLAGS`` is overwritten (the forced count must win)
+    and its ``JAX_PLATFORMS`` is ``cpu``: the forced devices are host
+    devices, and a child must never reach for an accelerator its parent
+    may already hold. ``PYTHONPATH`` is prepended to, not replaced.
+    Raises RuntimeError with stdout/stderr tails on a non-zero exit, a
+    missing RESULT line, or a timeout — the timeout case includes whatever partial output the
     child produced before the kill (a bare TimeoutExpired hid the
     hung child's last prints, which are exactly the debugging signal).
     """
@@ -35,6 +37,7 @@ def run_forced_devices(script: str, n_devices: int, *,
                 "os.environ['XLA_FLAGS'] = "
                 f"'--xla_force_host_platform_device_count={n_devices}'\n")
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = _SRC + os.pathsep + env.get("PYTHONPATH", "") \
         if env.get("PYTHONPATH") else _SRC
     try:

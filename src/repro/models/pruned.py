@@ -45,9 +45,12 @@ class PrunedModel:
 
 
 def _vcfg(cfg, lcfg: PrunedLayer):
-    """Per-layer view config: head counts shrunk to this layer's survivors."""
+    """Per-layer view config: head counts shrunk to this layer's survivors.
+    ``head_dim`` is pinned: a config that derives it as d_model // heads
+    (GPT2-small) would otherwise widen each surviving head."""
     return cfg.replace(num_heads=lcfg.kv_groups * cfg.q_per_kv,
-                       num_kv_heads=lcfg.kv_groups)
+                       num_kv_heads=lcfg.kv_groups,
+                       head_dim=cfg.resolved_head_dim)
 
 
 def _attn_forward(cfg, lcfg: PrunedLayer, lp, x):

@@ -23,8 +23,22 @@ class HardwareSpec:
     op_overhead: float      # seconds per fused op (dispatch/latency floor)
 
 
+# published per-chip peaks (Google Cloud documentation, "TPU v5e")
 TPU_V5E = HardwareSpec("tpu_v5e", peak_flops=197e12, hbm_bw=819e9,
                        ici_bw=50e9, hbm_bytes=16e9, op_overhead=2e-6)
+
+# peaks keyed by ``jax.Device.device_kind`` as JAX reports it
+HARDWARE = {"TPU v5 lite": TPU_V5E}
+
+
+def hardware_for(device_kind: str) -> HardwareSpec:
+    """Peak table entry of a device kind; a kind not in ``HARDWARE`` is an
+    error, never a default."""
+    try:
+        return HARDWARE[device_kind]
+    except KeyError:
+        raise KeyError(f"no peak entry for device kind {device_kind!r}; "
+                       f"known kinds: {sorted(HARDWARE)}") from None
 
 
 @dataclass(frozen=True)

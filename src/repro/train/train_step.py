@@ -31,7 +31,6 @@ from typing import Any, Dict, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
@@ -171,11 +170,11 @@ def make_train_step(cfg, tcfg: TrainConfig, *, teacher_params=None,
             aux = jax.tree.map(lambda a: jax.lax.pmean(a, data_axes), aux)
             return aux, grads, jax.tree.map(lambda e: e[None], new_ef)
 
-        sharded_grads = shard_map(
+        sharded_grads = jax.shard_map(
             _sharded_grads, mesh=mesh,
             in_specs=(P(), P(data_axes), P(data_axes)),
             out_specs=(P(), P(), P(data_axes)),
-            check_rep=False)
+            check_vma=False)
 
     def train_step(state: TrainState, batch: Dict):
         params = state.params

@@ -184,14 +184,13 @@ def _mesh1():
 
 
 def test_extra_all_reduce_fails_schedule_budget():
-    from jax.experimental.shard_map import shard_map
     mesh = _mesh1()
 
     def body(x):
         return jax.lax.psum(x, "data")
 
-    bad = jax.jit(shard_map(body, mesh=mesh, in_specs=P("data"),
-                            out_specs=P()))
+    bad = jax.jit(jax.shard_map(body, mesh=mesh, in_specs=P("data"),
+                                out_specs=P()))
     text = bad.trace(jnp.ones((4,), jnp.float32)) \
               .lower().compile().as_text()
     counts, sched = collective_schedule(text, 1)

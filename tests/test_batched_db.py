@@ -9,7 +9,7 @@ import numpy as np
 import pytest
 
 from repro.core.database import (SnapshotCache, apply_assignment,
-                                 build_database, group_modules)
+                                 build_database, chunk_size, group_modules)
 from repro.core.hessian import collect_hessians, xtx
 from repro.core.structures import get_capture, level_grid, registry
 from repro.kernels import ops, ref
@@ -32,6 +32,27 @@ def test_grouping_covers_registry(tiny_cfg, tiny_params):
     # tiny GPT2: one attn group + one ffn group, each with all layers
     assert len(groups) == 2
     assert all(len(gmods) == tiny_cfg.num_layers for _, gmods in groups)
+
+
+@pytest.mark.parametrize("n_mods,per,max_batch,shards,free,want", [
+    # DEVICE_MEM_FRACTION (0.8) of ``free`` is what a chunk may take
+    (12, 100, 16, 1, 500, 4),     # 4 fit: three chunks of 4
+    (12, 100, 16, 1, 700, 4),     # 5 fit: three chunks, spread evenly
+    (12, 100, 16, 1, 10**9, 12),  # all fit: max_batch binds
+    (12, 100, 4, 1, 10**9, 4),    # max_batch stays an upper bound
+    (12, 100, 16, 4, 300, 6),     # 2 fit per device, 4 devices: 6 + 6
+    (12, 100, 16, 1, 50, 1),      # none fit: one module at a time
+])
+def test_chunk_size_from_device_memory(n_mods, per, max_batch, shards, free,
+                                       want):
+    assert chunk_size(n_mods, per, max_batch, shards, free=free) == want
+
+
+def test_chunk_size_without_device_limit():
+    """The CPU backend reports no memory limit: only max_batch binds."""
+    assert jax.devices()[0].platform == "cpu"
+    assert chunk_size(12, 100, 16) == 12
+    assert chunk_size(12, 100, 5) == 4
 
 
 @pytest.mark.parametrize("max_batch", [16, 1])

@@ -10,7 +10,8 @@ from repro.configs import GPT2_SMALL, smoke_config
 from repro.core.latency import (_grid_for, _kinds_for, build_costmodel_table,
                                 build_measured_table)
 from repro.core.structures import UNITS, level_grid, registry
-from repro.runtime.costmodel import InferenceEnv, kv_cache_bytes
+from repro.runtime.costmodel import (TPU_V5E, InferenceEnv, hardware_for,
+                                     kv_cache_bytes)
 
 ENV = InferenceEnv(batch=8, seq=128, mode="prefill")
 
@@ -114,3 +115,11 @@ def test_units_cover_every_registry_kind():
             assert u.cost_time(cfg, ENV, m.n_structures) == 0.0
             assert u.timing_spec(cfg, ENV, 0) is not None
             assert u.timing_spec(cfg, ENV, m.n_structures) is None
+
+
+def test_hardware_for_unknown_kind_is_an_error():
+    """Peaks are looked up by the device kind JAX reports; a kind without
+    an entry fails instead of being priced as some other chip."""
+    assert hardware_for("TPU v5 lite") is TPU_V5E
+    with pytest.raises(KeyError, match="no peak entry"):
+        hardware_for("cpu")

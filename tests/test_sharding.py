@@ -57,7 +57,6 @@ s2, m2 = step(state2, b)
 out["loss_match"] = abs(float(m1["loss"]) - float(m2["loss"])) < 1e-4
 
 # int8 error-feedback compressed psum: mean of per-shard values
-from jax.experimental.shard_map import shard_map
 g = jax.random.normal(jax.random.key(2), (4, 16), jnp.float32)
 err0 = jnp.zeros((4, 16), jnp.float32)  # per-shard err: (1,16) inside
 
@@ -65,8 +64,8 @@ def comp(gl, el):
     avg, e = int8_ef_compress({"g": gl}, {"g": el}, ("data",))
     return avg["g"], e["g"]
 
-f = shard_map(comp, mesh=mesh, in_specs=(P("data"), P("data")),
-              out_specs=(P(None), P("data")))
+f = jax.shard_map(comp, mesh=mesh, in_specs=(P("data"), P("data")),
+                  out_specs=(P(None), P("data")))
 avg, err = f(g, err0)
 true_mean = jnp.mean(g.reshape(4, 1, 16), axis=0)
 rel = float(jnp.max(jnp.abs(avg[:1] - true_mean)) /

@@ -116,6 +116,27 @@ def test_search_batched_matches_serial_exact():
         assert r_b.speedup >= 2.0 - 1e-6
 
 
+def test_batched_eval_out_of_memory_fails_the_search():
+    """A device out-of-memory error in the batched population eval is a
+    sizing fault: it fails the search instead of demoting to the serial
+    path, which would hide it behind a slower run."""
+    from repro.robustness.report import RobustnessReport, report_scope
+    db, tab = synth_problem()
+
+    def eval_batched(assigns):
+        raise RuntimeError("RESOURCE_EXHAUSTED: Out of memory while trying "
+                           "to allocate 4.39G")
+
+    rep = RobustnessReport()
+    with report_scope(rep), pytest.raises(RuntimeError,
+                                          match="RESOURCE_EXHAUSTED"):
+        search(db, tab, 2.0, steps=8, pop=4, batched=True, seed=0,
+               eval_fn=lambda a: float(sum(a.values())),
+               eval_batched=eval_batched)
+    assert not rep.counts["demotions"]
+    assert not rep.breaker_open("spdy.batched_eval")
+
+
 def test_search_memoizes_candidate_scores():
     """Duplicate DP solutions must not be re-evaluated: every eval_fn call
     sees a never-before-scored assignment, and the total is well below the

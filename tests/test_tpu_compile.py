@@ -1,0 +1,109 @@
+"""Compile the main path's Pallas kernels and the GPT2-small database chunk
+for a described TPU v5e, without a chip.
+
+The TPU compiler refuses what interpret mode accepts: blocks off the
+(8, 128) tiling, kernels that need more scoped VMEM than granted, programs
+larger than HBM. Each case lowers and compiles at real widths for one chip
+of a ``v5e:2x2`` topology and checks that the kernel is really there
+(``tpu_custom_call``). The topology is described in a fixture, never at
+import: only one process at a time may load the TPU library.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.configs import GPT2_SMALL, get_config
+from repro.core.database import chunk_size, module_bytes
+from repro.core.obs import prune_structured_batched
+from repro.core.structures import level_grid, registry
+from repro.kernels import ops
+from repro.runtime.costmodel import TPU_V5E
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    # a described chip cannot read back what the persistent cache holds
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        t = topologies.get_topology_desc(platform="tpu",
+                                         topology_name="v5e:2x2")
+    except Exception as e:
+        jax.config.update("jax_enable_compilation_cache", was)
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    yield t
+    jax.config.update("jax_enable_compilation_cache", was)
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _compile(fn, shardings, *shapes):
+    args = [jax.ShapeDtypeStruct(s, d, sharding=shardings) for s, d in shapes]
+    return jax.jit(fn).lower(*args).compile()
+
+
+F32, BF16 = jnp.float32, jnp.bfloat16
+
+
+@pytest.mark.parametrize("d_in,d_out,gs", [(3072, 768, 1), (768, 768, 64),
+                                           (4096, 4096, 1)])
+def test_obs_downdate_compiles(one_chip, d_in, d_out, gs):
+    c = _compile(lambda *a: ops._obs_downdate_impl(*a, interpret=False),
+                 one_chip, ((d_in, d_out), F32), ((d_in, d_in), F32),
+                 ((d_in, gs), F32), ((gs, d_out), F32), ((gs, d_in), F32),
+                 ((d_in,), F32))
+    assert "tpu_custom_call" in c.as_text()
+
+
+def test_hessian_accum_compiles(one_chip):
+    c = _compile(lambda x: ops._hessian_accum_impl(x, interpret=False),
+                 one_chip, ((1024, 3072), F32))
+    assert "tpu_custom_call" in c.as_text()
+
+
+def test_flash_attention_compiles(one_chip):
+    qkv = ((1, 1024, 12, 64), BF16)  # GPT2-small: 12 heads x 64
+    c = _compile(lambda q, k, v: ops._flash_attention_impl(
+        q, k, v, causal=True, interpret=False), one_chip, qkv, qkv, qkv)
+    assert "tpu_custom_call" in c.as_text()
+
+
+def test_ssd_scan_compiles_at_mamba2_widths(one_chip):
+    cfg = get_config("mamba2-2.7b")
+    b, s = 1, 4 * cfg.ssm_chunk
+    h, p, n = cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state
+    c = _compile(lambda x, dt, A, B, C: ops._ssd_chunked_impl(
+        x, dt, A, B, C, chunk=cfg.ssm_chunk, interpret=False), one_chip,
+        ((b, s, h, p), BF16), ((b, s, h), F32), ((h,), F32),
+        ((b, s, n), BF16), ((b, s, n), BF16))
+    assert "tpu_custom_call" in c.as_text()
+
+
+def test_gpt2_ffn_db_chunk_fits_one_v5e(one_chip):
+    """The chunk `build_database` picks for GPT2-small's 12 FFN modules on
+    an empty v5e fits its HBM, and `module_bytes` bounds the compiler's
+    own count from above."""
+    mods = [m for m in registry(GPT2_SMALL) if m.kind == "ffn"]
+    levels = tuple(level_grid(mods[0]))
+    d_in, d_out = mods[0].d_in, GPT2_SMALL.d_model
+    per = module_bytes(d_in, d_out, len(levels))
+    k = chunk_size(len(mods), per, 16, free=int(TPU_V5E.hbm_bytes))
+    assert 1 <= k < len(mods)
+    c = _compile(lambda W, H: prune_structured_batched(
+        W, H, group_size=1, n_remove=max(levels), levels=levels),
+        one_chip, ((k, d_in, d_out), F32), ((k, d_in, d_in), F32))
+    ma = c.memory_analysis()
+    used = (ma.argument_size_in_bytes + ma.output_size_in_bytes
+            + ma.temp_size_in_bytes - ma.alias_size_in_bytes)
+    assert used <= k * per
+    assert used < TPU_V5E.hbm_bytes
