@@ -346,7 +346,18 @@ _COMPACT_STATICS = ("group_size", "n_remove", "levels", "use_kernel",
                     "interpret", "ratio", "min_rows", "pad_rows")
 
 
+def _named(name: str):
+    """Name the function that ``jax.jit`` wraps: its executable is then
+    ``jit_<name>`` in a profiler trace (every Algorithm-1 executable's
+    name starts with ``jit_prune_obs``)."""
+    def deco(fn):
+        fn.__name__ = fn.__qualname__ = name
+        return fn
+    return deco
+
+
 @functools.partial(jax.jit, static_argnames=_COMPACT_STATICS)
+@_named("prune_obs_compact")
 def prune_structured_compact(W: jnp.ndarray, Hinv: jnp.ndarray, *,
                              group_size: int, n_remove: int,
                              levels: Tuple[int, ...],
@@ -367,6 +378,7 @@ def prune_structured_compact(W: jnp.ndarray, Hinv: jnp.ndarray, *,
 
 
 @functools.partial(jax.jit, static_argnames=_COMPACT_STATICS)
+@_named("prune_obs_batched_compact")
 def prune_structured_batched_compact(W: jnp.ndarray, Hinv: jnp.ndarray, *,
                                      group_size: int, n_remove: int,
                                      levels: Tuple[int, ...],
@@ -389,6 +401,7 @@ def prune_structured_batched_compact(W: jnp.ndarray, Hinv: jnp.ndarray, *,
 @functools.partial(jax.jit, static_argnames=("group_size", "n_remove",
                                              "levels", "use_kernel",
                                              "interpret"))
+@_named("prune_obs")
 def prune_structured(W: jnp.ndarray, Hinv: jnp.ndarray, *, group_size: int,
                      n_remove: int, levels: Tuple[int, ...],
                      use_kernel: bool = False,
@@ -439,13 +452,13 @@ def _sharded_prune_jit(mesh, axes: Tuple[str, ...], group_size: int,
     f = jax.shard_map(_body, mesh=mesh, in_specs=(spec, spec),
                       out_specs=(spec, spec, spec), check_vma=False)
 
-    def _padded(W, Hinv):
+    def prune_obs_sharded(W, Hinv):
         b = W.shape[0]
         snaps, errs, order = f(pad_leading(W, ndev),
                                pad_leading(Hinv, ndev))
         return snaps[:b], errs[:b], order[:b]
 
-    return jax.jit(_padded)
+    return jax.jit(prune_obs_sharded)
 
 
 def prune_structured_sharded(W: jnp.ndarray, Hinv: jnp.ndarray, *,
@@ -479,6 +492,7 @@ def prune_structured_sharded(W: jnp.ndarray, Hinv: jnp.ndarray, *,
 @functools.partial(jax.jit, static_argnames=("group_size", "n_remove",
                                              "levels", "use_kernel",
                                              "interpret"))
+@_named("prune_obs_batched")
 def prune_structured_batched(W: jnp.ndarray, Hinv: jnp.ndarray, *,
                              group_size: int, n_remove: int,
                              levels: Tuple[int, ...],

@@ -18,6 +18,7 @@ from typing import Callable, Dict, List, Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation as _span
 
 from ..models.model import loss_fn
 from ..robustness import faults as _faults
@@ -213,15 +214,24 @@ def oneshot_prune(cfg, params, calib_batches: List[dict],
     loaded from / persisted to the latency cache instead of re-timed.
     ``search_pop`` sets the SPDY population per round; ``search_batched=
     False`` keeps the serial equivalence-reference search path.
+
+    The stages run under host spans ``prune.hessians``,
+    ``prune.latency_table``, ``prune.db``, ``prune.search`` and
+    ``prune.stitch`` (each member's parameters and calibration loss),
+    which a ``jax.profiler`` trace records on the device's clock.
     """
     targets = list(targets)  # consumed twice: family search + variants
-    hessians = collect_hessians(cfg, params, calib_batches,
-                                use_kernel=use_kernel, mesh=mesh,
-                                data_axes=data_axes)
-    table = build_table(cfg, env, backend=latency_backend,
-                        **(latency_kw or {}))
-    db = build_database(cfg, params, hessians, damp=damp, verbose=verbose,
-                        mesh=mesh, shard_axes=data_axes)
+    with _span("prune.hessians"):
+        hessians = collect_hessians(cfg, params, calib_batches,
+                                    use_kernel=use_kernel, mesh=mesh,
+                                    data_axes=data_axes)
+    with _span("prune.latency_table"):
+        table = build_table(cfg, env, backend=latency_backend,
+                            **(latency_kw or {}))
+    with _span("prune.db"):
+        db = build_database(cfg, params, hessians, damp=damp,
+                            verbose=verbose, mesh=mesh,
+                            shard_axes=data_axes)
     # device-resident snapshots only pay off for per-candidate loss eval;
     # without it the final per-target stitch is cheap on the host path
     cache = SnapshotCache(cfg, db) if eval_with_loss else None
@@ -242,22 +252,25 @@ def oneshot_prune(cfg, params, calib_batches: List[dict],
     # one search pass for the whole family: shared candidate pool, shared
     # stitch/eval memo, per-target budgets in the batched DP, per-target
     # fold-in RNG streams
-    results = search_family(db, table, targets, steps=search_steps,
-                            pop=search_pop, eval_fn=eval_fn,
-                            eval_batched=eval_batched, seed=seed,
-                            batched=search_batched, verbose=verbose,
-                            devices=(list(mesh.devices.flat)
-                                     if mesh is not None else None))
+    with _span("prune.search"):
+        results = search_family(db, table, targets, steps=search_steps,
+                                pop=search_pop, eval_fn=eval_fn,
+                                eval_batched=eval_batched, seed=seed,
+                                batched=search_batched, verbose=verbose,
+                                devices=(list(mesh.devices.flat)
+                                         if mesh is not None else None))
 
     variants: Dict[float, PrunedVariant] = {}
     for t in targets:
         res = results[t]
-        pruned = apply_assignment(cfg, params, db, res.assignment,
-                                  cache=cache)
+        with _span("prune.stitch", target=t):
+            pruned = apply_assignment(cfg, params, db, res.assignment,
+                                      cache=cache)
+            calib_loss = loss_eval(pruned)
         variants[t] = PrunedVariant(
             target_speedup=t, params=pruned, assignment=res.assignment,
             runtime=res.runtime, speedup=res.speedup,
-            calib_loss=loss_eval(pruned), search=res)
+            calib_loss=calib_loss, search=res)
         if verbose:
             print(f"target {t}x -> achieved {res.speedup:.2f}x, "
                   f"loss {variants[t].calib_loss:.4f} "

@@ -111,10 +111,12 @@ stage, where the recorded search result plus trainer checkpoints
 repair it deterministically.  Each stage record carries a
 ``stage_times`` payload (seconds per stage, ``export`` = the tail) so
 benchmarks can attribute wall-time to hessians/db/search/finetune
-under either schedule.
+under either schedule; each stage also runs under a ``prune.<stage>``
+host span, which a ``jax.profiler`` trace records on the device's clock.
 """
 from __future__ import annotations
 
+import contextlib
 import hashlib
 import json
 import os
@@ -534,6 +536,16 @@ def gradual_prune(cfg, params, env, targets: Sequence[float],
         frs._save()
 
 
+@contextlib.contextmanager
+def _stage(name: str, stage_t: Dict[str, float]):
+    """Host seconds of stage ``name`` into ``stage_t``, under a
+    ``prune.<name>`` span."""
+    t0 = time.perf_counter()
+    with jax.profiler.TraceAnnotation(f"prune.{name}"):
+        yield
+    stage_t[name] = time.perf_counter() - t0
+
+
 def _family_engine(cfg, params, env, targets, data, calib_batches, *, tcfg,
                    finetune_steps, search_steps, search_pop, search_batched,
                    latency_backend, latency_kw, mesh, data_axes, mc, specs,
@@ -543,8 +555,9 @@ def _family_engine(cfg, params, env, targets, data, calib_batches, *, tcfg,
     (``gradual_prune`` is the argument-validating, manifest-owning
     wrapper)."""
     teacher = jax.tree.map(lambda a: a, params)  # dense teacher
-    table = build_table(cfg, env, backend=latency_backend,
-                        **(latency_kw or {}))
+    with jax.profiler.TraceAnnotation("prune.latency_table"):
+        table = build_table(cfg, env, backend=latency_backend,
+                            **(latency_kw or {}))
     loss_eval = calib_loss_fn(cfg, calib_batches[:1])
     devices = list(mesh.devices.flat) if mesh is not None else None
 
@@ -615,19 +628,18 @@ def _family_engine(cfg, params, env, targets, data, calib_batches, *, tcfg,
             hessians = _load_hessians(
                 hpath, expected_sha=entry.get("hessians_sha256"))
         if hessians is None:
-            t0 = time.perf_counter()
-            hessians = collect_hessians(cfg, current, calib_batches,
-                                        mesh=mesh, data_axes=data_axes)
-            hsha = _stream_artifact(mgr, hpath, _hessian_arrays(hessians))
-            stage_t["hessians"] = time.perf_counter() - t0
+            with _stage("hessians", stage_t):
+                hessians = collect_hessians(cfg, current, calib_batches,
+                                            mesh=mesh, data_axes=data_axes)
+                hsha = _stream_artifact(mgr, hpath,
+                                        _hessian_arrays(hessians))
             frs.record(tkey, "hessians", hessians_sha256=hsha,
                        stage_times=dict(stage_t))
             preempt_at(i, "hessians")
-        t0 = time.perf_counter()
-        db = build_database(cfg, current, hessians, mesh=mesh,
-                            shard_axes=data_axes)
-        dsha = _stream_artifact(mgr, dpath, _db_arrays(db))
-        stage_t["db"] = time.perf_counter() - t0
+        with _stage("db", stage_t):
+            db = build_database(cfg, current, hessians, mesh=mesh,
+                                shard_axes=data_axes)
+            dsha = _stream_artifact(mgr, dpath, _db_arrays(db))
         frs.record(tkey, "db", db_sha256=dsha, stage_times=dict(stage_t))
         preempt_at(i, "db")
         return db
@@ -640,13 +652,12 @@ def _family_engine(cfg, params, env, targets, data, calib_batches, *, tcfg,
         thread concurrent with target ``i+1``'s stages; everything it
         touches is immutable (``cur`` is the finished params tree) and
         deterministic, so the scheduler cannot change a single bit."""
-        t0 = time.perf_counter()
-        loss_after = loss_eval(cur)
-        data_b, psha = npz_bytes(_flatten(cur))
-        mgr.submit_blob(os.path.join(tdir, "params.npz"), data_b,
-                        site="db.artifact_write")
-        pm = shrink(cfg, cur, db, res.assignment)
-        stage_t["export"] = time.perf_counter() - t0
+        with _stage("export", stage_t):
+            loss_after = loss_eval(cur)
+            data_b, psha = npz_bytes(_flatten(cur))
+            mgr.submit_blob(os.path.join(tdir, "params.npz"), data_b,
+                            site="db.artifact_write")
+            pm = shrink(cfg, cur, db, res.assignment)
         frs.record(tkey, "done", executed=False, loss_after_ft=loss_after,
                    params_sha256=psha, stage_times=dict(stage_t))
         out[i] = GradualVariant(
@@ -724,54 +735,57 @@ def _family_engine(cfg, params, env, targets, data, calib_batches, *, tcfg,
                                           cache=cache)
                 loss_before = float(entry["loss_before_ft"])  # sync: manifest
             else:
-                t0 = time.perf_counter()
-                if loss_b is None:
-                    loss_b = batched_calib_loss_fn(cfg, calib_batches[:1],
-                                                   cache.batch_axes(current))
-                res = search(db, table, target, steps=search_steps,
-                             pop=search_pop, batched=search_batched,
-                             seed=seeds[i], devices=devices,
-                             eval_fn=lambda a: loss_eval(apply_assignment(
-                                 cfg, current, db, a, cache=cache)),
-                             eval_batched=make_batched_eval(
-                                 cfg, current, cache, calib_batches[:1],
-                                 loss_b=loss_b))
-                masked = apply_assignment(cfg, current, db, res.assignment,
-                                          cache=cache)
-                loss_before = loss_eval(masked)
-                stage_t["search"] = time.perf_counter() - t0
+                with _stage("search", stage_t):
+                    if loss_b is None:
+                        loss_b = batched_calib_loss_fn(
+                            cfg, calib_batches[:1],
+                            cache.batch_axes(current))
+                    res = search(db, table, target, steps=search_steps,
+                                 pop=search_pop, batched=search_batched,
+                                 seed=seeds[i], devices=devices,
+                                 eval_fn=lambda a: loss_eval(
+                                     apply_assignment(cfg, current, db, a,
+                                                      cache=cache)),
+                                 eval_batched=make_batched_eval(
+                                     cfg, current, cache,
+                                     calib_batches[:1], loss_b=loss_b))
+                    masked = apply_assignment(cfg, current, db,
+                                              res.assignment, cache=cache)
+                    loss_before = loss_eval(masked)
                 frs.record(tkey, "search", loss_before_ft=loss_before,
                            stage_times=dict(stage_t),
                            **_result_payload(res))
                 preempt_at(i, "search")
 
             # ---- stage: distillation finetune ----
-            t0 = time.perf_counter()
-            masks = masks_from_assignment(cfg, masked, db, res.assignment)
-            trainer = make_trainer(tdir, masks=masks)
-            state = trainer.init_or_restore(masked)
-            start = int(state.step)
-            data_iter = data(i * finetune_steps + start) if callable(data) \
-                else data
-            fit_stop = None
-            if stop_after is not None and tuple(stop_after[:2]) == \
-                    (i, "finetune") and len(stop_after) > 2:
-                fit_stop = int(stop_after[2])
-            if start < finetune_steps:
-                frs.log_exec(tkey, "finetune")
-            state = trainer.fit(state, data_iter, steps=finetune_steps,
-                                stop_after=fit_stop)
-            if int(state.step) < finetune_steps:
-                # simulated stop_after kill or a real SIGTERM preemption —
-                # the trainer checkpointed; re-invoking resumes from that
-                # step (barrier: the previous target's export must be as
-                # durable as a serial run's before we report preempted)
-                _barrier()
-                raise FamilyPreempted(
-                    f"preempted mid-finetune of target {target} at step "
-                    f"{int(state.step)} (run dir {run_dir})")
-            current = state.params
-            stage_t["finetune"] = time.perf_counter() - t0
+            with _stage("finetune", stage_t):
+                masks = masks_from_assignment(cfg, masked, db,
+                                              res.assignment)
+                trainer = make_trainer(tdir, masks=masks)
+                state = trainer.init_or_restore(masked)
+                start = int(state.step)
+                data_iter = (data(i * finetune_steps + start)
+                             if callable(data) else data)
+                fit_stop = None
+                if stop_after is not None and tuple(stop_after[:2]) == \
+                        (i, "finetune") and len(stop_after) > 2:
+                    fit_stop = int(stop_after[2])
+                if start < finetune_steps:
+                    frs.log_exec(tkey, "finetune")
+                state = trainer.fit(state, data_iter,
+                                    steps=finetune_steps,
+                                    stop_after=fit_stop)
+                if int(state.step) < finetune_steps:
+                    # simulated stop_after kill or a real SIGTERM
+                    # preemption — the trainer checkpointed; re-invoking
+                    # resumes from that step (barrier: the previous
+                    # target's export must be as durable as a serial
+                    # run's before we report preempted)
+                    _barrier()
+                    raise FamilyPreempted(
+                        f"preempted mid-finetune of target {target} at step "
+                        f"{int(state.step)} (run dir {run_dir})")
+                current = state.params
 
             # ---- export tail: overlapped with the next target's stages
             # (only reads the finished `current`), or inline when serial

@@ -86,19 +86,20 @@ class _DeviceCtx(_HostCtx):
 
 
 def _shrink_impl(cfg, tree, db, assignment, ctx_cls) -> PrunedModel:
-    ctx = ctx_cls(tree["layers"], db, assignment)
-    out_layers: List[PrunedLayer] = []
-    for l in range(cfg.num_layers):
-        lcfg = PrunedLayer()
-        lp: Dict = {}
-        for unit in UNITS.values():
-            unit.shrink_layer(cfg, ctx, l, lcfg, lp)
-        lcfg.params = lp
-        out_layers.append(lcfg)
-    globals_ = {"embed": tree["embed"], "final_norm": tree["final_norm"]}
-    if tree.get("head"):
-        globals_["head"] = tree["head"]
-    return PrunedModel(cfg=cfg, layers=out_layers, globals_=globals_)
+    with jax.profiler.TraceAnnotation("prune.shrink"):
+        ctx = ctx_cls(tree["layers"], db, assignment)
+        out_layers: List[PrunedLayer] = []
+        for l in range(cfg.num_layers):
+            lcfg = PrunedLayer()
+            lp: Dict = {}
+            for unit in UNITS.values():
+                unit.shrink_layer(cfg, ctx, l, lcfg, lp)
+            lcfg.params = lp
+            out_layers.append(lcfg)
+        globals_ = {"embed": tree["embed"], "final_norm": tree["final_norm"]}
+        if tree.get("head"):
+            globals_["head"] = tree["head"]
+        return PrunedModel(cfg=cfg, layers=out_layers, globals_=globals_)
 
 
 def shrink(cfg, params, db: Dict[str, ModuleDB],

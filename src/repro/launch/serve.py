@@ -46,8 +46,11 @@ def main(argv=None):
     print(f"  prefill         {m['prefill_ms_mean']:8.2f} ms (warm, mean)")
     print(f"  decode          {m['decode_ms_per_token_mean']:8.2f} ms/token "
           f"(warm, mean)")
-    print(f"  request latency p50={m['p50_ms']:.1f} ms "
-          f"p99={m['p99_ms']:.1f} ms")
+    print(f"  latency (host clock) request p50={m['p50_ms']:.1f} "
+          f"p99={m['p99_ms']:.1f} ms, first token "
+          f"p50={m['ttft_p50_ms']:.1f} p99={m['ttft_p99_ms']:.1f} ms, "
+          f"inter-token p50={m['itl_p50_ms']:.2f} "
+          f"p99={m['itl_p99_ms']:.2f} ms")
     print(f"  throughput      {m['tokens_per_s']:8.1f} tokens/s")
     print("sample:", report.records[0].tokens[:8])
     return m

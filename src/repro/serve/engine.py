@@ -11,6 +11,7 @@ cache sizing contract. Two model adapters share one engine:
 """
 from __future__ import annotations
 
+import gc
 import time
 from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, List, Optional
@@ -18,6 +19,7 @@ from typing import Any, Callable, Dict, List, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation as _span
 
 from ..models import model as model_mod
 from ..models.pruned import (PrunedLayer, PrunedModel, _check_decodable,
@@ -58,9 +60,26 @@ class DenseServeModel:
         _check_decodable(cfg)
         self.cfg, self.params, self.max_len = cfg, params, max_len
         self._prefill_jit: Dict[int, Callable] = {}
-        self._step = jax.jit(
-            lambda p, c, t: decode_step(cfg, p, c, t))
-        self._insert = jax.jit(self._insert_impl)
+
+        # the jitted functions' names are the executables' names in a
+        # profiler trace (jit_serve_decode, jit_serve_prefill,
+        # jit_serve_insert), the same in both adapters
+        def serve_decode(p, c, t):
+            return decode_step(cfg, p, c, t)
+
+        def serve_insert(cache, row, slot, pos):
+            return {
+                "pos": cache["pos"].at[slot].set(pos),
+                "attn": {
+                    "k": cache["attn"]["k"].at[:, slot].set(
+                        row["attn"]["k"][:, 0]),
+                    "v": cache["attn"]["v"].at[:, slot].set(
+                        row["attn"]["v"][:, 0]),
+                },
+            }
+
+        self._step = jax.jit(serve_decode)
+        self._insert = jax.jit(serve_insert)
 
     def init_slots(self, nslots: int):
         return init_cache(self.cfg, nslots, self.max_len, per_slot=True)
@@ -78,29 +97,19 @@ class DenseServeModel:
         bucket = _bucket(s, self.max_len)
 
         if bucket not in self._prefill_jit:
-            def f(p, toks, last, _bucket=bucket):
+            def serve_prefill(p, toks, last, _bucket=bucket):
                 out = forward(cfg, p, toks, mode="prefill")
                 cache = model_mod.assemble_prefill_cache(
                     cfg, out, 1, _bucket, self.max_len)
                 logits = jax.lax.dynamic_slice_in_dim(out["logits"], last,
                                                       1, axis=1)
                 return logits, cache
-            self._prefill_jit[bucket] = jax.jit(f)
+            self._prefill_jit[bucket] = jax.jit(serve_prefill)
 
         padded = np.zeros((1, bucket), np.int64)
         padded[0, :s] = tokens
         return self._prefill_jit[bucket](self.params, jnp.asarray(padded),
                                          jnp.asarray(s - 1, jnp.int32))
-
-    @staticmethod
-    def _insert_impl(cache, row, slot, pos):
-        return {
-            "pos": cache["pos"].at[slot].set(pos),
-            "attn": {
-                "k": cache["attn"]["k"].at[:, slot].set(row["attn"]["k"][:, 0]),
-                "v": cache["attn"]["v"].at[:, slot].set(row["attn"]["v"][:, 0]),
-            },
-        }
 
     def insert(self, cache, row_cache, slot: int, pos: int):
         return self._insert(cache, row_cache, jnp.asarray(slot, jnp.int32),
@@ -133,10 +142,11 @@ class PrunedServeModel:
                       for m, lp in zip(meta, lps)]
             return PrunedModel(cfg=cfg, layers=layers, globals_=globals_)
 
-        def step_fn(lps, globals_, cache, toks):
+        # named as in DenseServeModel
+        def serve_decode(lps, globals_, cache, toks):
             return decode_step_pruned(rebuild(lps, globals_), cache, toks)
 
-        def prefill_fn(lps, globals_, toks, last):
+        def serve_prefill(lps, globals_, toks, last):
             logits, cache = prefill_pruned(rebuild(lps, globals_), toks,
                                            max_len, full_logits=True)
             logits = jax.lax.dynamic_slice_in_dim(logits, last, 1, axis=1)
@@ -144,10 +154,20 @@ class PrunedServeModel:
 
         self._lps = [l.params for l in pm.layers]
         self._globals = pm.globals_
-        self._step = jax.jit(step_fn)
+        def serve_insert(cache, row, slot, pos):
+            attn = []
+            for buf, rbuf in zip(cache["attn"], row["attn"]):
+                if buf is None:
+                    attn.append(None)
+                else:
+                    attn.append({"k": buf["k"].at[slot].set(rbuf["k"][0]),
+                                 "v": buf["v"].at[slot].set(rbuf["v"][0])})
+            return {"pos": cache["pos"].at[slot].set(pos), "attn": attn}
+
+        self._step = jax.jit(serve_decode)
         self._prefill_jit: Dict[int, Callable] = {}
-        self._prefill_fn = prefill_fn
-        self._insert = jax.jit(self._insert_impl)
+        self._prefill_fn = serve_prefill
+        self._insert = jax.jit(serve_insert)
 
     def init_slots(self, nslots: int):
         return init_cache_pruned(self.pm, nslots, self.max_len,
@@ -164,17 +184,6 @@ class PrunedServeModel:
                                          jnp.asarray(padded),
                                          jnp.asarray(s - 1, jnp.int32))
 
-    @staticmethod
-    def _insert_impl(cache, row, slot, pos):
-        attn = []
-        for buf, rbuf in zip(cache["attn"], row["attn"]):
-            if buf is None:
-                attn.append(None)
-            else:
-                attn.append({"k": buf["k"].at[slot].set(rbuf["k"][0]),
-                             "v": buf["v"].at[slot].set(rbuf["v"][0])})
-        return {"pos": cache["pos"].at[slot].set(pos), "attn": attn}
-
     def insert(self, cache, row_cache, slot: int, pos: int):
         return self._insert(cache, row_cache, jnp.asarray(slot, jnp.int32),
                             jnp.asarray(pos, jnp.int32))
@@ -185,6 +194,12 @@ class PrunedServeModel:
 
 @dataclass
 class RequestRecord:
+    """One request's tokens and times. ``prefill_ms`` and
+    ``decode_step_ms`` bracket the dispatches; ``t_admit``, ``t_first`` and
+    ``t_done`` are host-clock seconds since the start of ``run``: its
+    admission began, its first token was on the host, its last one was.
+    Its later tokens came at ``ServeReport.step_end[first_step]`` through
+    ``step_end[last_step]`` (both None for a one-token request)."""
     rid: int
     prompt_len: int
     steps: int
@@ -193,17 +208,38 @@ class RequestRecord:
     tokens: List[int] = field(default_factory=list)
     prefill_ms: float = 0.0
     decode_step_ms: List[float] = field(default_factory=list)
-    finish: float = 0.0           # virtual seconds since stream start
+    t_admit: float = 0.0
+    t_first: float = 0.0
+    t_done: float = 0.0
+    first_step: Optional[int] = None
+    last_step: Optional[int] = None
+
+    @property
+    def _t_due(self) -> float:
+        # arrivals are admitted on the virtual clock, which can run ahead
+        # of the host's: a request admitted before its arrival on the host
+        # clock waited for nothing
+        return min(self.arrival, self.t_admit)
 
     @property
     def latency_s(self) -> float:
-        """Queueing + service time of the whole request."""
-        return self.finish - self.arrival
+        """Queueing + service time of the whole request (host clock)."""
+        return self.t_done - self._t_due
+
+    @property
+    def ttft_s(self) -> float:
+        """Time to the first token (host clock)."""
+        return self.t_first - self._t_due
 
     @property
     def decode_ms_per_token(self) -> float:
         return float(np.mean(self.decode_step_ms)) \
             if self.decode_step_ms else 0.0
+
+
+def _percentiles(name: str, xs, qs) -> Dict[str, float]:
+    return {f"{name}p{q}_ms": float(np.percentile(xs, q)) if len(xs)
+            else float("nan") for q in qs}
 
 
 @dataclass
@@ -212,6 +248,9 @@ class ServeReport:
     wall_s: float                 # busy wall-clock (prefills + steps)
     steps: int                    # decode steps executed
     kv_cache_bytes: int
+    # host-clock seconds since the start of ``run`` at which each decode
+    # step's logits were on the host
+    step_end: List[float] = field(default_factory=list)
 
     @property
     def total_tokens(self) -> int:
@@ -221,9 +260,23 @@ class ServeReport:
     def tokens_per_s(self) -> float:
         return self.total_tokens / max(self.wall_s, 1e-12)
 
+    def token_times(self, rec: RequestRecord) -> np.ndarray:
+        """Host-clock stamp of each of ``rec``'s tokens."""
+        later = (self.step_end[rec.first_step:rec.last_step + 1]
+                 if rec.first_step is not None else [])
+        return np.array([rec.t_first, *later])
+
     def latency_percentiles(self, qs=(50, 99)) -> Dict[str, float]:
-        lats = [r.latency_s * 1e3 for r in self.records]
-        return {f"p{q}_ms": float(np.percentile(lats, q)) for q in qs}
+        """Request latency (``p<q>_ms``), time to first token
+        (``ttft_p<q>_ms``) and inter-token gaps (``itl_p<q>_ms``), all
+        from the host-clock stamps."""
+        itl = np.concatenate([np.diff(self.token_times(r))
+                              for r in self.records] or [[]])
+        d = _percentiles("", [r.latency_s * 1e3 for r in self.records], qs)
+        d.update(_percentiles("ttft_", [r.ttft_s * 1e3
+                                        for r in self.records], qs))
+        d.update(_percentiles("itl_", itl * 1e3, qs))
+        return d
 
     @property
     def prefill_ms_mean(self) -> float:
@@ -246,13 +299,50 @@ class ServeReport:
         return d
 
 
+class _GCSpans:
+    """While registered in ``gc.callbacks``, a ``serve.gc`` span over each
+    garbage collection."""
+
+    def __init__(self):
+        self._open = None
+
+    def __call__(self, phase, info):
+        if phase == "start":
+            self._open = _span("serve.gc", generation=info["generation"])
+            self._open.__enter__()
+        elif self._open is not None:
+            self._open.__exit__(None, None, None)
+            self._open = None
+
+    def __enter__(self):
+        gc.callbacks.append(self)
+        return self
+
+    def __exit__(self, *exc):
+        gc.callbacks.remove(self)
+        self(None, None)
+
+
 class ServeEngine:
     """Continuous batching over ``num_slots`` decode slots.
 
-    ``clock`` is injectable (tests script it) and is only read around jit
-    dispatches, so measured prefill/decode latencies are the compute, not
-    the host bookkeeping. Call :meth:`warmup` before timing runs so
-    reported latencies are warm (compiles excluded).
+    ``clock`` is injectable (tests script it). It is read once at the start
+    of :meth:`run` and then only around jit dispatches, so measured
+    prefill/decode latencies are the compute, not the host bookkeeping;
+    the same readings stamp each request and decode step. Call
+    :meth:`warmup` before timing runs so reported latencies are warm
+    (compiles excluded).
+
+    Under a ``jax.profiler`` session, ``run`` writes host spans named
+    ``serve.*`` onto the trace's clock: ``serve.run``; per admission
+    ``serve.admit`` (stats ``rid``, ``slot``, ``prompt_len``, ``bucket``)
+    over ``serve.prefill``, ``serve.insert``, ``serve.wait`` and
+    ``serve.sample``; per decode step ``serve.step`` (stats ``step``,
+    ``active``) over ``serve.dispatch``, ``serve.wait``, ``serve.pull``,
+    ``serve.check`` (repeated on a retry), ``serve.sample`` and
+    ``serve.bookkeep``; and ``serve.gc`` (stat ``generation``) over each
+    garbage collection. Without a session each span costs about a
+    microsecond.
     """
 
     def __init__(self, model, num_slots: int = 4,
@@ -291,7 +381,6 @@ class ServeEngine:
         """
         rep = current_report()
         old_cache = self.cache
-        toks = jnp.asarray(tokens.reshape(-1, 1), jnp.int32)
         for attempt in range(_STEP_RETRIES):
             try:
                 mult = _faults.poison_scalar("serve.step")
@@ -299,13 +388,20 @@ class ServeEngine:
                 rep.count("detected", "serve.step")
                 rep.count("retries", "serve.step")
                 continue
-            logits, new_cache = self.model.step(old_cache, toks)
-            if mult != 1.0:
-                logits = logits * mult
-            # sync: one pull per decode step — greedy sampling and the
-            # serve.step finite check both need host logits anyway
-            lg = np.asarray(logits)
-            if not np.isfinite(lg[active_slots]).all():
+            with _span("serve.dispatch"):
+                toks = jnp.asarray(tokens.reshape(-1, 1), jnp.int32)
+                logits, new_cache = self.model.step(old_cache, toks)
+                if mult != 1.0:
+                    logits = logits * mult
+            with _span("serve.wait"):
+                # sync: one pull per decode step — greedy sampling and the
+                # serve.step finite check both need host logits anyway
+                jax.block_until_ready(logits)
+            with _span("serve.pull"):
+                lg = np.asarray(logits)  # sync: the copy of ready logits
+            with _span("serve.check"):
+                finite = np.isfinite(lg[active_slots]).all()
+            if not finite:
                 rep.count("detected", "serve.step")
                 rep.count("retries", "serve.step")
                 continue
@@ -325,10 +421,11 @@ class ServeEngine:
         """Serve a request stream to completion; returns per-request and
         aggregate metrics.
 
-        Time is virtual: it advances by the measured wall-clock of each
-        prefill/decode dispatch and fast-forwards across idle gaps to the
-        next arrival, so a seeded Poisson stream yields deterministic
-        tokens and reproducible latency structure.
+        Arrivals are admitted on a virtual clock: it advances by the
+        measured wall-clock of each prefill/decode dispatch and
+        fast-forwards across idle gaps to the next arrival, so a seeded
+        Poisson stream yields deterministic tokens. Latencies are read
+        from the host-clock stamps.
         """
         for r in requests:
             if r.prompt_len + r.steps > self.max_len:
@@ -337,6 +434,11 @@ class ServeEngine:
                     f"{r.prompt_len} + steps={r.steps} > max_len="
                     f"{self.max_len}; decoding past capacity would "
                     "overwrite the last cache slot and corrupt output")
+        with _span("serve.run", requests=len(requests)), _GCSpans():
+            return self._serve(requests)
+
+    def _serve(self, requests: List[Request]) -> ServeReport:
+        t_run = self.clock()
         pending = sorted(requests, key=lambda r: (r.arrival, r.rid))
         records = {r.rid: RequestRecord(
             rid=r.rid, prompt_len=r.prompt_len, steps=r.steps,
@@ -346,35 +448,49 @@ class ServeEngine:
         active: Dict[int, RequestRecord] = {}
         last_tok = np.zeros(self.num_slots, np.int64)
         remaining: Dict[int, int] = {}
+        step_end: List[float] = []
         t = 0.0
         busy = 0.0
-        nsteps = 0
 
         while pending or active:
             # admit arrived requests into free slots (prefill + insert)
             while pending and free and pending[0].arrival <= t:
                 req = pending.pop(0)
                 slot = free.pop()
-                t0 = self.clock()
-                logits, row = self.model.prefill(req.tokens)
-                self.cache = self.model.insert(self.cache, row, slot,
-                                               req.prompt_len)
-                # sync: one pull per admission — the first token gates
-                # whether the request enters the decode batch at all
-                tok = int(np.argmax(np.asarray(logits), axis=-1)[0, 0])
-                dt = self.clock() - t0
-                t += dt
-                busy += dt
-                rec = records[req.rid]
-                rec.prefill_ms = dt * 1e3
-                rec.tokens.append(tok)
-                last_tok[slot] = tok
-                if req.steps > 1:
-                    active[slot] = rec
-                    remaining[slot] = req.steps - 1
-                else:
-                    rec.finish = t
-                    free.append(slot)
+                with _span("serve.admit", rid=req.rid, slot=slot,
+                           prompt_len=req.prompt_len,
+                           bucket=_bucket(req.prompt_len, self.max_len)):
+                    t0 = self.clock()
+                    with _span("serve.prefill"):
+                        logits, row = self.model.prefill(req.tokens)
+                    with _span("serve.insert"):
+                        self.cache = self.model.insert(
+                            self.cache, row, slot, req.prompt_len)
+                    with _span("serve.wait"):
+                        # sync: one pull per admission — the first token
+                        # gates whether the request enters the decode
+                        # batch at all
+                        jax.block_until_ready(logits)
+                    with _span("serve.sample"):
+                        # sync: the copy of ready logits
+                        tok = int(np.argmax(np.asarray(logits),
+                                            axis=-1)[0, 0])
+                    t1 = self.clock()
+                    dt = t1 - t0
+                    t += dt
+                    busy += dt
+                    rec = records[req.rid]
+                    rec.prefill_ms = dt * 1e3
+                    rec.t_admit, rec.t_first = t0 - t_run, t1 - t_run
+                    rec.tokens.append(tok)
+                    last_tok[slot] = tok
+                    if req.steps > 1:
+                        active[slot] = rec
+                        remaining[slot] = req.steps - 1
+                        rec.first_step = len(step_end)
+                    else:
+                        rec.t_done = rec.t_first
+                        free.append(slot)
 
             if not active:
                 if pending:
@@ -383,25 +499,31 @@ class ServeEngine:
 
             # one batched decode step over all slots
             slots = sorted(active)
-            t0 = self.clock()
-            lg = self._step_once(last_tok, slots)
-            dt = self.clock() - t0
-            t += dt
-            busy += dt
-            nsteps += 1
-            for slot in slots:
-                tok = int(np.argmax(lg[slot, 0]))
-                rec = active[slot]
-                rec.tokens.append(tok)
-                rec.decode_step_ms.append(dt * 1e3)
-                last_tok[slot] = tok
-                remaining[slot] -= 1
-                if remaining[slot] == 0:
-                    rec.finish = t
-                    del active[slot]
-                    del remaining[slot]
-                    free.append(slot)
+            step = len(step_end)
+            with _span("serve.step", step=step, active=len(slots)):
+                t0 = self.clock()
+                lg = self._step_once(last_tok, slots)
+                t1 = self.clock()
+                dt = t1 - t0
+                t += dt
+                busy += dt
+                step_end.append(t1 - t_run)
+                with _span("serve.sample"):
+                    toks = [int(np.argmax(lg[slot, 0])) for slot in slots]
+                with _span("serve.bookkeep"):
+                    for slot, tok in zip(slots, toks):
+                        rec = active[slot]
+                        rec.tokens.append(tok)
+                        rec.decode_step_ms.append(dt * 1e3)
+                        last_tok[slot] = tok
+                        remaining[slot] -= 1
+                        if remaining[slot] == 0:
+                            rec.t_done, rec.last_step = step_end[-1], step
+                            del active[slot]
+                            del remaining[slot]
+                            free.append(slot)
 
         return ServeReport(records=[records[r.rid] for r in requests],
-                           wall_s=busy, steps=nsteps,
-                           kv_cache_bytes=self.kv_cache_bytes)
+                           wall_s=busy, steps=len(step_end),
+                           kv_cache_bytes=self.kv_cache_bytes,
+                           step_end=step_end)

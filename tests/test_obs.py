@@ -186,3 +186,29 @@ def test_correlated_structures_not_both_removed():
         + np.asarray(W)[2 * first:2 * first + 2]
     np.testing.assert_allclose(snap[2 * twin:2 * twin + 2], expect,
                                atol=0.05, rtol=0.05)
+
+
+@pytest.mark.parametrize("variant", ["plain", "compact", "batched",
+                                     "batched_compact", "sharded"])
+def test_algorithm1_executables_have_stable_names(variant):
+    """Every Algorithm-1 executable is named ``jit_prune_obs...``, the name
+    a profiler trace gives its executions."""
+    from repro.core import obs
+    W, _, _, _, Hinv = _setup()
+    W = jnp.asarray(W, jnp.float32)
+    kw = dict(group_size=4, n_remove=2, levels=(0, 2))
+    if variant == "sharded":
+        mesh = jax.make_mesh((1,), ("data",))
+        fn = obs._sharded_prune_jit(mesh, ("data",), 4, 2, (0, 2), False,
+                                    None, False, 0.75, 64, 16)
+        args, kw = (W[None], Hinv[None]), {}
+    else:
+        fn = {"plain": obs.prune_structured,
+              "compact": obs.prune_structured_compact,
+              "batched": obs.prune_structured_batched,
+              "batched_compact": obs.prune_structured_batched_compact
+              }[variant]
+        args = (W[None], Hinv[None]) if "batched" in variant else (W, Hinv)
+    name = fn.lower(*args, **kw).as_text().split()[1]
+    want = "obs" if variant == "plain" else f"obs_{variant}"
+    assert name == f"@jit_prune_{want}"

@@ -72,7 +72,8 @@ def test_engine_matches_sequential_generate(tiny_cfg, tiny_params,
         ref = generate(tiny_cfg, tiny_params, req.tokens[None, :],
                        steps=req.steps, max_len=MAX_LEN)
         assert rec.tokens == list(np.asarray(ref[0])), f"rid={req.rid}"
-        assert rec.finish >= rec.arrival
+        assert 0 <= rec.t_admit < rec.t_first <= rec.t_done
+        assert rec.latency_s >= rec.ttft_s > 0
 
 
 def test_engine_rejects_cache_overflow(tiny_cfg, dense_engine):
@@ -284,6 +285,67 @@ def test_metrics_attribute_prefill_and_decode_separately(tiny_cfg,
         assert rec.prefill_ms == pytest.approx(1.0)
         for dms in rec.decode_step_ms:
             assert dms == pytest.approx(1.0)
+
+
+def test_stamps_are_the_engine_clock_readings(tiny_cfg, tiny_params):
+    """With a scripted clock ticking 1 ms per reading (the run's start is
+    tick 0, then one pair per admission or decode step), each request's
+    first token is stamped at the end of its admission's pair, each decode
+    step at the end of its own, and a request's later tokens at the steps
+    it took part in."""
+    ticks = iter(range(10**6))
+
+    def clock():
+        return next(ticks) * 1e-3
+
+    eng = ServeEngine(DenseServeModel(tiny_cfg, tiny_params, MAX_LEN),
+                      num_slots=2, clock=clock)
+    eng.warmup((8, 16))
+    reqs = _requests(tiny_cfg, n=4, seed=5)
+    report = eng.run(reqs)
+    assert len(report.step_end) == report.steps > 0
+    ends = sorted([r.t_first for r in report.records] + report.step_end)
+    assert ends == pytest.approx([2e-3 * (i + 1) for i in range(len(ends))])
+    for req, rec in zip(reqs, report.records):
+        assert rec.t_first - rec.t_admit == pytest.approx(1e-3)
+        times = report.token_times(rec)
+        assert len(times) == len(rec.tokens) == req.steps
+        assert np.all(np.diff(times) >= 2e-3 - 1e-9)
+        assert rec.t_done == times[-1]
+        assert rec.last_step - rec.first_step == req.steps - 2
+        due = min(rec.arrival, rec.t_admit)
+        assert rec.latency_s == pytest.approx(rec.t_done - due)
+        assert rec.ttft_s == pytest.approx(rec.t_first - due)
+    m = report.as_dict()
+    assert m["itl_p50_ms"] >= 2.0 - 1e-6
+    assert m["ttft_p50_ms"] <= m["p50_ms"]
+
+
+def test_executables_have_stable_names(tiny_cfg, tiny_params, mag_db,
+                                       dense_engine):
+    """Both adapters' decode, prefill and insert compile to modules named
+    jit_serve_decode, jit_serve_prefill and jit_serve_insert, the names a
+    profiler trace gives their executions."""
+    def module(jitted, *args):
+        return jitted.lower(*args).as_text().split()[1]
+
+    pm = shrink(tiny_cfg, tiny_params, mag_db,
+                _half_heads_assignment(tiny_cfg, mag_db))
+    pruned = ServeEngine(PrunedServeModel(pm, MAX_LEN), num_slots=2)
+    for eng, weights in ((dense_engine, (dense_engine.model.params,)),
+                         (pruned, (pruned.model._lps,
+                                   pruned.model._globals))):
+        model = eng.model
+        _, row = model.prefill(np.zeros((5,), np.int64))
+        i32 = jnp.asarray(0, jnp.int32)
+        toks = jnp.zeros((2, 1), jnp.int32)
+        padded = jnp.zeros((1, 8), jnp.int32)
+        assert module(model._step, *weights, eng.cache, toks) \
+            == "@jit_serve_decode"
+        assert module(model._prefill_jit[8], *weights, padded, i32) \
+            == "@jit_serve_prefill"
+        assert module(model._insert, eng.cache, row, i32, i32) \
+            == "@jit_serve_insert"
 
 
 @pytest.mark.chaos
