@@ -263,7 +263,12 @@ def entry_serve_decode() -> EntryResult:
     model = DenseServeModel(cfg, params, max_len=64)
     cache = model.init_slots(4)
     toks = jnp.zeros((4, 1), jnp.int32)
-    return audit_jitted("serve.decode", model._step, (params, cache, toks))
+    # the step donates the K/V off-CPU (`engine._donate_kv`); re-declare
+    # it here so the aliases are checked statically on any backend
+    step = jax.jit(model._step.__wrapped__, donate_argnums=(1,))
+    return audit_jitted("serve.decode", step,
+                        (params, cache["attn"], cache["pos"], toks),
+                        donate_argnums=(1,))
 
 
 def entry_serve_decode_gqa() -> EntryResult:
@@ -284,8 +289,10 @@ def entry_serve_decode_gqa() -> EntryResult:
     model = PrunedServeModel(pm, max_len=64)
     cache = model.init_slots(4)
     toks = jnp.zeros((4, 1), jnp.int32)
-    return audit_jitted("serve.decode_gqa", model._step,
-                        (model._lps, model._globals, cache, toks))
+    step = jax.jit(model._step.__wrapped__, donate_argnums=(2,))
+    return audit_jitted("serve.decode_gqa", step,
+                        (model._lps, model._globals, cache["attn"],
+                         cache["pos"], toks), donate_argnums=(2,))
 
 
 def entry_train_step() -> EntryResult:

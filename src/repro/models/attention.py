@@ -224,9 +224,13 @@ def self_attention(cfg, p, x, *, cache=None, cache_pos=None, capture=None):
             k = apply_rope(k, posq, cfg.rope_theta)
         slot = (cache_pos % sc) if window else jnp.minimum(cache_pos, sc - 1)
         if vec:
-            # per-slot scatter: slot i writes its own row
-            ck = cache["k"].at[jnp.arange(b), slot].set(k[:, 0])
-            cv = cache["v"].at[jnp.arange(b), slot].set(v[:, 0])
+            # slot i writes its own row, as a select over the cache axis:
+            # the TPU compiler keeps the buffer (positions minor) in place,
+            # where a per-row scatter makes it convert the whole buffer
+            # out of its layout and back
+            hit = (jnp.arange(sc)[None, :] == slot[:, None])[..., None, None]
+            ck = jnp.where(hit, k.astype(cache["k"].dtype), cache["k"])
+            cv = jnp.where(hit, v.astype(cache["v"].dtype), cache["v"])
         else:
             ck = jax.lax.dynamic_update_slice_in_dim(cache["k"], k, slot,
                                                      axis=1)

@@ -440,7 +440,12 @@ def decode_step(cfg, params, cache, tokens):
                                      positions=positions))
     kind = block_kind(cfg)
 
-    def body(x, lp):
+    # The stacked self-attention K/V ride in the scan's carry, and layer i
+    # reads and writes back its own slice: passed as xs and restacked as
+    # ys, every layer's slice is copied out and back. Other caches stay
+    # per-layer xs/ys.
+    def body(carry, lp):
+        x, kv, i = carry
         x = constrain_batch(x)
         new_c = {}
         if kind == "ssm":
@@ -448,10 +453,15 @@ def decode_step(cfg, params, cache, tokens):
             y, new_c["ssm"] = ssm_mod.ssm_decode_step(cfg, lp["ssm"], h,
                                                       lp["cache_ssm"])
             x = x + y
-            return x, new_c
+            return (x, kv, i + 1), new_c
         h = apply_norm(cfg, lp["ln1"], x)
-        a, new_c["attn"] = attn_mod.self_attention(
-            cfg, lp["attn"], h, cache=lp["cache_attn"], cache_pos=pos)
+        layer_kv = jax.tree.map(
+            lambda a: jax.lax.dynamic_index_in_dim(a, i, keepdims=False), kv)
+        a, layer_kv = attn_mod.self_attention(
+            cfg, lp["attn"], h, cache=layer_kv, cache_pos=pos)
+        kv = jax.tree.map(
+            lambda a, u: jax.lax.dynamic_update_index_in_dim(a, u, i, 0),
+            kv, layer_kv)
         if kind == "hybrid":
             m, new_c["ssm"] = ssm_mod.ssm_decode_step(cfg, lp["ssm"], h,
                                                       lp["cache_ssm"])
@@ -467,15 +477,14 @@ def decode_step(cfg, params, cache, tokens):
         else:
             f = ffn_mod.ffn_apply(cfg, lp["ffn"], h2)
         x = x + f
-        return x, new_c
+        return (x, kv, i + 1), new_c
 
     scan_in = dict(params["layers"])
-    if "attn" in cache:
-        scan_in["cache_attn"] = cache["attn"]
     if "ssm" in cache:
         scan_in["cache_ssm"] = cache["ssm"]
     if kind == "decoder":
         scan_in["cache_cross"] = cache["cross"]
+    carry = (x, cache.get("attn"), jnp.int32(0))
 
     if cfg.cross_attn_every:
         every = cfg.cross_attn_every
@@ -483,30 +492,28 @@ def decode_step(cfg, params, cache, tokens):
         grouped = jax.tree.map(lambda a: a.reshape(g, every, *a.shape[1:]),
                                scan_in)
 
-        def group_body(x, gp):
-            def inner(x, lp1):
-                return body(x, lp1)
-            x, new_c = jax.lax.scan(inner, x, gp["layers"])
+        def group_body(carry, gp):
+            (x, kv, i), new_c = jax.lax.scan(body, carry, gp["layers"])
             hx = apply_norm(cfg, gp["cross"]["lnx"], x)
             x = x + attn_mod.cross_attention(cfg, gp["cross"]["xattn"], hx,
                                              gp["kv"])
-            return x, new_c
+            return (x, kv, i), new_c
 
-        x, new_caches = jax.lax.scan(
-            group_body, x,
+        (x, new_attn, _), new_caches = jax.lax.scan(
+            group_body, carry,
             {"layers": grouped, "cross": params["cross"],
              "kv": cache["cross"]})
         new_caches = jax.tree.map(
             lambda a: a.reshape(cfg.num_layers, *a.shape[2:]), new_caches)
     else:
-        x, new_caches = jax.lax.scan(body, x, scan_in)
+        (x, new_attn, _), new_caches = jax.lax.scan(body, carry, scan_in)
 
     x = apply_norm(cfg, params["final_norm"], x)
     logits = unembed(cfg, params["embed"], params.get("head", {}), x)
     new_cache = dict(cache)
     new_cache["pos"] = pos + 1
-    if "attn" in new_caches:
-        new_cache["attn"] = new_caches["attn"]
+    if "attn" in cache:
+        new_cache["attn"] = new_attn
     if "ssm" in new_caches:
         new_cache["ssm"] = new_caches["ssm"]
     return logits, new_cache

@@ -44,9 +44,10 @@ parameter reloads) and routes each request by its latency class to the
 smallest member target meeting the class's speedup demand — strictest
 latency gets the fastest member, relaxed traffic keeps dense quality.
 
-Faults: the per-step ``serve.step`` site is retried from the untouched
-functional cache (see ``ServeEngine._step_once``), so chaos-tier runs
-recover bit-identically.
+Faults: the decode step donates the slot cache's K/V off the CPU, so a
+detected non-finite ``serve.step`` is retried from the failed attempt's
+K/V with the pre-step positions, which the step rewrites before reading
+(see ``ServeEngine._step_once``); chaos-tier runs recover bit-identically.
 """
 from .engine import (DenseServeModel, PrunedServeModel, RequestRecord,
                      ServeEngine, ServeReport)

@@ -42,6 +42,19 @@ def _bucket(s: int, max_len: int) -> int:
     return min(b, max_len)
 
 
+def _donate_kv() -> bool:
+    """Whether the decode step donates the slot cache's K/V, so that the
+    step's output K/V are written in place: off the CPU only, like
+    ``hessian._donate``."""
+    return jax.default_backend() != "cpu"
+
+
+def _kv_consumed(cache) -> bool:
+    """Whether a dispatch consumed ``cache``'s K/V in place (donated)."""
+    leaves = jax.tree.leaves(cache.get("attn"))
+    return bool(leaves) and leaves[0].is_deleted()
+
+
 def _kv_bytes(cache) -> int:
     """Total KV byte footprint of a slot cache (dense stack or pruned
     per-layer list; ``None`` entries of dropped layers cost nothing)."""
@@ -64,8 +77,8 @@ class DenseServeModel:
         # the jitted functions' names are the executables' names in a
         # profiler trace (jit_serve_decode, jit_serve_prefill,
         # jit_serve_insert), the same in both adapters
-        def serve_decode(p, c, t):
-            return decode_step(cfg, p, c, t)
+        def serve_decode(p, kv, pos, t):
+            return decode_step(cfg, p, {"pos": pos, "attn": kv}, t)
 
         def serve_insert(cache, row, slot, pos):
             return {
@@ -78,7 +91,8 @@ class DenseServeModel:
                 },
             }
 
-        self._step = jax.jit(serve_decode)
+        self._step = jax.jit(serve_decode,
+                             donate_argnums=(1,) if _donate_kv() else ())
         self._insert = jax.jit(serve_insert)
 
     def init_slots(self, nslots: int):
@@ -116,7 +130,7 @@ class DenseServeModel:
                             jnp.asarray(pos, jnp.int32))
 
     def step(self, cache, tokens):
-        return self._step(self.params, cache, tokens)
+        return self._step(self.params, cache["attn"], cache["pos"], tokens)
 
 
 class PrunedServeModel:
@@ -143,8 +157,9 @@ class PrunedServeModel:
             return PrunedModel(cfg=cfg, layers=layers, globals_=globals_)
 
         # named as in DenseServeModel
-        def serve_decode(lps, globals_, cache, toks):
-            return decode_step_pruned(rebuild(lps, globals_), cache, toks)
+        def serve_decode(lps, globals_, kv, pos, toks):
+            return decode_step_pruned(rebuild(lps, globals_),
+                                      {"pos": pos, "attn": kv}, toks)
 
         def serve_prefill(lps, globals_, toks, last):
             logits, cache = prefill_pruned(rebuild(lps, globals_), toks,
@@ -164,7 +179,8 @@ class PrunedServeModel:
                                  "v": buf["v"].at[slot].set(rbuf["v"][0])})
             return {"pos": cache["pos"].at[slot].set(pos), "attn": attn}
 
-        self._step = jax.jit(serve_decode)
+        self._step = jax.jit(serve_decode,
+                             donate_argnums=(2,) if _donate_kv() else ())
         self._prefill_jit: Dict[int, Callable] = {}
         self._prefill_fn = serve_prefill
         self._insert = jax.jit(serve_insert)
@@ -189,7 +205,8 @@ class PrunedServeModel:
                             jnp.asarray(pos, jnp.int32))
 
     def step(self, cache, tokens):
-        return self._step(self._lps, self._globals, cache, tokens)
+        return self._step(self._lps, self._globals, cache["attn"],
+                          cache["pos"], tokens)
 
 
 @dataclass
@@ -248,6 +265,8 @@ class ServeReport:
     wall_s: float                 # busy wall-clock (prefills + steps)
     steps: int                    # decode steps executed
     kv_cache_bytes: int
+    # decode steps whose K/V input the step consumed in place (donated)
+    donated_steps: int = 0
     # host-clock seconds since the start of ``run`` at which each decode
     # step's logits were on the host
     step_end: List[float] = field(default_factory=list)
@@ -294,7 +313,8 @@ class ServeReport:
              "wall_s": self.wall_s,
              "prefill_ms_mean": self.prefill_ms_mean,
              "decode_ms_per_token_mean": self.decode_ms_per_token_mean,
-             "kv_cache_bytes": self.kv_cache_bytes}
+             "kv_cache_bytes": self.kv_cache_bytes,
+             "donated_steps": self.donated_steps}
         d.update(self.latency_percentiles())
         return d
 
@@ -363,24 +383,30 @@ class ServeEngine:
             toks = jnp.zeros((self.num_slots, 1), jnp.int32)
             # sync: warmup barrier — wait for each bucket's compile
             jax.block_until_ready(self.model.step(cache, toks)[0])
-        # warmup state is discarded; self.cache was never mutated
+        # warmup state is discarded; self.cache was never mutated (the
+        # insert does not donate, so a step consumes only the insert's
+        # output)
 
     # ------------------------------------------------------------------
     # fault-handled decode step (site: serve.step)
     # ------------------------------------------------------------------
 
     def _step_once(self, tokens: np.ndarray, active_slots: List[int]):
-        """One decode step with bounded retries.
+        """One decode step with bounded retries; returns the host logits
+        and whether the step consumed its K/V input in place.
 
-        The functional cache update makes recovery trivial: a detected
-        fault (injected raise/OSError, or non-finite logits on an active
-        slot from nan/inf poison) discards the candidate ``(logits,
-        cache)`` and recomputes from the untouched previous cache —
-        recovered runs are bit-identical to clean ones. ``delay`` faults
-        are absorbed into the measured step latency.
+        Off the CPU the step donates the cache's K/V (``_donate_kv``), so
+        the previous cache is gone once the step is dispatched. A detected
+        non-finite step (nan/inf poison on an active slot) is retried from
+        the candidate's K/V with the pre-step positions, which are never
+        donated. That is exact: a step writes only position ``pos[s]`` of
+        each slot ``s``, before any read of it, so the retry overwrites the
+        failed attempt's rows with the same values and recovered runs are
+        bit-identical to clean ones. An injected raise/OSError comes before
+        the dispatch and donates nothing. ``delay`` faults are absorbed
+        into the measured step latency.
         """
         rep = current_report()
-        old_cache = self.cache
         for attempt in range(_STEP_RETRIES):
             try:
                 mult = _faults.poison_scalar("serve.step")
@@ -388,11 +414,13 @@ class ServeEngine:
                 rep.count("detected", "serve.step")
                 rep.count("retries", "serve.step")
                 continue
+            cache = self.cache
             with _span("serve.dispatch"):
                 toks = jnp.asarray(tokens.reshape(-1, 1), jnp.int32)
-                logits, new_cache = self.model.step(old_cache, toks)
+                logits, new_cache = self.model.step(cache, toks)
                 if mult != 1.0:
                     logits = logits * mult
+            donated = _kv_consumed(cache)
             with _span("serve.wait"):
                 # sync: one pull per decode step — greedy sampling and the
                 # serve.step finite check both need host logits anyway
@@ -404,11 +432,12 @@ class ServeEngine:
             if not finite:
                 rep.count("detected", "serve.step")
                 rep.count("retries", "serve.step")
+                self.cache = dict(new_cache, pos=cache["pos"])
                 continue
             if attempt:
                 rep.count("recovered", "serve.step")
             self.cache = new_cache
-            return lg
+            return lg, donated
         raise RuntimeError(
             f"serve.step produced unusable output {_STEP_RETRIES} times "
             "in a row — fault is not transient")
@@ -449,6 +478,7 @@ class ServeEngine:
         last_tok = np.zeros(self.num_slots, np.int64)
         remaining: Dict[int, int] = {}
         step_end: List[float] = []
+        donated_steps = 0
         t = 0.0
         busy = 0.0
 
@@ -502,12 +532,13 @@ class ServeEngine:
             step = len(step_end)
             with _span("serve.step", step=step, active=len(slots)):
                 t0 = self.clock()
-                lg = self._step_once(last_tok, slots)
+                lg, donated = self._step_once(last_tok, slots)
                 t1 = self.clock()
                 dt = t1 - t0
                 t += dt
                 busy += dt
                 step_end.append(t1 - t_run)
+                donated_steps += donated
                 with _span("serve.sample"):
                     toks = [int(np.argmax(lg[slot, 0])) for slot in slots]
                 with _span("serve.bookkeep"):
@@ -526,4 +557,4 @@ class ServeEngine:
         return ServeReport(records=[records[r.rid] for r in requests],
                            wall_s=busy, steps=len(step_end),
                            kv_cache_bytes=self.kv_cache_bytes,
-                           step_end=step_end)
+                           donated_steps=donated_steps, step_end=step_end)

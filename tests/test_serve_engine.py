@@ -340,12 +340,90 @@ def test_executables_have_stable_names(tiny_cfg, tiny_params, mag_db,
         i32 = jnp.asarray(0, jnp.int32)
         toks = jnp.zeros((2, 1), jnp.int32)
         padded = jnp.zeros((1, 8), jnp.int32)
-        assert module(model._step, *weights, eng.cache, toks) \
-            == "@jit_serve_decode"
+        assert module(model._step, *weights, eng.cache["attn"],
+                      eng.cache["pos"], toks) == "@jit_serve_decode"
         assert module(model._prefill_jit[8], *weights, padded, i32) \
             == "@jit_serve_prefill"
         assert module(model._insert, eng.cache, row, i32, i32) \
             == "@jit_serve_insert"
+
+
+# ----------------------------------------------------------------------
+# the decode step's K/V: a per-slot masked write, donated off the CPU
+# ----------------------------------------------------------------------
+
+@pytest.mark.parametrize("cache_dtype", [jnp.float32, jnp.bfloat16])
+def test_per_slot_write_equals_scatter(tiny_cfg, tiny_params, cache_dtype):
+    """The per-slot K/V write of a vector-``pos`` decode equals a scatter
+    of each slot's new K/V row at ``min(pos, sc - 1)``, over the whole
+    returned cache, for mixed positions with one slot at ``sc - 1`` and one
+    past it (the clamp); attention then reads the same values."""
+    from repro.models import attention as attn_mod
+    cfg = tiny_cfg
+    p = jax.tree.map(lambda a: a[0], tiny_params["layers"])["attn"]
+    sc, dh, h = 16, cfg.resolved_head_dim, cfg.num_kv_heads
+    pos = jnp.asarray([0, 5, sc - 1, sc + 3], jnp.int32)
+    b = pos.shape[0]
+    ks = jax.random.split(jax.random.key(2), 3)
+    x = jax.random.normal(ks[0], (b, 1, cfg.d_model), jnp.float32)
+    cache = {n: jax.random.normal(k, (b, sc, h, dh)).astype(cache_dtype)
+             for n, k in zip("kv", ks[1:])}
+    out, got = attn_mod.self_attention(cfg, p, x, cache=cache,
+                                       cache_pos=pos)
+    _, k, v = attn_mod._project_qkv(cfg, p, x, x)
+    row = jnp.minimum(pos, sc - 1)
+    want = {"k": cache["k"].at[jnp.arange(b), row].set(
+                k[:, 0].astype(cache_dtype)),
+            "v": cache["v"].at[jnp.arange(b), row].set(
+                v[:, 0].astype(cache_dtype))}
+    for n in "kv":
+        assert got[n].dtype == cache_dtype
+        np.testing.assert_array_equal(np.asarray(got[n]),
+                                      np.asarray(want[n]))
+    out_want, _ = attn_mod.self_attention(cfg, p, x, cache=want,
+                                          cache_pos=pos)
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(out_want))
+
+
+@pytest.mark.parametrize("donate", [False, True])
+@pytest.mark.parametrize("kind", ["dense", "member"])
+def test_engine_under_kv_donation(monkeypatch, tiny_cfg, tiny_params,
+                                  mag_db, kind, donate):
+    """With the decode step's K/V donated (forced on the CPU) or not, each
+    adapter serves the tokens of an undonated engine, a ``serve.step`` nan
+    recovers bit-identically from the failed attempt's K/V, ``warmup``
+    leaves the engine's cache alive, and ``donated_steps`` counts every
+    decode step that consumed its K/V in place."""
+    from repro.serve import engine as engine_mod
+    pm = shrink(tiny_cfg, tiny_params, mag_db,
+                _half_heads_assignment(tiny_cfg, mag_db))
+
+    def new_engine():
+        model = (DenseServeModel(tiny_cfg, tiny_params, MAX_LEN)
+                 if kind == "dense" else PrunedServeModel(pm, MAX_LEN))
+        eng = ServeEngine(model, num_slots=2)
+        eng.warmup((8, 16))
+        return eng
+
+    reqs = _requests(tiny_cfg, n=4, seed=13)
+    ref = new_engine().run(reqs)
+    assert ref.donated_steps == 0
+    monkeypatch.setattr(engine_mod, "_donate_kv", lambda: donate)
+    eng = new_engine()
+    assert not any(x.is_deleted() for x in jax.tree.leaves(eng.cache))
+    out = eng.run(reqs)
+    assert out.as_dict()["donated_steps"] == out.donated_steps
+    rep = RobustnessReport()
+    with install(FaultPlan.parse("serve.step:nan@2")), report_scope(rep):
+        healed = new_engine().run(reqs)
+    assert rep.counts["recovered"].get("serve.step", 0) == 1
+    # arrivals are admitted on a clock of measured times, so the number of
+    # steps may differ from run to run; every step donates or none does
+    for r in (out, healed):
+        assert r.steps > 0
+        assert r.donated_steps == (r.steps if donate else 0)
+    for a, b, c in zip(ref.records, out.records, healed.records):
+        assert a.tokens == b.tokens == c.tokens, f"rid={a.rid}"
 
 
 @pytest.mark.chaos

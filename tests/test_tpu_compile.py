@@ -107,3 +107,89 @@ def test_gpt2_ffn_db_chunk_fits_one_v5e(one_chip):
             + ma.temp_size_in_bytes - ma.alias_size_in_bytes)
     assert used <= k * per
     assert used < TPU_V5E.hbm_bytes
+
+
+SLOTS, SLOT_LEN = 64, 1024          # the serving cells' slot cache
+
+
+def _on(sharding, tree):
+    return jax.tree.map(lambda s: jax.ShapeDtypeStruct(
+        s.shape, s.dtype, sharding=sharding), tree)
+
+
+def _dense_decode(cfg):
+    from repro.models import model_init
+    from repro.serve.engine import DenseServeModel
+    params = jax.eval_shape(lambda k: model_init(cfg, k)[0],
+                            jax.random.key(0))
+    model = DenseServeModel(cfg, params, SLOT_LEN)
+    return model, (params,)
+
+
+def _member_decode(cfg, heads):
+    """A member of ``cfg`` whose layer i keeps ``heads[i]`` heads and its
+    MLP, as ``jax.eval_shape`` structs."""
+    from repro.models import model_init
+    from repro.models.pruned import PrunedLayer, PrunedModel
+    from repro.serve.engine import PrunedServeModel
+    dh = cfg.resolved_head_dim
+
+    def member(key):
+        p = model_init(cfg, key)[0]
+        lps = []
+        for i, h in enumerate(heads):
+            lp = jax.tree.map(lambda a: a[i], p["layers"])
+            a = lp["attn"]
+            lp["attn"] = {"wq": a["wq"][:, :h * dh], "wk": a["wk"][:, :h * dh],
+                          "wv": a["wv"][:, :h * dh], "wo": a["wo"][:h * dh]}
+            lps.append(lp)
+        return lps, {k: v for k, v in p.items() if k != "layers"}
+
+    lps, globals_ = jax.eval_shape(member, jax.random.key(0))
+    pm = PrunedModel(cfg, [PrunedLayer(kv_groups=h, d_ff=cfg.d_ff, params=lp)
+                           for h, lp in zip(heads, lps)], globals_)
+    model = PrunedServeModel(pm, SLOT_LEN)
+    return model, (model._lps, model._globals)
+
+
+@pytest.mark.parametrize("adapter", ["dense", "member"])
+def test_serve_decode_writes_kv_in_place(one_chip, monkeypatch, adapter):
+    """Both adapters' ``jit_serve_decode`` at the serving cells' widths
+    (GPT-2 small, 64 slots of 1,024; depth cut to 2 dense layers, or a
+    member with layers of 12, 2 and 1 heads) convert no K/V buffer or
+    stacked cache out of its layout, and donate the K/V whole: a per-slot
+    scatter made the compiler copy every buffer out of its layout and
+    back, and the dense layer scan copied each layer's slice out and back.
+    An asynchronous copy (``copy-start``) that keeps the layout only moves
+    a buffer between on-chip and device memory."""
+    import re
+
+    from repro.serve import engine
+    monkeypatch.setattr(engine, "_donate_kv", lambda: True)
+    if adapter == "dense":
+        cfg = GPT2_SMALL.replace(num_layers=2)
+        model, weights = _dense_decode(cfg)
+    else:
+        cfg = GPT2_SMALL.replace(num_layers=3)
+        model, weights = _member_decode(cfg, (12, 2, 1))
+    cache = jax.eval_shape(lambda: model.init_slots(SLOTS))
+    args = _on(one_chip, (*weights, cache["attn"], cache["pos"],
+                          jax.ShapeDtypeStruct((SLOTS, 1), jnp.int32)))
+    c = model._step.lower(*args).compile()
+    kv = jax.tree.leaves(cache["attn"])
+    # a buffer, and for the stacked cache also one layer's slice of it
+    shapes = {s for x in kv for s in (
+        (x.shape, x.shape[1:], (1, *x.shape[1:])) if x.ndim == 5
+        else (x.shape,))}
+    kv_shapes = {"bf16[%s]" % ",".join(map(str, s)) for s in shapes}
+    text = c.as_text()
+    copies = re.findall(r"= (bf16\[[\d,]*\])\S* copy\(", text)
+    assert not kv_shapes & set(copies), sorted(copies)
+    moves = re.findall(r"= \((bf16\[[\d,]*\])(\{[^}]*\}), bf16\[[\d,]*\]"
+                       r"(\{[^}]*\}), u32\[\]\S*\) copy-start\(", text)
+    layout = lambda s: re.sub(r"S\(\d+\)", "", s)  # noqa: E731
+    converted = [m for m in moves
+                 if m[0] in kv_shapes and layout(m[1]) != layout(m[2])]
+    assert not converted, converted
+    kv_bytes = sum(x.size * x.dtype.itemsize for x in kv)
+    assert c.memory_analysis().alias_size_in_bytes == kv_bytes
