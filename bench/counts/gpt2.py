@@ -1,15 +1,21 @@
-"""Operations and bytes that any implementation must spend, from shapes.
+"""Operations and bytes that any implementation of GPT-2 must spend, from
+shapes: the counts of the configurations whose ``reference`` is ``gpt2``.
 
 The counts are the least work of the job, never what the program happens
 to run: padding, recomputation and copies are not counted, so a program that
 removes waste moves towards 100% of its roofline and never past it.
 
-A GPT-2 configuration here is the plain dict of ``bench/configs/<name>.json``
-with its per-layer widths (``weights.layer_widths``).
+The serving job calls two functions of a counts module,
+``decode_steps(cfg, positions)`` and ``prefill_flops(cfg, s)``, with the
+configuration's plain dict (``bench/configs/<name>.json``; here with its
+per-layer widths, ``weights.layer_widths``). Every architecture has its own
+``bench/counts/<reference>.py`` with the two.
 """
 from __future__ import annotations
 
 from typing import Dict, List, Tuple
+
+import numpy as np
 
 from weights import layer_widths
 
@@ -63,13 +69,19 @@ def kv_bytes_per_position(cfg: Dict) -> int:
     return sum(2 * hd * COMPUTE_BYTES for hd, _ in _layer_sizes(cfg))
 
 
-def decode_steps(cfg: Dict, n, pos_sum):
-    """(operations, bytes) of batched decode steps, elementwise over arrays
-    of each step's active slots ``n`` and the sum ``pos_sum`` of the
-    positions they feed (a token at position p attends p + 1 keys): the
-    weights read once at compute-type bytes plus each active slot's
-    position row, the K/V of the positions each slot holds, and one new
-    K/V position written per slot."""
+def decode_steps(cfg: Dict, positions):
+    """(operations, bytes) of batched decode steps, one entry per row of
+    ``positions`` (steps, slots): the position each active slot feeds at
+    that step, -1 where a slot is empty. A token at position p attends
+    p + 1 keys in every layer, so a step needs only its active slots ``n``
+    and the sum ``pos_sum`` of their positions: the weights read once at
+    compute-type bytes plus each active slot's position row, the K/V of
+    the positions each slot holds, and one new K/V position written per
+    slot."""
+    positions = np.asarray(positions, np.int64)
+    active = positions >= 0
+    n = active.sum(1)
+    pos_sum = np.where(active, positions, 0).sum(1)
     kv = kv_bytes_per_position(cfg)
     flops = (n * (matmul_flops_per_token(cfg) + head_flops(cfg))
              + attn_flops(cfg, 1) * (pos_sum + n))

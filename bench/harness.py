@@ -4,10 +4,14 @@ check, the compile cache and the result line.
 A cell of ``BENCHMARK.json`` names a configuration and a traffic mix; they
 are ``bench/configs/<config>.json`` and ``bench/traffic/<traffic>.json``.
 The mix names its job (``bench/<job>.py``), and the configuration its
-family (``bench/families/<family>.py``) and reference
-(``bench/references/<reference>.py``). A per-layer metric is
-``bench/metrics/<name>.py`` with a ``read(run)`` that returns a number, or
-None where the run holds nothing for it to read.
+family (``bench/families/<family>.py``: the program's side) and its
+reference, the architecture's yardstick, in two files of one name: the
+plain reference (``bench/references/<reference>.py``) and the least-work
+counts (``bench/counts/<reference>.py``). Each of the three gets the
+configuration's dict whole, so a new architecture joins by new files
+alone. A per-layer metric is ``bench/metrics/<name>.py`` with a
+``read(run)`` that returns a number, or None where the run holds nothing
+for it to read.
 """
 from __future__ import annotations
 

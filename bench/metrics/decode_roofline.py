@@ -1,8 +1,8 @@
 """Share of its roofline that the decode step reaches, in %: the least time
 of all decode steps in the window (per step the larger of its required
-operations over peak FLOP/s and its required bytes over peak bandwidth;
-``bench/counts.py``) over their device time. Which bound holds is
-``run.decode_bound``."""
+operations over peak FLOP/s and its required bytes over peak bandwidth,
+from the configuration's counts module ``bench/counts/<reference>.py``)
+over their device time. Which bound holds is ``run.decode_bound``."""
 import trace_reduce as T
 
 

@@ -1,7 +1,8 @@
 """Whole-step utilisation of the chip's bf16 peak, in %: the operations the
-window's prompts and generated tokens require (``bench/counts.py``: LM head
-at the last prompt position only, attention over the positions held) over
-the window's host seconds times the peak."""
+window's prompts and generated tokens require (the configuration's counts
+module ``bench/counts/<reference>.py``; for GPT-2 the LM head at the last
+prompt position only, attention over the positions held) over the
+window's host seconds times the peak."""
 
 
 def read(run):

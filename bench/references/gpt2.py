@@ -15,6 +15,7 @@ bfloat16 compute.
 from __future__ import annotations
 
 import math
+from typing import Dict
 
 import jax
 import jax.numpy as jnp
@@ -67,12 +68,14 @@ def forward(w, tokens, *, head_dim: int, eps: float, quant=None):
     return _mm(x, w["embed"].T, quant)
 
 
-def make_gaps(head_dim: int, eps: float, control: bool = False):
+def make_gaps(cfg: Dict, control: bool = False):
     """A jitted ``(w, tokens, served, score) -> gaps`` over one padded
-    sequence: at each position where ``score`` holds, the reference's best
-    logit minus its logit of ``served`` (the token the program produced
-    from that position). With ``control`` it also returns the same gap for
-    the token that the fp8 control puts first."""
+    sequence, for the configuration's plain dict ``cfg`` (its ``head_dim``
+    and ``layer_norm_epsilon``): at each position where ``score`` holds,
+    the reference's best logit minus its logit of ``served`` (the token the
+    program produced from that position). With ``control`` it also returns
+    the same gap for the token that the fp8 control puts first."""
+    head_dim, eps = int(cfg["head_dim"]), float(cfg["layer_norm_epsilon"])
 
     def gaps(w, tokens, served, score):
         with jax.default_matmul_precision("highest"):

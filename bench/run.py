@@ -69,8 +69,11 @@ def make_ctx(args) -> Ctx:
     ctx.cfg = harness.load_json(BENCH, "configs", cell["config"])
     ctx.mix = harness.load_json(BENCH, "traffic", cell["traffic"])
     ctx.family = harness.load_module(BENCH, "families", ctx.cfg["family"])
+    # the architecture's yardstick: its reference and its counts share the
+    # configuration's ``reference`` name
     ctx.reference = harness.load_module(BENCH, "references",
                                         ctx.cfg["reference"])
+    ctx.counts = harness.load_module(BENCH, "counts", ctx.cfg["reference"])
     ctx.job = harness.load_module(BENCH, ".", ctx.mix["job"])
     ctx.peaks = harness.load_peaks(BENCH)
     ctx.seed, ctx.seconds = args.seed, args.seconds

@@ -33,7 +33,6 @@ from typing import Dict, List
 
 import numpy as np
 
-import counts
 import harness
 import trace_reduce as T
 import traffic
@@ -44,8 +43,8 @@ WARM_STREAM = 2**20    # stream index of the warm-up requests
 
 class Recorder:
     """The engine's model adapter, passed through; it counts decode steps,
-    records each admission's step index and prompt length, and in a traced
-    run names each call with a host span."""
+    records each admission's step index, prompt length and slot, and in a
+    traced run names each call with a host span."""
 
     def __init__(self, model, traced: bool):
         import jax
@@ -57,19 +56,23 @@ class Recorder:
 
     def reset(self):
         self.steps = 0
-        self.admissions: List = []      # (decode steps before, prompt_len)
+        # (decode steps before, prompt_len, slot); the slot comes with the
+        # insert that follows each prefill
+        self.admissions: List = []
         self.t_last_admit = None
 
     def init_slots(self, nslots: int):
         return self.model.init_slots(nslots)
 
     def prefill(self, tokens):
-        self.admissions.append((self.steps, int(tokens.shape[0])))
+        self.admissions.append((self.steps, int(tokens.shape[0]), None))
         self.t_last_admit = time.perf_counter()
         with self._span("bench.prefill"):
             return self.model.prefill(tokens)
 
     def insert(self, cache, row, slot, pos):
+        a, s, _ = self.admissions[-1]
+        self.admissions[-1] = (a, s, int(slot))
         with self._span("bench.insert"):
             return self.model.insert(cache, row, slot, pos)
 
@@ -79,20 +82,18 @@ class Recorder:
             return self.model.step(cache, tokens)
 
 
-def step_positions(admissions, reqs, nsteps: int):
-    """Per decode step, the number of active slots and the sum of the
-    positions they feed, rebuilt from the admissions: request r, admitted
-    after a_r steps with a prompt of s_r tokens, feeds position s_r + j at
-    step a_r + j for j < steps_r - 1 (the engine admits in request order)."""
-    n = np.zeros(nsteps + 1, np.int64)
-    pos = np.zeros(nsteps + 1, np.int64)
-    for (a, s), r in zip(admissions, reqs):
-        k = r.steps - 1
-        if k <= 0:
-            continue
-        n[a:a + k] += 1
-        pos[a:a + k] += s + np.arange(k)
-    return n[:nsteps], pos[:nsteps]
+def step_positions(admissions, reqs, nsteps: int, slots: int) -> np.ndarray:
+    """The position each slot feeds at each decode step, (nsteps, slots),
+    -1 where the slot is empty, rebuilt from the admissions: request r,
+    admitted into slot c_r after a_r steps with a prompt of s_r tokens,
+    feeds position s_r + j at step a_r + j for j < steps_r - 1 (the engine
+    admits in request order)."""
+    pos = np.full((nsteps, slots), -1, np.int64)
+    for (a, s, c), r in zip(admissions, reqs):
+        k = min(r.steps - 1, nsteps - a)
+        if k > 0:
+            pos[a:a + k, c] = s + np.arange(k)
+    return pos
 
 
 def _requests(reqs):
@@ -146,7 +147,9 @@ def replay_gaps(gaps_fn, w, reqs, recs, idx, max_len: int, control=False):
 def run(ctx) -> Dict:
     """One run of a serving cell; returns the result (without checks) and
     the checks. ``ctx`` carries the parsed arguments, the configuration,
-    the mix, the family and reference modules, peaks and the start time."""
+    the mix, the family, reference and counts modules, peaks and the start
+    time. Of the configuration it reads only ``vocab_size`` and ``check``;
+    the three modules get the whole dict."""
     import jax
 
     from repro.robustness.report import report_scope
@@ -224,9 +227,7 @@ def run(ctx) -> Dict:
     del engine, rec, model, streams
     gc.collect()
     w = plain_weights()
-    gaps_fn = ctx.reference.make_gaps(cfg["head_dim"],
-                                      cfg["layer_norm_epsilon"],
-                                      control=ctx.control)
+    gaps_fn = ctx.reference.make_gaps(cfg, control=ctx.control)
     done = [i for i, (r, x) in enumerate(zip(reqs, recs))
             if len(x.tokens) == r.steps]
     idx = [done[i] for i in check_sample([reqs[i] for i in done],
@@ -267,6 +268,8 @@ def run(ctx) -> Dict:
     }
     if ctx.trace:
         result["run"] = traced = serve_run(ctx, meta, seconds)
+        notes["decode_least_s"] = traced["decode_least_s"]
+        notes["required_flops"] = traced["required_flops"]
         # what the readers chose from: the costliest executables, each with
         # its device seconds and executions, against the decode calls
         mods = T.module_counts(traced["reduced"], traced["windows"])
@@ -277,24 +280,34 @@ def run(ctx) -> Dict:
 
 
 def serve_run(ctx, meta, seconds: float):
-    """What the per-layer readers of a traced serving run read."""
-    cfg, peaks = ctx.cfg, ctx.peaks_dev
+    """What the per-layer readers of a traced serving run read. Besides the
+    trace and the harness's own sums, a reader that counts a kernel's
+    least work of its own finds the configuration's counts module
+    (``counts``), each stream's positions, (steps, slots) with -1 for an
+    empty slot (``positions``), and each stream's prompt lengths in
+    admission order (``prompts``)."""
+    cfg, peaks, counts = ctx.cfg, ctx.peaks_dev, ctx.counts
     reduced = T.reduce_xplane(T.latest_xplane(ctx.trace_dir))
     windows = T.windows_of(reduced, STREAM_SPAN)
     steps = sum(m[1] for m in meta)
     admissions = sum(len(m[2]) for m in meta)
+    slots = int(ctx.mix["slots"])
+    positions = [step_positions(adm, reqs, nsteps, slots)
+                 for reqs, nsteps, adm in meta]
+    prompts = [np.array([s for _, s, _ in adm], np.int64)
+               for _, _, adm in meta]
     least, bound_s, flops = 0.0, {"compute": 0.0, "memory": 0.0}, 0
-    for reqs, nsteps, adm in meta:
-        n, pos = step_positions(adm, reqs, nsteps)
-        f, b = counts.decode_steps(cfg, n, pos)
+    for pos, lens in zip(positions, prompts):
+        f, b = counts.decode_steps(cfg, pos)
         tc, tm = f / peaks["bf16_flops"], b / peaks["hbm_bytes_per_s"]
         least += float(np.maximum(tc, tm).sum())
         bound_s["compute"] += float(tc[tc >= tm].sum())
         bound_s["memory"] += float(tm[tm > tc].sum())
-        flops += int(f.sum()) + sum(counts.prefill_flops(cfg, s)
-                                    for _, s in adm)
+        flops += int(f.sum()) + sum(counts.prefill_flops(cfg, int(s))
+                                    for s in lens)
     return {"reduced": reduced, "windows": windows, "steps": steps,
             "admissions": admissions, "decode_least_s": least,
             "decode_bound": max(bound_s, key=bound_s.get),
             "required_flops": flops, "host_window_s": seconds,
-            "peaks": peaks}
+            "peaks": peaks, "counts": counts, "positions": positions,
+            "prompts": prompts}

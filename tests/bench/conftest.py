@@ -28,16 +28,18 @@ TINY_MIX = {"job": "serve_job", "kind": "offline_backlog", "slots": 4,
 
 
 def tiny_ctx(cfg=None, mix=None, seed=2**33 + 5, seconds=0.3,
-             control=False):
-    """A serving cell's context at tiny size, without the device check."""
+             control=False, bench=BENCH):
+    """A serving cell's context at tiny size, without the device check,
+    with the configuration's modules found by name under ``bench``."""
     import harness
     import run as R
     ctx = R.Ctx()
     ctx.cfg, ctx.mix = dict(cfg or TINY), dict(mix or TINY_MIX)
     ctx.chips = 1
-    ctx.family = harness.load_module(BENCH, "families", ctx.cfg["family"])
-    ctx.reference = harness.load_module(BENCH, "references",
+    ctx.family = harness.load_module(bench, "families", ctx.cfg["family"])
+    ctx.reference = harness.load_module(bench, "references",
                                         ctx.cfg["reference"])
+    ctx.counts = harness.load_module(bench, "counts", ctx.cfg["reference"])
     ctx.job = harness.load_module(BENCH, ".", ctx.mix["job"])
     ctx.seed, ctx.seconds = seed, seconds
     ctx.trace, ctx.control = False, control
