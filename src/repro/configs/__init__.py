@@ -17,6 +17,7 @@ from .hymba_1p5b import CONFIG as HYMBA_1P5B
 from .internlm2_20b import CONFIG as INTERNLM2_20B
 from .llama32_vision_11b import CONFIG as LLAMA32_VISION_11B
 from .mamba2_2p7b import CONFIG as MAMBA2_2P7B
+from .mellum2_12b import CONFIG as MELLUM2_12B
 from .phi35_moe_42b import CONFIG as PHI35_MOE
 from .qwen15_110b import CONFIG as QWEN15_110B
 from .qwen2_72b import CONFIG as QWEN2_72B
@@ -27,6 +28,7 @@ ARCHS = {
         DBRX_132B, PHI35_MOE, MAMBA2_2P7B, LLAMA32_VISION_11B,
         H2O_DANUBE_1P8B, QWEN15_110B, QWEN2_72B, INTERNLM2_20B,
         WHISPER_LARGE_V3, HYMBA_1P5B, BERT_BASE, BERT_LARGE, GPT2_SMALL,
+        MELLUM2_12B,
     ]
 }
 
@@ -69,6 +71,9 @@ def smoke_config(name: str) -> ModelConfig:
         kw.update(cross_attn_every=2, num_frontend_tokens=16, frontend_dim=128)
     if c.attention == "sliding_window":
         kw.update(window_size=64)
+    if c.attention_pattern:
+        # one whole period of the layer pattern
+        kw.update(num_layers=len(c.attention_pattern))
     return c.replace(**kw)
 
 

@@ -12,6 +12,20 @@ from typing import Optional, Tuple
 
 
 @dataclass(frozen=True)
+class Yarn:
+    """YaRN scaling of rotary frequencies (Peng et al. 2023), as the Hugging
+    Face ``rope_parameters`` state it: frequencies interpolated by
+    ``factor`` below the correction range that ``beta_fast``/``beta_slow``
+    give over ``original_max_position`` positions, kept above it, ramped
+    linearly between; cos and sin scaled by ``attention_factor``."""
+    factor: float
+    original_max_position: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    attention_factor: float = 1.0
+
+
+@dataclass(frozen=True)
 class ModelConfig:
     name: str
     family: str  # dense | moe | ssm | hybrid | vlm | audio | encoder
@@ -29,6 +43,11 @@ class ModelConfig:
     qkv_bias: bool = False
     rope_theta: float = 10000.0
     causal: bool = True
+    # one period of per-layer attention kinds ("full" | "sliding_window"),
+    # repeated over the depth; () means every layer is ``attention``
+    attention_pattern: Tuple[str, ...] = ()
+    # RoPE scaling of the layers whose kind is "full" (None: plain RoPE)
+    full_rope_scaling: Optional[Yarn] = None
 
     # --- ffn ---
     ffn_activation: str = "swiglu"  # swiglu | gelu
@@ -40,6 +59,10 @@ class ModelConfig:
     # on the usual 0.9^i grid; "expert" restricts each expert's level grid
     # to (0, d_ff) — keep-or-drop whole experts (router always kept full)
     moe_prune_unit: str = "width"
+    # experts whose weights this device holds, 0 .. experts_held - 1 of the
+    # num_experts the router scores (one rank of expert parallelism); 0:
+    # all of them
+    experts_held: int = 0
 
     # --- SSM (Mamba-2 / SSD) ---
     ssm_state: int = 0
@@ -82,6 +105,11 @@ class ModelConfig:
         return self.head_dim or (self.d_model // max(self.num_heads, 1))
 
     @property
+    def attention_period(self) -> Tuple[str, ...]:
+        """Attention kind of each layer of one period of the stack."""
+        return tuple(self.attention_pattern) or (self.attention,)
+
+    @property
     def d_inner(self) -> int:
         """SSM inner dim."""
         return self.ssm_expand * self.d_model
@@ -119,7 +147,8 @@ class ModelConfig:
         else:
             per_layer += attn if self.attention != "none" else 0
             if self.num_experts:
-                per_layer += n_experts * ffn_dense + d * n_experts  # + router
+                held = self.experts_held or n_experts
+                per_layer += held * ffn_dense + d * n_experts  # + router
                 active_per_layer += attn + self.num_experts_per_tok * ffn_dense
             else:
                 per_layer += ffn_dense

@@ -74,8 +74,9 @@ def _canon(obj) -> str:
 
 
 def cfg_fingerprint(cfg) -> Dict:
-    """ModelConfig as a plain JSON-able dict (full field set)."""
-    return dataclasses.asdict(cfg)
+    """ModelConfig as a plain JSON-able dict (full field set), as JSON reads
+    it back (a tuple field as a list), so that a stored key compares equal."""
+    return json.loads(_canon(dataclasses.asdict(cfg)))
 
 
 def env_fingerprint(env: cm.InferenceEnv) -> Dict:

@@ -13,9 +13,12 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from .layers import apply_rope, compute_dtype, dense_init
+from .layers import apply_rope, compute_dtype, dense_init, layer_rope
 
 NEG_INF = -1e30
+# named scope of the attention between the projections, per kind; the
+# compiler keeps it in each operation's ``op_name`` metadata
+ATTN_SCOPE = {"full": "attn_full", "sliding_window": "attn_window"}
 
 
 def attention_init(key, cfg, nlayers: int, cross: bool = False):
@@ -185,26 +188,47 @@ def _select_impl(cfg, sq, sk):
     return impl
 
 
-def self_attention(cfg, p, x, *, cache=None, cache_pos=None, capture=None):
+def self_attention(cfg, p, x, *, cache=None, cache_pos=None, capture=None,
+                   kind=None):
     """Self-attention for train/prefill (cache=None) or decode (cache given).
 
-    cache: dict(k=(B,Sc,HKV,D), v=...) — ring buffer for sliding-window.
+    kind: the layer's attention kind ("full" | "sliding_window"; default
+    ``cfg.attention``), which sets its window and its RoPE.
+    cache: dict(k=(B,Sc,HKV,D), v=...), or (B,HKV,Sc,D) where
+    :func:`heads_major` — a ring buffer for sliding-window.
     cache_pos: absolute position of the current token — a scalar int32
     (lockstep batch, the classic ``generate`` loop) or a (B,) int32 vector
     (per-slot positions, the continuous-batching serving engine; each slot
     writes its own cache row and masks its own prefix).
+    Everything between the projections (RoPE, the cache's write and read,
+    scores, softmax, weighted sum) runs under the named scope
+    ``ATTN_SCOPE[kind]``.
     Returns (out, new_cache).
     """
     b, sq, _ = x.shape
-    causal = cfg.causal
-    window = cfg.window_size if cfg.attention == "sliding_window" else 0
+    kind = kind or cfg.attention
     q, k, v = _project_qkv(cfg, p, x, x)
+    with jax.named_scope(ATTN_SCOPE.get(kind, "attn")):
+        out, new_cache = _attend(cfg, q, k, v, kind, cache, cache_pos)
+    flat = out.reshape(b, sq, -1)
+    if capture is not None:
+        capture["wo_in"] = flat
+    y = jnp.einsum("bsh,hd->bsd", flat, p["wo"].astype(x.dtype))
+    return y, new_cache
+
+
+def _attend(cfg, q, k, v, kind, cache, cache_pos):
+    """Attention of projected q, k, v (B,S,H,D) for ``self_attention``."""
+    b, sq = q.shape[:2]
+    causal = cfg.causal
+    window = cfg.window_size if kind == "sliding_window" else 0
+    theta, yarn = layer_rope(cfg, kind)
 
     if cache is None:
         if cfg.pos_emb == "rope":
             pos = jnp.arange(sq)[None, :]
-            q = apply_rope(q, pos, cfg.rope_theta)
-            k = apply_rope(k, pos, cfg.rope_theta)
+            q = apply_rope(q, pos, theta, yarn)
+            k = apply_rope(k, pos, theta, yarn)
         impl = _select_impl(cfg, sq, sq)
         if impl == "flash_lax":
             out = flash_attention_chunked(q, k, v, causal=causal,
@@ -215,27 +239,31 @@ def self_attention(cfg, p, x, *, cache=None, cache_pos=None, capture=None):
         new_cache = None
     else:
         # single-token decode: sq == 1
-        sc = cache["k"].shape[1]
+        hm = heads_major(cfg)
+        axis = kv_pos_axis(cfg)
+        sc = cache["k"].shape[axis]
         vec = jnp.ndim(cache_pos) == 1  # per-slot positions (serving engine)
         if cfg.pos_emb == "rope":
             posq = cache_pos[:, None] if vec else \
                 jnp.broadcast_to(cache_pos.reshape(1, 1), (b, 1))
-            q = apply_rope(q, posq, cfg.rope_theta)
-            k = apply_rope(k, posq, cfg.rope_theta)
+            q = apply_rope(q, posq, theta, yarn)
+            k = apply_rope(k, posq, theta, yarn)
         slot = (cache_pos % sc) if window else jnp.minimum(cache_pos, sc - 1)
+        k, v = cache_rows(cfg, k), cache_rows(cfg, v)
         if vec:
             # slot i writes its own row, as a select over the cache axis:
             # the TPU compiler keeps the buffer (positions minor) in place,
             # where a per-row scatter makes it convert the whole buffer
             # out of its layout and back
-            hit = (jnp.arange(sc)[None, :] == slot[:, None])[..., None, None]
+            hit = jnp.arange(sc)[None, :] == slot[:, None]
+            hit = hit[:, None, :, None] if hm else hit[:, :, None, None]
             ck = jnp.where(hit, k.astype(cache["k"].dtype), cache["k"])
             cv = jnp.where(hit, v.astype(cache["v"].dtype), cache["v"])
         else:
             ck = jax.lax.dynamic_update_slice_in_dim(cache["k"], k, slot,
-                                                     axis=1)
+                                                     axis=axis)
             cv = jax.lax.dynamic_update_slice_in_dim(cache["v"], v, slot,
-                                                     axis=1)
+                                                     axis=axis)
         # positions of cached entries; posb broadcasts the scalar path so
         # one mask expression covers both (identical values for scalars)
         idx = jnp.arange(sc)
@@ -252,19 +280,16 @@ def self_attention(cfg, p, x, *, cache=None, cache_pos=None, capture=None):
             valid &= kpos > posb - window
         qg = _grouped(q, cfg.num_kv_heads)
         scale = 1.0 / math.sqrt(cfg.resolved_head_dim)
-        logits = jnp.einsum("bqhgd,bkhd->bhgqk", qg, ck) * scale
+        cache_dims = "bhkd" if hm else "bkhd"
+        logits = jnp.einsum(f"bqhgd,{cache_dims}->bhgqk", qg, ck) * scale
         logits = jnp.where(valid[:, None, None, None, :],
                            logits.astype(jnp.float32), NEG_INF)
         probs = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
-        out = jnp.einsum("bhgqk,bkhd->bqhgd", probs, cv)
+        out = jnp.einsum(f"bhgqk,{cache_dims}->bqhgd", probs, cv)
         out = out.reshape(b, sq, cfg.num_heads, cfg.resolved_head_dim)
         new_cache = {"k": ck, "v": cv}
 
-    flat = out.reshape(b, sq, -1)
-    if capture is not None:
-        capture["wo_in"] = flat
-    y = jnp.einsum("bsh,hd->bsd", flat, p["wo"].astype(x.dtype))
-    return y, new_cache
+    return out, new_cache
 
 
 def cross_attention(cfg, p, x, kv_cache, *, capture=None):
@@ -304,10 +329,41 @@ def cross_kv(cfg, p, kv_x):
             "v": v.reshape(b, t, cfg.num_kv_heads, dh)}
 
 
-def init_kv_cache(cfg, batch: int, seq_len: int, nlayers: int, dtype):
-    """Allocate the self-attention KV cache (ring-buffer for SWA archs)."""
-    window = cfg.window_size if cfg.attention == "sliding_window" else 0
-    sc = min(seq_len, window) if window else seq_len
+def heads_major(cfg) -> bool:
+    """Whether the K/V cache holds each slot's heads before its positions,
+    (B, HKV, S, D), instead of (B, S, HKV, D): for heads that fill the
+    TPU's 128 lanes. A decode step's scores and weighted sum are then
+    matrix products batched over (slot, head) on the buffer as it is laid
+    out; over (B, S, HKV, D) the TPU compiler converts each layer's whole
+    buffer into that order every step."""
+    return cfg.resolved_head_dim % 128 == 0
+
+
+def kv_pos_axis(cfg) -> int:
+    """Axis of the positions in one layer's K/V cache (batch first)."""
+    return 2 if heads_major(cfg) else 1
+
+
+def cache_rows(cfg, x):
+    """Keys or values (B, S, HKV, D) in the K/V cache's order."""
+    return x.swapaxes(1, 2) if heads_major(cfg) else x
+
+
+def kv_shape(cfg, batch: int, positions: int, heads: int):
+    """Shape of one layer's K (or V) cache."""
     dh = cfg.resolved_head_dim
-    shape = (nlayers, batch, sc, cfg.num_kv_heads, dh)
+    if heads_major(cfg):
+        return (batch, heads, positions, dh)
+    return (batch, positions, heads, dh)
+
+
+def init_kv_cache(cfg, batch: int, seq_len: int, nlayers: int, dtype,
+                  kind=None):
+    """Allocate the self-attention KV cache of ``nlayers`` layers of one
+    attention kind (default ``cfg.attention``): a ring buffer of the window
+    for sliding-window layers."""
+    kind = kind or cfg.attention
+    window = cfg.window_size if kind == "sliding_window" else 0
+    sc = min(seq_len, window) if window else seq_len
+    shape = (nlayers,) + kv_shape(cfg, batch, sc, cfg.num_kv_heads)
     return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}

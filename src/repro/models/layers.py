@@ -78,22 +78,67 @@ def apply_norm(cfg, p, x, out_dtype=None):
 # Rotary position embeddings
 # ----------------------------------------------------------------------
 
-def rope_frequencies(head_dim: int, theta: float) -> jnp.ndarray:
-    return 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32)
-                            / head_dim))
+def rope_frequencies(head_dim: int, theta: float, yarn=None) -> jnp.ndarray:
+    """Inverse frequencies of the rotary pairs; with ``yarn`` (a
+    ``configs.Yarn``) those of YaRN, as Hugging Face's
+    ``_compute_yarn_parameters`` states them: interpolated by the factor
+    below the correction range that ``beta_fast``/``beta_slow`` give over
+    the original context (floor and ceil of the correction dimensions),
+    kept above it, and ramped linearly between."""
+    exponent = jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim
+    extrapolated = 1.0 / (theta ** exponent)
+    if yarn is None:
+        return extrapolated
+    interpolated = 1.0 / (yarn.factor * theta ** exponent)
+
+    def correction_dim(rotations):
+        return (head_dim * math.log(yarn.original_max_position
+                                    / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(correction_dim(yarn.beta_fast)), 0)
+    high = min(math.ceil(correction_dim(yarn.beta_slow)), head_dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = jnp.clip((jnp.arange(head_dim // 2, dtype=jnp.float32) - low)
+                    / (high - low), 0.0, 1.0)
+    keep = 1.0 - ramp       # share of the extrapolated frequency
+    return interpolated * (1.0 - keep) + extrapolated * keep
 
 
-def apply_rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float
-               ) -> jnp.ndarray:
-    """x: (..., seq, heads, head_dim); positions: (..., seq)."""
-    head_dim = x.shape[-1]
-    freqs = rope_frequencies(head_dim, theta)  # (hd/2,)
+def apply_rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float,
+               yarn=None) -> jnp.ndarray:
+    """x: (..., seq, heads, head_dim); positions: (..., seq). With ``yarn``
+    the frequencies are YaRN's and cos and sin are scaled by its
+    ``attention_factor``.
+
+    Computed over the heads flattened, (..., seq, heads * head_dim), each
+    head's halves swapped by two lane rotations: elementwise work on a 4-d
+    (heads, head_dim) view lets the TPU compiler give the projection that
+    feeds it another layout, and then relayout its weights every step."""
+    *lead, heads, head_dim = x.shape
+    half = head_dim // 2
+    freqs = rope_frequencies(head_dim, theta, yarn)  # (hd/2,)
     angles = positions[..., :, None].astype(jnp.float32) * freqs  # (..., s, hd/2)
-    cos = jnp.cos(angles)[..., :, None, :]
-    sin = jnp.sin(angles)[..., :, None, :]
-    x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
-    out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
-    return out.astype(x.dtype)
+    cos, sin = jnp.cos(angles), jnp.sin(angles)
+    if yarn is not None:
+        cos, sin = cos * yarn.attention_factor, sin * yarn.attention_factor
+    # (..., s, heads * head_dim): each head [c, c] and [s, s]
+    cos = jnp.tile(jnp.concatenate([cos, cos], -1), heads)
+    sin = jnp.tile(jnp.concatenate([sin, sin], -1), heads)
+    flat = x.reshape(*lead, heads * head_dim).astype(jnp.float32)
+    # rotate_half per head: [-x2, x1]
+    first = (jnp.arange(heads * head_dim) % head_dim) < half
+    rot = jnp.where(first, -jnp.roll(flat, -half, axis=-1),
+                    jnp.roll(flat, half, axis=-1))
+    out = flat * cos + rot * sin
+    return out.reshape(x.shape).astype(x.dtype)
+
+
+def layer_rope(cfg, kind: str):
+    """(theta, yarn) of a layer of attention kind ``kind``."""
+    return cfg.rope_theta, (cfg.full_rope_scaling if kind == "full"
+                            else None)
 
 
 # ----------------------------------------------------------------------

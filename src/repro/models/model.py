@@ -10,6 +10,7 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from . import attention as attn_mod
 from . import transformer
 from .layers import compute_dtype
 from .transformer import decode_step, forward, init_cache, model_init
@@ -102,25 +103,32 @@ def serve_prefill(cfg, params, batch, max_len: Optional[int] = None):
     return out["logits"][:, -1:], cache
 
 
-def assemble_prefill_cache(cfg, out, batch: int, s: int, max_len: int):
+def assemble_prefill_cache(cfg, out, batch: int, s, max_len: int):
     """Build the decode cache from a prefill ``forward`` output dict.
 
     Shared by ``serve_prefill`` and the continuous-batching engine (which
     prefills at a padded bucket length and re-homes rows into slots).
+    ``s`` is the prompt's true length (it may be traced): the window
+    layers' rings take the last real positions of the prompt, each at
+    ``p % window``, never the bucket's padding.
     """
     cache = init_cache(cfg, batch, max_len)
     if "cache" in out:
-        pre = out["cache"]  # (L,B,Sc,HKV,D), ring-rolled if SWA
-        sc = cache["attn"]["k"].shape[2]
-        if pre["k"].shape[2] >= sc:  # SWA ring buffer already full-size
-            cache["attn"] = {"k": pre["k"][:, :, :sc], "v": pre["v"][:, :, :sc]}
-        else:
-            cache["attn"] = {
-                "k": jax.lax.dynamic_update_slice_in_dim(
-                    cache["attn"]["k"], pre["k"], 0, axis=2),
-                "v": jax.lax.dynamic_update_slice_in_dim(
-                    cache["attn"]["v"], pre["v"], 0, axis=2),
-            }
+        # one K/V stack per position of the layer pattern (one without)
+        period = cfg.attention_period
+        multi = len(period) > 1
+        pre = out["cache"] if multi else [out["cache"]]
+        dst = cache["attn"] if multi else [cache["attn"]]
+        axis = 1 + attn_mod.kv_pos_axis(cfg)    # stacked over layers
+        for j, kind in enumerate(period):
+            if kind == "sliding_window":
+                dst[j] = transformer.ring_cache(pre[j], s,
+                                                dst[j]["k"].shape[axis], axis)
+            else:   # prefill rows at the start of the cache
+                dst[j] = jax.tree.map(
+                    lambda c, p: jax.lax.dynamic_update_slice_in_dim(
+                        c, p, 0, axis=axis), dst[j], pre[j])
+        cache["attn"] = dst if multi else dst[0]
     if "cache_ssm" in out:
         cache["ssm"] = out["cache_ssm"]
     if "frontend_kv" in out:

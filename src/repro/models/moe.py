@@ -6,6 +6,12 @@ sorted by expert id, each expert keeps its first `capacity` tokens, expert
 FFNs run as dense batched einsums over (E, C, d), and outputs scatter-add
 back with routing weights. FLOPs scale with top_k (not num_experts), unlike
 the dense-dispatch einsum formulation.
+
+:func:`moe_apply_held` is the layer of one rank of expert parallelism: it
+holds experts ``0 .. cfg.experts_held - 1``, routes every token over all
+``cfg.num_experts``, and computes its own experts' part of the result for
+the tokens routed to them, as grouped matrix products over the held
+experts (``jax.lax.ragged_dot``). It has no capacity and drops nothing.
 """
 from __future__ import annotations
 
@@ -22,14 +28,15 @@ CAPACITY_FACTOR = 1.25
 
 def moe_init(key, cfg, nlayers: int):
     d, f, e = cfg.d_model, cfg.d_ff, cfg.num_experts
+    held = cfg.experts_held or e
     pfx = (nlayers,) if nlayers else ()
     spfx = ("layers",) if nlayers else ()
     ks = jax.random.split(key, 4)
     p = {
         "router": dense_init(ks[0], pfx + (d, e)),
-        "wg": dense_init(ks[1], pfx + (e, d, f)),
-        "wu": dense_init(ks[2], pfx + (e, d, f)),
-        "wd": dense_init(ks[3], pfx + (e, f, d)),
+        "wg": dense_init(ks[1], pfx + (held, d, f)),
+        "wu": dense_init(ks[2], pfx + (held, d, f)),
+        "wd": dense_init(ks[3], pfx + (held, f, d)),
     }
     s = {
         "router": spfx + ("embed", None),
@@ -105,6 +112,56 @@ def moe_apply(cfg, p, x, capture=None):
         y.reshape(-1, d))[:t]
     out = constrain_batch(out)
     return out.reshape(b, s, d), aux
+
+
+def route(cfg, p, xf):
+    """Router of (t, d) tokens over all ``cfg.num_experts``, in float32:
+    softmax, top-k, renormalised. Returns (weights, experts), each (t, k)."""
+    logits = jnp.einsum("td,de->te", xf.astype(jnp.float32),
+                        p["router"].astype(jnp.float32))
+    probs = jax.nn.softmax(logits, axis=-1)
+    topw, topi = jax.lax.top_k(probs, cfg.num_experts_per_tok)
+    return topw / jnp.sum(topw, -1, keepdims=True), topi
+
+
+def moe_apply_held(cfg, p, x, live=None):
+    """The held experts' part of the MoE output for ``x`` (b, s, d).
+
+    Every (token, expert) assignment to a held expert is kept: the
+    assignments are sorted by expert, those to experts held elsewhere
+    last, and the three SwiGLU products run as ragged matrix products over
+    the held experts' groups, so the rows past the last group (assignments
+    held elsewhere) are no held expert's work. Returns (out, tokens): the
+    weighted sum of the held experts' outputs, and per held expert the
+    number of assignments of the tokens where ``live`` (b * s,) holds
+    (every token without it), an int32 (held,).
+    """
+    dt = x.dtype
+    b, s, d = x.shape
+    t, k = b * s, cfg.num_experts_per_tok
+    held = p["wg"].shape[0]
+    xf = x.reshape(t, d)
+    topw, topi = route(cfg, p, xf)
+    flat_e = topi.reshape(-1)                           # (t*k,)
+    mine = flat_e < held
+    group = jnp.where(mine, flat_e, held)               # held elsewhere: last
+    order = jnp.argsort(group, stable=True)
+    sizes = jnp.bincount(group, length=held + 1)[:held].astype(jnp.int32)
+    rows = xf[order // k]
+    g = jax.lax.ragged_dot(rows, p["wg"].astype(dt), sizes)
+    u = jax.lax.ragged_dot(rows, p["wu"].astype(dt), sizes)
+    y = jax.lax.ragged_dot(jax.nn.silu(g) * u, p["wd"].astype(dt), sizes)
+    y = y[jnp.argsort(order)]                           # back to (t*k,)
+    w = topw.reshape(-1)
+    y = jnp.where(mine[:, None], y.astype(jnp.float32) * w[:, None], 0.0)
+    out = y.reshape(t, k, d).sum(1).astype(dt).reshape(b, s, d)
+    if live is None:
+        counted = mine
+    else:
+        counted = mine & jnp.repeat(live.reshape(-1), k)
+    tokens = jnp.bincount(jnp.where(counted, flat_e, held),
+                          length=held + 1)[:held].astype(jnp.int32)
+    return out, tokens
 
 
 def _constrain_experts(x):

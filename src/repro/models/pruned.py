@@ -239,11 +239,10 @@ def prefill_pruned(pm: PrunedModel, tokens, max_len: int, *,
                 k = attn_mod.apply_rope(k, pos, cfg.rope_theta)
             buf = cache["attn"][i]
             cache["attn"][i] = {
-                "k": jax.lax.dynamic_update_slice_in_dim(
-                    buf["k"], k.astype(buf["k"].dtype), 0, axis=1),
-                "v": jax.lax.dynamic_update_slice_in_dim(
-                    buf["v"], v.astype(buf["v"].dtype), 0, axis=1),
-            }
+                n: jax.lax.dynamic_update_slice_in_dim(
+                    buf[n], attn_mod.cache_rows(vcfg, x).astype(buf[n].dtype),
+                    0, axis=attn_mod.kv_pos_axis(vcfg))
+                for n, x in (("k", k), ("v", v))}
             a, _ = attn_mod.self_attention(vcfg, lp["attn"], h)
             x = x + a
         if lcfg.expert_ff:

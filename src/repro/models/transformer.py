@@ -20,7 +20,13 @@ from . import ffn as ffn_mod
 from . import moe as moe_mod
 from . import ssm as ssm_mod
 from .layers import (apply_norm, compute_dtype, dense_init, embed_tokens,
-                     embedding_init, lm_head_init, norm_init, unembed)
+                     embedding_init, layer_rope, lm_head_init, norm_init,
+                     unembed)
+
+# named scope of the held experts and their router in the decode and
+# prefill programs (attention's are ``attention.ATTN_SCOPE``); the compiler
+# keeps it in each operation's ``op_name`` metadata
+MOE_SCOPE = "moe"
 
 
 # ----------------------------------------------------------------------
@@ -68,8 +74,20 @@ def model_init(cfg, key):
     p: Dict[str, Any] = {}
     s: Dict[str, Any] = {}
     p["embed"], s["embed"] = embedding_init(next(ks), cfg)
-    p["layers"], s["layers"] = _block_init(cfg, next(ks), cfg.num_layers,
-                                           kind=block_kind(cfg))
+    n = len(cfg.attention_period)
+    if n > 1:
+        # a layer pattern: one stack per position of the period, so that
+        # a scan step over periods reads (and writes the cache of) each
+        # stack once
+        _check_pattern(cfg)
+        k_layers = next(ks)
+        p["layers"], s["layers"] = (list(t) for t in zip(*[
+            _block_init(cfg, jax.random.fold_in(k_layers, j),
+                        cfg.num_layers // n, kind=block_kind(cfg))
+            for j in range(n)]))
+    else:
+        p["layers"], s["layers"] = _block_init(cfg, next(ks), cfg.num_layers,
+                                               kind=block_kind(cfg))
     p["final_norm"], s["final_norm"] = norm_init(cfg)
     p["head"], s["head"] = lm_head_init(next(ks), cfg)
 
@@ -99,13 +117,16 @@ def model_init(cfg, key):
 # block forward (full-sequence; used by train & prefill)
 # ----------------------------------------------------------------------
 
-def _self_block(cfg, lp, x, *, build_cache: bool, capture: bool):
-    """One standard block. Returns (x, aux, cache_kv, captures)."""
+def _self_block(cfg, lp, x, *, build_cache: bool, capture: bool,
+                attn_kind=None):
+    """One standard block, its attention of kind ``attn_kind`` (default
+    ``cfg.attention``). Returns (x, aux, cache_kv, captures)."""
     caps: Dict[str, Any] = {}
     aux = jnp.zeros((), jnp.float32)
     cap_attn = {} if capture else None
     h = apply_norm(cfg, lp["ln1"], x)
     kind = block_kind(cfg)
+    attn_kind = attn_kind or cfg.attention
 
     cache_kv = None
     if build_cache:
@@ -113,10 +134,11 @@ def _self_block(cfg, lp, x, *, build_cache: bool, capture: bool):
         q, k, v = attn_mod._project_qkv(cfg, lp["attn"], h, h)
         if cfg.pos_emb == "rope":
             pos = jnp.arange(h.shape[1])[None, :]
-            k = attn_mod.apply_rope(k, pos, cfg.rope_theta)
-        cache_kv = (k, v)
+            k = attn_mod.apply_rope(k, pos, *layer_rope(cfg, attn_kind))
+        cache_kv = (attn_mod.cache_rows(cfg, k), attn_mod.cache_rows(cfg, v))
 
-    a, _ = attn_mod.self_attention(cfg, lp["attn"], h, capture=cap_attn)
+    a, _ = attn_mod.self_attention(cfg, lp["attn"], h, capture=cap_attn,
+                                   kind=attn_kind)
     ssm_cache = None
     if kind == "hybrid":
         m = ssm_mod.ssm_apply(cfg, lp["ssm"], h,
@@ -131,7 +153,10 @@ def _self_block(cfg, lp, x, *, build_cache: bool, capture: bool):
 
     h2 = apply_norm(cfg, lp["ln2"], x)
     cap_ffn = {} if capture else None
-    if cfg.num_experts:
+    if cfg.experts_held:
+        with jax.named_scope(MOE_SCOPE):
+            f, _ = moe_mod.moe_apply_held(cfg, lp["moe"], h2)
+    elif cfg.num_experts:
         f, aux = moe_mod.moe_apply(cfg, lp["moe"], h2, capture=cap_ffn)
     else:
         f = ffn_mod.ffn_apply(cfg, lp["ffn"], h2, capture=cap_ffn)
@@ -164,7 +189,7 @@ def _decoder_block(cfg, lp, x, enc_kv, *, build_cache: bool = False,
         if cfg.pos_emb == "rope":
             pos = jnp.arange(h.shape[1])[None, :]
             k = attn_mod.apply_rope(k, pos, cfg.rope_theta)
-        cache_kv = (k, v)
+        cache_kv = (attn_mod.cache_rows(cfg, k), attn_mod.cache_rows(cfg, v))
     a, _ = attn_mod.self_attention(cfg, lp["attn"], h, capture=cap_a)
     x = x + a
     hx = apply_norm(cfg, lp["lnx"], x)
@@ -223,6 +248,45 @@ def _scan_stack(cfg, layers_p, x, body_fn, *, collect_hiddens: bool):
         return x, ys
 
     x, ys = jax.lax.scan(body, x, layers_p)
+    return x, ys
+
+
+def _scan_periods(cfg, layers_p, x, *, build_cache: bool, capture: bool,
+                  collect_hiddens: bool):
+    """The stack of a layer pattern as a scan over periods: ``layers_p`` is
+    one stacked tree per position of the period, and each step runs the
+    period's blocks in order, each with its attention kind. The per-layer
+    outputs come back stacked over all layers, in order, as from
+    :func:`_scan_stack`; the cache K/V as one stack per position."""
+    period = cfg.attention_period
+    blocks = [_maybe_remat(cfg, functools.partial(
+        _self_block, cfg, build_cache=build_cache, capture=capture,
+        attn_kind=k)) for k in period]
+
+    def body(x, lps):
+        per_layer, kv = [], []
+        for block, lp in zip(blocks, lps):
+            x = constrain_batch(x)
+            x, aux, cache_kv, _, caps = block(lp, x)
+            x = constrain_batch(x)
+            y = {"aux": aux}
+            if caps:
+                y["caps"] = caps
+            if collect_hiddens:
+                y["hidden"] = x
+            per_layer.append(y)
+            kv.append(cache_kv)
+        ys = jax.tree.map(lambda *a: jnp.stack(a), *per_layer)
+        if build_cache:
+            ys["cache_k"] = [c[0] for c in kv]
+            ys["cache_v"] = [c[1] for c in kv]
+        return x, ys
+
+    x, ys = jax.lax.scan(body, x, list(layers_p))
+    # (periods, position, ...) -> (layers, ...)
+    caches = {k: ys.pop(k) for k in ("cache_k", "cache_v") if k in ys}
+    ys = jax.tree.map(lambda a: a.reshape(-1, *a.shape[2:]), ys)
+    ys.update(caches)
     return x, ys
 
 
@@ -337,6 +401,11 @@ def forward(cfg, params, tokens, *, frontend_embeds=None, mode: str = "train",
     elif kind == "decoder":
         x, ys = _scan_stack(cfg, {"lp": params["layers"], "kv": enc_kv}, x,
                             body, collect_hiddens=collect_hiddens)
+    elif len(cfg.attention_period) > 1:
+        _check_pattern(cfg)
+        x, ys = _scan_periods(cfg, params["layers"], x,
+                              build_cache=build_cache, capture=capture,
+                              collect_hiddens=collect_hiddens)
     else:
         x, ys = _scan_stack(cfg, params["layers"], x, body,
                             collect_hiddens=collect_hiddens)
@@ -349,22 +418,38 @@ def forward(cfg, params, tokens, *, frontend_embeds=None, mode: str = "train",
     if capture and "caps" in ys:
         out["captures"] = ys["caps"]
     if build_cache and "cache_k" in ys:
-        out["cache"] = _ring_cache(cfg, ys["cache_k"], ys["cache_v"])
+        # every position's K/V, (L,B,S,HKV,D) or (L,B,HKV,S,D) (the cache's
+        # order), or under a layer pattern a list of one such stack per
+        # position of the period
+        k, v = ys["cache_k"], ys["cache_v"]
+        out["cache"] = ([{"k": a, "v": b} for a, b in zip(k, v)]
+                        if isinstance(k, list) else {"k": k, "v": v})
     if build_cache and "cache_ssm" in ys:
         out["cache_ssm"] = ys["cache_ssm"]
     return out
 
 
-def _ring_cache(cfg, k, v):
-    """(L,B,S,HKV,D) prefill keys -> ring-buffer cache for decode."""
-    window = cfg.window_size if cfg.attention == "sliding_window" else 0
-    s = k.shape[2]
-    if window and s > window:
-        k, v = k[:, :, -window:], v[:, :, -window:]
-        shift = (s - window) % window
-        k = jnp.roll(k, shift, axis=2)
-        v = jnp.roll(v, shift, axis=2)
-    return {"k": k, "v": v}
+def _check_pattern(cfg):
+    period = cfg.attention_period
+    if block_kind(cfg) != "self" or cfg.cross_attn_every:
+        raise NotImplementedError(
+            "a layer pattern is run for self-attention decoders only")
+    if cfg.num_layers % len(period) or not set(period) <= set(
+            attn_mod.ATTN_SCOPE):
+        raise ValueError(f"{cfg.num_layers} layers do not repeat the "
+                         f"pattern {period}")
+
+
+def ring_cache(kv, length, size: int, axis: int):
+    """A window ring of ``size`` entries from prefill K/V ``kv`` (stacked
+    over layers, positions on ``axis``) of a prompt of ``length`` real
+    positions (may be traced; the rest is padding): entry j holds the last
+    real position p with p % size == j. Entries whose position would be
+    negative hold anything; decode masks them."""
+    j = jnp.arange(size)
+    last = jnp.asarray(length, jnp.int32) - 1
+    src = jnp.clip(last - (last - j) % size, 0, kv["k"].shape[axis] - 1)
+    return jax.tree.map(lambda a: jnp.take(a, src, axis=axis), kv)
 
 
 # ----------------------------------------------------------------------
@@ -397,12 +482,18 @@ def init_cache(cfg, batch: int, seq_len: int, dtype=None, *,
                 raise ValueError(
                     f"kv_heads has {len(kv_heads)} entries for "
                     f"{cfg.num_layers} layers")
-            dh = cfg.resolved_head_dim
             cache["attn"] = [
                 None if not h else
-                {"k": jnp.zeros((batch, seq_len, int(h), dh), dtype),
-                 "v": jnp.zeros((batch, seq_len, int(h), dh), dtype)}
+                {n: jnp.zeros(attn_mod.kv_shape(cfg, batch, seq_len, int(h)),
+                              dtype) for n in ("k", "v")}
                 for h in kv_heads]
+        elif len(cfg.attention_period) > 1:
+            # one stack per position of the layer pattern, each of its
+            # layers' attention kind (a window ring or max_len positions)
+            period = cfg.attention_period
+            cache["attn"] = [attn_mod.init_kv_cache(
+                cfg, batch, seq_len, cfg.num_layers // len(period), dtype,
+                kind=k) for k in period]
         else:
             cache["attn"] = attn_mod.init_kv_cache(cfg, batch, seq_len,
                                                    cfg.num_layers, dtype)
@@ -430,6 +521,17 @@ def decode_step(cfg, params, cache, tokens):
     ``cache["pos"]`` is a scalar (lockstep batch) or a (B,) vector of
     per-slot positions (continuous batching): each slot then embeds, RoPE-
     rotates, writes and masks at its own absolute position.
+
+    Under a layer pattern the layers run as a scan over its periods, each
+    layer with its attention kind (``cfg.attention_period``): the params
+    and the K/V are one stack per position of the period, so that each
+    scan step reads each stack's slice once and writes it back in place.
+    With held experts (``cfg.experts_held``) and a routing counter
+    ``cache["route"]`` (int32 (3,): held token-expert assignments, held
+    experts with a token summed over layers and steps, most tokens on one
+    held expert in a layer and step), the step adds its routing to the
+    counter, over the slots where ``cache["live"]`` (B,) holds (all
+    without it).
     """
     pos = cache["pos"]
     if cfg.pos_emb == "learned":
@@ -439,26 +541,26 @@ def decode_step(cfg, params, cache, tokens):
     x = constrain_batch(embed_tokens(cfg, params["embed"], tokens,
                                      positions=positions))
     kind = block_kind(cfg)
+    period = cfg.attention_period
+    live = cache.get("live")
 
     # The stacked self-attention K/V ride in the scan's carry, and layer i
     # reads and writes back its own slice: passed as xs and restacked as
     # ys, every layer's slice is copied out and back. Other caches stay
     # per-layer xs/ys.
-    def body(carry, lp):
-        x, kv, i = carry
+    def layer(x, kv, i, lp, attn_kind, new_c):
         x = constrain_batch(x)
-        new_c = {}
         if kind == "ssm":
             h = apply_norm(cfg, lp["ln1"], x)
             y, new_c["ssm"] = ssm_mod.ssm_decode_step(cfg, lp["ssm"], h,
                                                       lp["cache_ssm"])
-            x = x + y
-            return (x, kv, i + 1), new_c
+            return x + y, kv
         h = apply_norm(cfg, lp["ln1"], x)
         layer_kv = jax.tree.map(
             lambda a: jax.lax.dynamic_index_in_dim(a, i, keepdims=False), kv)
         a, layer_kv = attn_mod.self_attention(
-            cfg, lp["attn"], h, cache=layer_kv, cache_pos=pos)
+            cfg, lp["attn"], h, cache=layer_kv, cache_pos=pos,
+            kind=attn_kind)
         kv = jax.tree.map(
             lambda a, u: jax.lax.dynamic_update_index_in_dim(a, u, i, 0),
             kv, layer_kv)
@@ -472,14 +574,35 @@ def decode_step(cfg, params, cache, tokens):
             x = x + attn_mod.cross_attention(cfg, lp["xattn"], hx,
                                              lp["cache_cross"])
         h2 = apply_norm(cfg, lp["ln2"], x)
-        if cfg.num_experts:
+        if cfg.experts_held:
+            with jax.named_scope(MOE_SCOPE):
+                f, tokens_held = moe_mod.moe_apply_held(cfg, lp["moe"], h2,
+                                                        live)
+            new_c.setdefault("route", []).append(tokens_held)
+        elif cfg.num_experts:
             f, _ = moe_mod.moe_apply(cfg, lp["moe"], h2)
         else:
             f = ffn_mod.ffn_apply(cfg, lp["ffn"], h2)
-        x = x + f
+        return x + f, kv
+
+    def body(carry, lp):
+        x, kv, i = carry
+        new_c = {}
+        if len(period) == 1:
+            x, kv = layer(x, kv, i, lp, period[0], new_c)
+        else:   # position j of the period: its params, its K/V stack
+            kv = list(kv)
+            for j, attn_kind in enumerate(period):
+                x, kv[j] = layer(x, kv[j], i, lp[j], attn_kind, new_c)
+        if "route" in new_c:
+            new_c["route"] = jnp.stack(new_c["route"])
         return (x, kv, i + 1), new_c
 
-    scan_in = dict(params["layers"])
+    if len(period) > 1:
+        _check_pattern(cfg)
+        scan_in = list(params["layers"])
+    else:
+        scan_in = dict(params["layers"])
     if "ssm" in cache:
         scan_in["cache_ssm"] = cache["ssm"]
     if kind == "decoder":
@@ -511,9 +634,16 @@ def decode_step(cfg, params, cache, tokens):
     x = apply_norm(cfg, params["final_norm"], x)
     logits = unembed(cfg, params["embed"], params.get("head", {}), x)
     new_cache = dict(cache)
+    new_cache.pop("live", None)
     new_cache["pos"] = pos + 1
     if "attn" in cache:
         new_cache["attn"] = new_attn
     if "ssm" in new_caches:
         new_cache["ssm"] = new_caches["ssm"]
+    if "route" in cache and "route" in new_caches:
+        held = new_caches["route"].reshape(-1, cfg.experts_held)  # (L, held)
+        r = cache["route"]
+        new_cache["route"] = jnp.stack([
+            r[0] + held.sum(), r[1] + (held > 0).sum(),
+            jnp.maximum(r[2], held.max())]).astype(jnp.int32)
     return logits, new_cache

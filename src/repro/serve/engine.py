@@ -63,13 +63,22 @@ def _kv_bytes(cache) -> int:
 
 
 class DenseServeModel:
-    """Engine adapter for stock (unpruned) params."""
+    """Engine adapter for stock (unpruned) params.
+
+    Each layer's attention is of its kind in ``cfg.attention_period``
+    (full, or a ring of the window); the slot cache holds one K/V stack per
+    kind. With held experts (``cfg.experts_held``) the slot cache also
+    carries the routing counter ``route`` that ``transformer.decode_step``
+    adds to, over the slots the engine marks ``live``.
+    """
 
     def __init__(self, cfg, params, max_len: int):
-        if (not cfg.causal or cfg.attention != "full"
-                or cfg.frontend != "none"):
+        if (not cfg.causal or cfg.frontend != "none"
+                or not set(cfg.attention_period) <= {"full",
+                                                     "sliding_window"}):
             raise NotImplementedError(
-                "serving engine covers causal full-attention text decoders")
+                "serving engine covers causal text decoders of full and "
+                "sliding-window attention")
         _check_decodable(cfg)
         self.cfg, self.params, self.max_len = cfg, params, max_len
         self._prefill_jit: Dict[int, Callable] = {}
@@ -77,26 +86,30 @@ class DenseServeModel:
         # the jitted functions' names are the executables' names in a
         # profiler trace (jit_serve_decode, jit_serve_prefill,
         # jit_serve_insert), the same in both adapters
-        def serve_decode(p, kv, pos, t):
-            return decode_step(cfg, p, {"pos": pos, "attn": kv}, t)
+        if cfg.experts_held:
+            def serve_decode(p, kv, pos, t, route, live):
+                return decode_step(cfg, p, {"pos": pos, "attn": kv,
+                                            "route": route, "live": live}, t)
+        else:
+            def serve_decode(p, kv, pos, t):
+                return decode_step(cfg, p, {"pos": pos, "attn": kv}, t)
 
         def serve_insert(cache, row, slot, pos):
-            return {
-                "pos": cache["pos"].at[slot].set(pos),
-                "attn": {
-                    "k": cache["attn"]["k"].at[:, slot].set(
-                        row["attn"]["k"][:, 0]),
-                    "v": cache["attn"]["v"].at[:, slot].set(
-                        row["attn"]["v"][:, 0]),
-                },
-            }
+            # the row holds the prompt's K/V as the prefill placed it: the
+            # full layers' from position 0, the rings from the true length
+            return dict(cache, pos=cache["pos"].at[slot].set(pos),
+                        attn=jax.tree.map(lambda c, r: c.at[:, slot].set(
+                            r[:, 0]), cache["attn"], row["attn"]))
 
         self._step = jax.jit(serve_decode,
                              donate_argnums=(1,) if _donate_kv() else ())
         self._insert = jax.jit(serve_insert)
 
     def init_slots(self, nslots: int):
-        return init_cache(self.cfg, nslots, self.max_len, per_slot=True)
+        cache = init_cache(self.cfg, nslots, self.max_len, per_slot=True)
+        if self.cfg.experts_held:
+            cache["route"] = jnp.zeros((3,), jnp.int32)
+        return cache
 
     def prefill(self, tokens: np.ndarray):
         """(s,) prompt -> (last-token logits (1,1,V), single-row cache).
@@ -111,10 +124,10 @@ class DenseServeModel:
         bucket = _bucket(s, self.max_len)
 
         if bucket not in self._prefill_jit:
-            def serve_prefill(p, toks, last, _bucket=bucket):
+            def serve_prefill(p, toks, last):
                 out = forward(cfg, p, toks, mode="prefill")
                 cache = model_mod.assemble_prefill_cache(
-                    cfg, out, 1, _bucket, self.max_len)
+                    cfg, out, 1, last + 1, self.max_len)
                 logits = jax.lax.dynamic_slice_in_dim(out["logits"], last,
                                                       1, axis=1)
                 return logits, cache
@@ -130,6 +143,12 @@ class DenseServeModel:
                             jnp.asarray(pos, jnp.int32))
 
     def step(self, cache, tokens):
+        if self.cfg.experts_held:
+            live = cache.get("live")
+            if live is None:
+                live = jnp.ones(cache["pos"].shape, bool)
+            return self._step(self.params, cache["attn"], cache["pos"],
+                              tokens, cache["route"], live)
         return self._step(self.params, cache["attn"], cache["pos"], tokens)
 
 
@@ -360,8 +379,10 @@ class ServeEngine:
     ``serve.sample``; per decode step ``serve.step`` (stats ``step``,
     ``active``) over ``serve.dispatch``, ``serve.wait``, ``serve.pull``,
     ``serve.check`` (repeated on a retry), ``serve.sample`` and
-    ``serve.bookkeep``; and ``serve.gc`` (stat ``generation``) over each
-    garbage collection. Without a session each span costs about a
+    ``serve.bookkeep``; ``serve.gc`` (stat ``generation``) over each
+    garbage collection; and, where the model's slot cache carries a
+    routing counter (held experts), ``serve.route`` once after the drain
+    (see :meth:`_route_span`). Without a session each span costs about a
     microsecond.
     """
 
@@ -372,6 +393,9 @@ class ServeEngine:
         self.clock = clock
         self.cache = model.init_slots(num_slots)
         self.kv_cache_bytes = _kv_bytes(self.cache)
+        # the last run's routing counter (see _route_span), where the
+        # model counts its routing
+        self.route: Optional[Dict[str, int]] = None
         self.max_len = model.max_len
 
     def warmup(self, prompt_lens=(8,)):
@@ -416,6 +440,11 @@ class ServeEngine:
                 continue
             cache = self.cache
             with _span("serve.dispatch"):
+                if "route" in cache:
+                    # the routing counter counts the active slots' tokens
+                    live = np.zeros(self.num_slots, bool)
+                    live[active_slots] = True
+                    cache = dict(cache, live=jnp.asarray(live))
                 toks = jnp.asarray(tokens.reshape(-1, 1), jnp.int32)
                 logits, new_cache = self.model.step(cache, toks)
                 if mult != 1.0:
@@ -432,7 +461,10 @@ class ServeEngine:
             if not finite:
                 rep.count("detected", "serve.step")
                 rep.count("retries", "serve.step")
+                # a retry restarts from the pre-step positions and counts
                 self.cache = dict(new_cache, pos=cache["pos"])
+                if "route" in cache:
+                    self.cache["route"] = cache["route"]
                 continue
             if attempt:
                 rep.count("recovered", "serve.step")
@@ -464,7 +496,26 @@ class ServeEngine:
                     f"{self.max_len}; decoding past capacity would "
                     "overwrite the last cache slot and corrupt output")
         with _span("serve.run", requests=len(requests)), _GCSpans():
-            return self._serve(requests)
+            report = self._serve(requests)
+            if "route" in self.cache:
+                self._route_span()
+            return report
+
+    def _route_span(self):
+        """A ``serve.route`` span whose stats are the run's routing counter
+        (``transformer.decode_step``), copied to the host once, after the
+        drain: ``assignments`` (held token-expert pairs), ``experts_hit``
+        (held experts with a token, summed over layers and steps) and
+        ``max_expert_tokens`` (most tokens on one held expert in one layer
+        and step)."""
+        route = self.cache["route"]
+        # sync: once per run, after the last step
+        a, hit, most = (int(v) for v in np.asarray(route))
+        self.route = {"assignments": a, "experts_hit": hit,
+                      "max_expert_tokens": most}
+        with _span("serve.route", **self.route):
+            # the next run counts from zero
+            self.cache = dict(self.cache, route=jnp.zeros_like(route))
 
     def _serve(self, requests: List[Request]) -> ServeReport:
         t_run = self.clock()

@@ -15,7 +15,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.configs import GPT2_SMALL, get_config
+from repro.configs import GPT2_SMALL, MELLUM2_12B, get_config
 from repro.core.database import chunk_size, module_bytes
 from repro.core.obs import prune_structured_batched
 from repro.core.structures import level_grid, registry
@@ -162,8 +162,6 @@ def test_serve_decode_writes_kv_in_place(one_chip, monkeypatch, adapter):
     back, and the dense layer scan copied each layer's slice out and back.
     An asynchronous copy (``copy-start``) that keeps the layout only moves
     a buffer between on-chip and device memory."""
-    import re
-
     from repro.serve import engine
     monkeypatch.setattr(engine, "_donate_kv", lambda: True)
     if adapter == "dense":
@@ -175,9 +173,15 @@ def test_serve_decode_writes_kv_in_place(one_chip, monkeypatch, adapter):
     cache = jax.eval_shape(lambda: model.init_slots(SLOTS))
     args = _on(one_chip, (*weights, cache["attn"], cache["pos"],
                           jax.ShapeDtypeStruct((SLOTS, 1), jnp.int32)))
-    c = model._step.lower(*args).compile()
-    kv = jax.tree.leaves(cache["attn"])
-    # a buffer, and for the stacked cache also one layer's slice of it
+    _assert_kv_in_place(model._step.lower(*args).compile(), cache["attn"])
+
+
+def _assert_kv_in_place(c, attn):
+    """No K/V buffer (nor one layer's slice of a stack) is copied or moved
+    into another layout, and the K/V are donated whole."""
+    import re
+    kv = jax.tree.leaves(attn)
+    # a buffer, and for a stacked cache also one layer's slice of it
     shapes = {s for x in kv for s in (
         (x.shape, x.shape[1:], (1, *x.shape[1:])) if x.ndim == 5
         else (x.shape,))}
@@ -193,3 +197,30 @@ def test_serve_decode_writes_kv_in_place(one_chip, monkeypatch, adapter):
     assert not converted, converted
     kv_bytes = sum(x.size * x.dtype.itemsize for x in kv)
     assert c.memory_analysis().alias_size_in_bytes == kv_bytes
+
+
+def test_mellum_decode_writes_kv_in_place(one_chip, monkeypatch):
+    """Mellum2's ``jit_serve_decode`` at its published widths (one period
+    of 3 window layers and 1 full one, 8 held experts, the code-long
+    cell's 32 slots of 4,096) writes the window rings of 1,024 and the full
+    caches in place: each is one stack per position of the period, held
+    heads-major (heads of 128 lanes), read and written back once a scan
+    step, and none is converted out of its layout."""
+    from repro.models import model_init
+    from repro.serve import engine
+    monkeypatch.setattr(engine, "_donate_kv", lambda: True)
+    cfg = MELLUM2_12B.replace(num_layers=4, experts_held=8)
+    # weights held in bfloat16, norm scales in float32
+    params = jax.tree_util.tree_map_with_path(
+        lambda path, x: x if path[-1].key == "scale" else
+        jax.ShapeDtypeStruct(x.shape, jnp.bfloat16),
+        jax.eval_shape(lambda k: model_init(cfg, k)[0], jax.random.key(0)))
+    model = engine.DenseServeModel(cfg, params, 4096)
+    cache = jax.eval_shape(lambda: model.init_slots(32))
+    assert [x["k"].shape for x in cache["attn"]] == [
+        (1, 32, 4, n, 128) for n in (1024, 1024, 1024, 4096)]
+    args = _on(one_chip, (params, cache["attn"], cache["pos"],
+                          jax.ShapeDtypeStruct((32, 1), jnp.int32),
+                          cache["route"],
+                          jax.ShapeDtypeStruct((32,), jnp.bool_)))
+    _assert_kv_in_place(model._step.lower(*args).compile(), cache["attn"])
