@@ -42,6 +42,19 @@ def _bucket(s: int, max_len: int) -> int:
     return min(b, max_len)
 
 
+def serve_sample(logits):
+    """The engine's greedy sampler and finite check, on the device: (B, 1,
+    V) logits -> (B,) int32, each row's first index of its largest value
+    (as ``np.argmax``), or -1 where the row holds a nan or an inf. Its
+    executable is ``jit_serve_sample``."""
+    x = logits[:, 0]
+    ids = jnp.argmax(x, axis=-1).astype(jnp.int32)
+    return jnp.where(jnp.isfinite(x).all(axis=-1), ids, -1)
+
+
+_sample = jax.jit(serve_sample)
+
+
 def _donate_kv() -> bool:
     """Whether the decode step donates the slot cache's K/V, so that the
     step's output K/V are written in place: off the CPU only, like
@@ -287,7 +300,7 @@ class ServeReport:
     # decode steps whose K/V input the step consumed in place (donated)
     donated_steps: int = 0
     # host-clock seconds since the start of ``run`` at which each decode
-    # step's logits were on the host
+    # step's token ids were on the host
     step_end: List[float] = field(default_factory=list)
 
     @property
@@ -377,13 +390,14 @@ class ServeEngine:
     ``serve.admit`` (stats ``rid``, ``slot``, ``prompt_len``, ``bucket``)
     over ``serve.prefill``, ``serve.insert``, ``serve.wait`` and
     ``serve.sample``; per decode step ``serve.step`` (stats ``step``,
-    ``active``) over ``serve.dispatch``, ``serve.wait``, ``serve.pull``,
-    ``serve.check`` (repeated on a retry), ``serve.sample`` and
-    ``serve.bookkeep``; ``serve.gc`` (stat ``generation``) over each
-    garbage collection; and, where the model's slot cache carries a
-    routing counter (held experts), ``serve.route`` once after the drain
-    (see :meth:`_route_span`). Without a session each span costs about a
-    microsecond.
+    ``active``) over ``serve.dispatch`` (the decode step and
+    :func:`serve_sample`), ``serve.wait``, ``serve.pull`` (stat ``bytes``,
+    the token ids copied to the host), ``serve.check`` (repeated on a
+    retry), ``serve.sample`` and ``serve.bookkeep``; ``serve.gc`` (stat
+    ``generation``) over each garbage collection; and, where the model's
+    slot cache carries a routing counter (held experts), ``serve.route``
+    once after the drain (see :meth:`_route_span`). Without a session each
+    span costs about a microsecond.
     """
 
     def __init__(self, model, num_slots: int = 4,
@@ -399,14 +413,15 @@ class ServeEngine:
         self.max_len = model.max_len
 
     def warmup(self, prompt_lens=(8,)):
-        """Compile the prefill buckets, the insert, and the decode step."""
+        """Compile the prefill buckets, the insert, the decode step and the
+        sampler."""
         for s in prompt_lens:
             s = min(int(s), self.max_len - 1)
             logits, row = self.model.prefill(np.zeros((s,), np.int64))
             cache = self.model.insert(self.cache, row, 0, s)
             toks = jnp.zeros((self.num_slots, 1), jnp.int32)
             # sync: warmup barrier — wait for each bucket's compile
-            jax.block_until_ready(self.model.step(cache, toks)[0])
+            jax.block_until_ready(_sample(self.model.step(cache, toks)[0]))
         # warmup state is discarded; self.cache was never mutated (the
         # insert does not donate, so a step consumes only the insert's
         # output)
@@ -416,8 +431,10 @@ class ServeEngine:
     # ------------------------------------------------------------------
 
     def _step_once(self, tokens: np.ndarray, active_slots: List[int]):
-        """One decode step with bounded retries; returns the host logits
-        and whether the step consumed its K/V input in place.
+        """One decode step with bounded retries; returns the host token ids,
+        ``(num_slots,)`` int32 from :func:`serve_sample` (-1 on a row that
+        is not finite), and whether the step consumed its K/V input in
+        place.
 
         Off the CPU the step donates the cache's K/V (``_donate_kv``), so
         the previous cache is gone once the step is dispatched. A detected
@@ -449,15 +466,16 @@ class ServeEngine:
                 logits, new_cache = self.model.step(cache, toks)
                 if mult != 1.0:
                     logits = logits * mult
+                ids = _sample(logits)
             donated = _kv_consumed(cache)
             with _span("serve.wait"):
-                # sync: one pull per decode step — greedy sampling and the
-                # serve.step finite check both need host logits anyway
-                jax.block_until_ready(logits)
-            with _span("serve.pull"):
-                lg = np.asarray(logits)  # sync: the copy of ready logits
+                # sync: one pull per decode step — the next step's tokens
+                # are this step's ids
+                jax.block_until_ready(ids)
+            with _span("serve.pull", bytes=ids.nbytes):
+                host_ids = np.asarray(ids)  # sync: the copy of ready ids
             with _span("serve.check"):
-                finite = np.isfinite(lg[active_slots]).all()
+                finite = (host_ids[active_slots] >= 0).all()
             if not finite:
                 rep.count("detected", "serve.step")
                 rep.count("retries", "serve.step")
@@ -469,7 +487,7 @@ class ServeEngine:
             if attempt:
                 rep.count("recovered", "serve.step")
             self.cache = new_cache
-            return lg, donated
+            return host_ids, donated
         raise RuntimeError(
             f"serve.step produced unusable output {_STEP_RETRIES} times "
             "in a row — fault is not transient")
@@ -583,7 +601,7 @@ class ServeEngine:
             step = len(step_end)
             with _span("serve.step", step=step, active=len(slots)):
                 t0 = self.clock()
-                lg, donated = self._step_once(last_tok, slots)
+                ids, donated = self._step_once(last_tok, slots)
                 t1 = self.clock()
                 dt = t1 - t0
                 t += dt
@@ -591,7 +609,7 @@ class ServeEngine:
                 step_end.append(t1 - t_run)
                 donated_steps += donated
                 with _span("serve.sample"):
-                    toks = [int(np.argmax(lg[slot, 0])) for slot in slots]
+                    toks = ids[slots].tolist()
                 with _span("serve.bookkeep"):
                     for slot, tok in zip(slots, toks):
                         rec = active[slot]

@@ -19,6 +19,7 @@ from repro.runtime.costmodel import InferenceEnv
 from repro.serve import (CLASS_SPEEDUP, DenseServeModel, FamilyServer,
                          PrunedServeModel, Request, ServeEngine,
                          synthetic_requests)
+from repro.serve import engine as engine_mod
 
 MAX_LEN = 48
 
@@ -323,9 +324,10 @@ def test_stamps_are_the_engine_clock_readings(tiny_cfg, tiny_params):
 
 def test_executables_have_stable_names(tiny_cfg, tiny_params, mag_db,
                                        dense_engine):
-    """Both adapters' decode, prefill and insert compile to modules named
-    jit_serve_decode, jit_serve_prefill and jit_serve_insert, the names a
-    profiler trace gives their executions."""
+    """Both adapters' decode, prefill and insert, and the engine's sampler,
+    compile to modules named jit_serve_decode, jit_serve_prefill,
+    jit_serve_insert and jit_serve_sample, the names a profiler trace gives
+    their executions."""
     def module(jitted, *args):
         return jitted.lower(*args).as_text().split()[1]
 
@@ -346,6 +348,119 @@ def test_executables_have_stable_names(tiny_cfg, tiny_params, mag_db,
             == "@jit_serve_prefill"
         assert module(model._insert, eng.cache, row, i32, i32) \
             == "@jit_serve_insert"
+    logits = jnp.zeros((2, 1, tiny_cfg.vocab_size), jnp.float32)
+    assert module(engine_mod._sample, logits) == "@jit_serve_sample"
+
+
+# ----------------------------------------------------------------------
+# greedy sampling and the finite check on the device (serve_sample)
+# ----------------------------------------------------------------------
+
+_LO = np.finfo(np.float32).min
+_ULP = float(np.finfo(np.float32).eps)
+
+
+def _rows(*rows):
+    return np.asarray(rows, np.float32)[:, None, :]
+
+
+def _vocab_width():
+    """64 random rows of GPT-2's vocabulary, each with its largest value
+    planted twice: tied, or one and two float32 steps apart; three rows
+    not finite."""
+    rng = np.random.default_rng(0)
+    x = rng.standard_normal((64, 1, 50257)).astype(np.float32)
+    top = np.float32(8)
+    up1 = np.nextafter(top, np.float32(9))
+    up2 = np.nextafter(up1, np.float32(9))
+    want = []
+    for r in range(64):
+        p, q = np.sort(rng.choice(50257, 2, replace=False))
+        x[r, 0, p], x[r, 0, q] = [(top, top), (up1, top), (up2, up1),
+                                  (up1, up2)][r % 4]
+        want.append(int(q if r % 4 == 3 else p))
+    x[3, 0, 9], x[10, 0, 0], x[20, 0, 50256] = np.nan, np.inf, -np.inf
+    want[3] = want[10] = want[20] = -1
+    return x, want
+
+
+@pytest.mark.parametrize("case", [
+    # exact ties: the first index of the largest value wins
+    lambda: (_rows([1, 3, 3, 0, 3], [0, 0, 0, 0, 0], [2, -1, 2, 2, 1]),
+             [1, 0, 0]),
+    # the lowest finite value everywhere but one entry, and everywhere
+    lambda: (_rows([_LO, _LO, _LO, -7, _LO], [_LO] * 5), [3, 0]),
+    # -inf everywhere but one entry is not finite, like any -inf
+    lambda: (_rows([-np.inf, -np.inf, 0.5, -np.inf, -np.inf],
+                   [0, 1, 2, 3, 4]), [-1, 4]),
+    # a nan, a +inf or a -inf anywhere in a row
+    lambda: (_rows([0, np.nan, 1, 2, 3], [5, 4, 3, 2, 1],
+                   [0, 1, np.inf, 2, 9], [9, 1, 2, -np.inf, 3],
+                   [np.nan] * 5), [-1, 0, -1, -1, -1]),
+    # float32 values that differ in their last bits (one bfloat16 value)
+    lambda: (_rows([0, 0, 1 + 2 * _ULP, 0, 1 + _ULP],
+                   [0, 0, 1 + _ULP, 0, 1 + 2 * _ULP],
+                   [1 + _ULP, 1, 1, 1, 1 + _ULP]), [2, 4, 0]),
+    _vocab_width,
+], ids=["ties", "lowest-finite", "neg-inf-but-one", "non-finite",
+        "last-bit", "vocab-width"])
+def test_sampler_equals_host_argmax(case):
+    """``serve_sample`` gives each row's ``np.argmax`` over the host
+    logits, first index on ties, and -1 for a row with a nan or an inf."""
+    logits, want = case()
+    ids = engine_mod._sample(jnp.asarray(logits))
+    assert ids.dtype == jnp.int32 and ids.shape == (logits.shape[0],)
+    host = np.where(np.isfinite(logits[:, 0]).all(-1),
+                    np.argmax(logits[:, 0], -1), -1)
+    assert np.asarray(ids).tolist() == host.tolist() == want
+
+
+@pytest.mark.parametrize("kind", ["dense", "member"])
+def test_engine_serves_host_argmax_of_step_logits(tiny_cfg, tiny_params,
+                                                  mag_db, kind):
+    """Each adapter's engine, sampling on the device, serves the tokens
+    that a plain loop over the same adapter's ``model.step`` gets from
+    ``np.argmax`` of the host logits."""
+    model = (DenseServeModel(tiny_cfg, tiny_params, MAX_LEN)
+             if kind == "dense" else
+             PrunedServeModel(shrink(tiny_cfg, tiny_params, mag_db,
+                                     _half_heads_assignment(tiny_cfg,
+                                                            mag_db)),
+                              MAX_LEN))
+    eng = ServeEngine(model, num_slots=2)
+    eng.warmup((8, 16))
+    reqs = _requests(tiny_cfg, n=4, seed=17)
+    report = eng.run(reqs)
+    for req, rec in zip(reqs, report.records):
+        logits, row = model.prefill(req.tokens)
+        toks = [int(np.argmax(np.asarray(logits)[0, 0]))]
+        cache = model.insert(model.init_slots(2), row, 1, req.prompt_len)
+        for _ in range(req.steps - 1):
+            feed = np.zeros((2, 1), np.int32)
+            feed[1, 0] = toks[-1]
+            logits, cache = model.step(cache, jnp.asarray(feed))
+            toks.append(int(np.argmax(np.asarray(logits)[1, 0])))
+        assert rec.tokens == toks, f"rid={req.rid}"
+
+
+def test_step_pulls_num_slots_token_ids(tmp_path, tiny_cfg, dense_engine):
+    """Traced, each decode step's ``serve.pull`` span says it copied
+    ``4 * num_slots`` bytes to the host: the step's int32 token ids, not its
+    logits."""
+    from jax.profiler import ProfileData
+    reqs = _requests(tiny_cfg, n=3, seed=7)
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.enable_hlo_proto = False
+    with jax.profiler.trace(str(tmp_path), profiler_options=opts):
+        report = dense_engine.run(reqs)
+    path, = tmp_path.glob("**/*.xplane.pb")
+    pulls = [dict(ev.stats)
+             for plane in ProfileData.from_file(str(path)).planes
+             for line in plane.lines for ev in line.events
+             if ev.name == "serve.pull"]
+    assert len(pulls) == report.steps > 0
+    assert {p["bytes"] for p in pulls} == {4 * dense_engine.num_slots}
 
 
 # ----------------------------------------------------------------------
