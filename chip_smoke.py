@@ -40,7 +40,6 @@ benchmark numbers. The last line of standard output is one JSON object,
 from __future__ import annotations
 
 import argparse
-import dataclasses
 import json
 import os
 import sys
@@ -154,17 +153,9 @@ def _pruned_logits_fn(pm):
     (closing over them would bake them into the executable)."""
     import jax
 
-    from repro.models.pruned import PrunedModel, forward_pruned
-    shells = [dataclasses.replace(l, params=None) for l in pm.layers]
-
-    def f(lps, globals_, tokens):
-        layers = [dataclasses.replace(s, params=lp)
-                  for s, lp in zip(shells, lps)]
-        return forward_pruned(PrunedModel(pm.cfg, layers, globals_), tokens)
-
-    jf = jax.jit(f)
-    return lambda tokens: jf([l.params for l in pm.layers], pm.globals_,
-                             tokens)
+    from repro.models.pruned import forward_pruned
+    jf = jax.jit(lambda w, tokens: forward_pruned(pm.with_weights(w), tokens))
+    return lambda tokens: jf(pm.weights(), tokens)
 
 
 def shrink_phase(log, cfg, res, calib):

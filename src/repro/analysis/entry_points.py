@@ -223,7 +223,7 @@ def entry_shrink_stitched() -> EntryResult:
 
     def _shrink(st):
         pm = shrink_from_stitched(cfg, st, db, a)
-        return [l.params for l in pm.layers], pm.globals_
+        return pm.weights()
 
     return audit_jitted("shrink.stitched", jax.jit(_shrink), (stitched,))
 
@@ -236,12 +236,11 @@ def entry_serve_prefill() -> EntryResult:
     cfg, params = _tiny_state()
     model = DenseServeModel(cfg, params, max_len=64)
     s = 8
-    model.prefill(np.zeros((s,), np.int64))  # builds the bucket jit
     bucket = _bucket(s, model.max_len)
     padded = jnp.asarray(np.zeros((1, bucket), np.int64))
     metrics, findings = audit_jitted(
-        "serve.prefill", model._prefill_jit[bucket],
-        (params, padded, jnp.asarray(s - 1, jnp.int32)))
+        "serve.prefill", model._prefill,
+        (model.weights, padded, jnp.asarray(s - 1, jnp.int32)))
 
     # third column of the latency loop: the LatencyTable prediction vs a
     # roofline over the audited HLO costs, same env, same hardware spec
@@ -265,10 +264,10 @@ def entry_serve_decode() -> EntryResult:
     toks = jnp.zeros((4, 1), jnp.int32)
     # the step donates the K/V off-CPU (`engine._donate_kv`); re-declare
     # it here so the aliases are checked statically on any backend
-    step = jax.jit(model._step.__wrapped__, donate_argnums=(1,))
+    step = jax.jit(model._step.__wrapped__, donate_argnums=model.kv_argnums)
     return audit_jitted("serve.decode", step,
-                        (params, cache["attn"], cache["pos"], toks),
-                        donate_argnums=(1,))
+                        (model.weights, cache["attn"], cache["pos"], toks,
+                         {}), donate_argnums=model.kv_argnums)
 
 
 def entry_serve_decode_gqa() -> EntryResult:
@@ -289,10 +288,10 @@ def entry_serve_decode_gqa() -> EntryResult:
     model = PrunedServeModel(pm, max_len=64)
     cache = model.init_slots(4)
     toks = jnp.zeros((4, 1), jnp.int32)
-    step = jax.jit(model._step.__wrapped__, donate_argnums=(2,))
+    step = jax.jit(model._step.__wrapped__, donate_argnums=model.kv_argnums)
     return audit_jitted("serve.decode_gqa", step,
-                        (model._lps, model._globals, cache["attn"],
-                         cache["pos"], toks), donate_argnums=(2,))
+                        (model.weights, cache["attn"], cache["pos"], toks,
+                         {}), donate_argnums=model.kv_argnums)
 
 
 def entry_train_step() -> EntryResult:

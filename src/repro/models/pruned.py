@@ -2,20 +2,27 @@
 
 After ZipLM shrink, layers have *different* head counts / FC widths (and
 some modules are dropped entirely), so the homogeneous ``lax.scan`` stack no
-longer applies. This module runs per-layer parameter lists with an unrolled
-loop, reusing the same primitive ops — this is where the structural speedup
-actually materializes (smaller matmuls / skipped modules).
+longer applies. This runtime runs the per-layer parameter lists as an
+unrolled loop over ``transformer``'s own blocks (``_self_block`` for
+forward and prefill, ``decode_block`` for decode), each under a view
+config of its layer's widths; a dropped module is an absent key, whose
+half the block skips. This is where the structural speedup materializes
+(smaller matmuls / skipped modules). A pruned MoE and a pruned SSM keep
+their own code here.
 """
 from __future__ import annotations
 
+import dataclasses
 from dataclasses import dataclass, field
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, List
 
 import jax
 import jax.numpy as jnp
 
 from . import attention as attn_mod
-from .layers import apply_norm, compute_dtype, embed_tokens, unembed
+from .layers import apply_norm, compute_dtype, embed_tokens
+from .transformer import (_ffn_half, _self_block, decode_block, embed_at,
+                          final_logits, init_cache)
 
 
 @dataclass
@@ -34,14 +41,24 @@ class PrunedModel:
     globals_: Dict[str, Any]  # embed / final_norm / head (+cross params)
 
     def num_params(self) -> int:
-        leaves = jax.tree.leaves([l.params for l in self.layers]) \
-            + jax.tree.leaves(self.globals_)
-        return int(sum(x.size for x in leaves))
+        return int(sum(x.size for x in jax.tree.leaves(self.weights())))
 
     def encoder_params(self) -> int:
         """Transformer-stack params only (paper reports 'encoder size')."""
-        return int(sum(x.size for l in self.layers
-                       for x in jax.tree.leaves(l.params)))
+        return int(sum(x.size for x in jax.tree.leaves(self.weights()[0])))
+
+    def weights(self):
+        """The model's arrays as one tree: (per-layer params, globals)."""
+        return [l.params for l in self.layers], self.globals_
+
+    def with_weights(self, weights) -> "PrunedModel":
+        """This structure over ``weights`` (as :meth:`weights` gives them):
+        a jitted function that takes them as an argument rebuilds the model
+        inside, so the arrays are not baked in as constants."""
+        lps, globals_ = weights
+        return PrunedModel(self.cfg, [dataclasses.replace(l, params=lp)
+                                      for l, lp in zip(self.layers, lps)],
+                           globals_)
 
 
 def _vcfg(cfg, lcfg: PrunedLayer):
@@ -53,34 +70,28 @@ def _vcfg(cfg, lcfg: PrunedLayer):
                        head_dim=cfg.resolved_head_dim)
 
 
-def _attn_forward(cfg, lcfg: PrunedLayer, lp, x):
-    out, _ = attn_mod.self_attention(_vcfg(cfg, lcfg), lp, x)
-    return out
+def _block(lcfg: PrunedLayer):
+    """The layer's params as ``transformer``'s blocks take them: all but a
+    pruned MoE, which :func:`_moe_forward` applies after the block."""
+    return {k: v for k, v in lcfg.params.items() if k != "moe"}
 
 
-def _ffn_forward(cfg, lp, x):
-    dt = x.dtype
-    if "wg" in lp:
-        h = jax.nn.silu(x @ lp["wg"].astype(dt)) * (x @ lp["wu"].astype(dt))
-    else:
-        h = jax.nn.gelu(x @ lp["wi"].astype(dt) + lp["bi"].astype(dt))
-    y = h @ lp["wd"].astype(dt)
-    if "bd" in lp:
-        y = y + lp["bd"].astype(dt)
-    return y
-
-
-def _moe_forward(cfg, lcfg: PrunedLayer, lp, x):
-    """Pruned MoE: per-expert widths differ. Fully-dropped experts keep
+def _moe_forward(cfg, lcfg: PrunedLayer, x):
+    """The layer's pruned MoE half (norm, experts, residual), where it kept
+    one. Per-expert widths differ. Fully-dropped experts keep
     their router column and hold a ``None`` compute slot, so the top-k
     selection (and the normalization over the selected weights) is exactly
     the masked model's — a dead expert can still win a top-k slot and
     absorb routing weight, it just contributes nothing. Dense-gather
     dispatch per live expert (unrolled; few experts after pruning)."""
+    # kept apart from moe.py, which would otherwise branch on its caller
+    if not lcfg.expert_ff:
+        return x
+    lp = lcfg.params["moe"]
     dt = x.dtype
     b, s, d = x.shape
     t = b * s
-    xf = x.reshape(t, d)
+    xf = apply_norm(cfg, lcfg.params["ln2"], x).reshape(t, d)
     logits = (xf @ lp["router"].astype(dt)).astype(jnp.float32)
     probs = jax.nn.softmax(logits, axis=-1)
     k = min(cfg.num_experts_per_tok, probs.shape[-1])
@@ -93,16 +104,17 @@ def _moe_forward(cfg, lcfg: PrunedLayer, lp, x):
         w_e = jnp.where(topi == e, topw, 0.0).sum(-1).astype(dt)  # (t,)
         h = jax.nn.silu(xf @ ep["wg"].astype(dt)) * (xf @ ep["wu"].astype(dt))
         out = out + w_e[:, None] * (h @ ep["wd"].astype(dt))
-    return out.reshape(b, s, d)
+    return x + out.reshape(b, s, d)
 
 
 def _ssm_forward(cfg, lcfg: PrunedLayer, lp, x):
     """SSD block at pruned width (dims derive from the shrunk weights)."""
+    # kept apart: a view config cannot state a pruned SSM's head count
+    # (``ssm_heads`` derives from ``d_inner``)
     from . import ssm as ssm_mod
     di = lcfg.ssm_heads * cfg.ssm_head_dim
     dt_ = x.dtype
     b, s, d = x.shape
-    n = cfg.ssm_state
     h = lcfg.ssm_heads
     hp = cfg.ssm_head_dim
     z = x @ lp["in_z"].astype(dt_)
@@ -124,38 +136,43 @@ def _ssm_forward(cfg, lcfg: PrunedLayer, lp, x):
     return y @ lp["out_proj"].astype(dt_)
 
 
-def forward_pruned(pm: PrunedModel, tokens, frontend_embeds=None):
-    """Unrolled forward over heterogeneous pruned layers -> fp32 logits."""
+def _mixers(cfg, lcfg: PrunedLayer, x):
+    """The token mixers of a layer with a pruned SSM (or of a hybrid
+    layer): what is left of its SSM and its attention, on one norm and
+    averaged in a hybrid."""
+    lp = lcfg.params
+    if "ln1" not in lp:     # both dropped
+        return x
+    h = apply_norm(cfg, lp["ln1"], x)
+    y = _ssm_forward(cfg, lcfg, lp["ssm"], h) if "ssm" in lp else 0.0
+    if "attn" in lp:
+        y = y + attn_mod.self_attention(_vcfg(cfg, lcfg), lp["attn"], h)[0]
+    return x + (0.5 * y if cfg.hybrid else y)
+
+
+def _forward(pm: PrunedModel, tokens, build_cache: bool = False):
+    """The layers in order, each ``transformer._self_block`` under its view
+    config (a layer beside a pruned SSM: its :func:`_mixers` and the
+    block's FFN half), then its pruned MoE. Returns (fp32 logits, each
+    layer's prompt K/V, or None where it caches none)."""
     cfg = pm.cfg
     x = embed_tokens(cfg, pm.globals_["embed"], tokens)
+    kvs = []
     for lcfg in pm.layers:
-        lp = lcfg.params
-        attn_out = None
-        if lcfg.kv_groups > 0 and "attn" in lp:
-            h = apply_norm(cfg, lp["ln1"], x)
-            attn_out = _attn_forward(cfg, lcfg, lp["attn"], h)
-        ssm_out = None
-        if lcfg.ssm_heads > 0 and "ssm" in lp:
-            h = apply_norm(cfg, lp["ln1"], x)
-            ssm_out = _ssm_forward(cfg, lcfg, lp["ssm"], h)
-        if attn_out is not None and ssm_out is not None:
-            x = x + 0.5 * (attn_out + ssm_out)
-        elif cfg.hybrid and (attn_out is not None or ssm_out is not None):
-            live = attn_out if attn_out is not None else ssm_out
-            x = x + 0.5 * live
-        elif attn_out is not None:
-            x = x + attn_out
-        elif ssm_out is not None:
-            x = x + ssm_out
+        vcfg, lp = _vcfg(cfg, lcfg), _block(lcfg)
+        if cfg.hybrid or "ssm" in lp:
+            x, kv = _ffn_half(vcfg, lp, _mixers(cfg, lcfg, x))[0], None
+        else:
+            x, _, kv, _, _ = _self_block(vcfg, lp, x, build_cache=build_cache,
+                                         capture=False)
+        kvs.append(None if kv is None else dict(zip("kv", kv)))
+        x = _moe_forward(cfg, lcfg, x)
+    return final_logits(cfg, pm.globals_, x), kvs
 
-        if lcfg.expert_ff:
-            h2 = apply_norm(cfg, lp["ln2"], x)
-            x = x + _moe_forward(cfg, lcfg, lp["moe"], h2)
-        elif lcfg.d_ff > 0 and ("ffn" in lp):
-            h2 = apply_norm(cfg, lp["ln2"], x)
-            x = x + _ffn_forward(cfg, lp["ffn"], h2)
-    x = apply_norm(cfg, pm.globals_["final_norm"], x)
-    return unembed(cfg, pm.globals_["embed"], pm.globals_.get("head", {}), x)
+
+def forward_pruned(pm: PrunedModel, tokens, frontend_embeds=None):
+    """Unrolled forward over heterogeneous pruned layers -> fp32 logits."""
+    return _forward(pm, tokens)[0]
 
 
 # ----------------------------------------------------------------------
@@ -179,10 +196,8 @@ def init_cache_pruned(pm: PrunedModel, batch: int, max_len: int, dtype=None,
     kv_groups, head_dim) buffer — this is the cache-bytes win the serve
     bench asserts.
     """
-    from .transformer import init_cache
     _check_decodable(pm.cfg)
-    kv_heads = [l.kv_groups if (l.kv_groups > 0 and "attn" in l.params) else 0
-                for l in pm.layers]
+    kv_heads = [l.kv_groups if "attn" in l.params else 0 for l in pm.layers]
     return init_cache(pm.cfg, batch, max_len, dtype, kv_heads=kv_heads,
                       per_slot=per_slot)
 
@@ -200,8 +215,7 @@ def kv_cache_bytes_per_layer(pm: PrunedModel, batch: int, max_len: int,
     itemsize = jnp.dtype(dtype or compute_dtype(pm.cfg)).itemsize
     dh = pm.cfg.resolved_head_dim
     return [2 * batch * max_len * l.kv_groups * dh * itemsize
-            if (l.kv_groups > 0 and "attn" in l.params) else 0
-            for l in pm.layers]
+            if "attn" in l.params else 0 for l in pm.layers]
 
 
 def kv_cache_bytes(pm: PrunedModel, batch: int, max_len: int,
@@ -220,71 +234,34 @@ def prefill_pruned(pm: PrunedModel, tokens, max_len: int, *,
     cache) with ``cache["pos"]`` scalar; the serving engine re-homes rows
     into per-slot caches itself.
     """
-    cfg = pm.cfg
-    _check_decodable(cfg)
     b, s = tokens.shape
     if s > max_len:
         raise RuntimeError(f"prompt_len={s} exceeds cache max_len={max_len}")
     cache = init_cache_pruned(pm, b, max_len)
-    x = embed_tokens(cfg, pm.globals_["embed"], tokens)
-    for i, lcfg in enumerate(pm.layers):
-        lp = lcfg.params
-        if lcfg.kv_groups > 0 and "attn" in lp:
-            vcfg = _vcfg(cfg, lcfg)
-            h = apply_norm(cfg, lp["ln1"], x)
-            # recompute k/v for the cache; attention reuses them internally
-            _, k, v = attn_mod._project_qkv(vcfg, lp["attn"], h, h)
-            if cfg.pos_emb == "rope":
-                pos = jnp.arange(s)[None, :]
-                k = attn_mod.apply_rope(k, pos, cfg.rope_theta)
-            buf = cache["attn"][i]
-            cache["attn"][i] = {
-                n: jax.lax.dynamic_update_slice_in_dim(
-                    buf[n], attn_mod.cache_rows(vcfg, x).astype(buf[n].dtype),
-                    0, axis=attn_mod.kv_pos_axis(vcfg))
-                for n, x in (("k", k), ("v", v))}
-            a, _ = attn_mod.self_attention(vcfg, lp["attn"], h)
-            x = x + a
-        if lcfg.expert_ff:
-            h2 = apply_norm(cfg, lp["ln2"], x)
-            x = x + _moe_forward(cfg, lcfg, lp["moe"], h2)
-        elif lcfg.d_ff > 0 and "ffn" in lp:
-            h2 = apply_norm(cfg, lp["ln2"], x)
-            x = x + _ffn_forward(cfg, lp["ffn"], h2)
-    x = apply_norm(cfg, pm.globals_["final_norm"], x)
-    logits = unembed(cfg, pm.globals_["embed"], pm.globals_.get("head", {}), x)
+    logits, kvs = _forward(pm, tokens, build_cache=True)
+    # the prompt's rows at the start of each layer's cache, as
+    # ``model.assemble_prefill_cache`` places a full layer's
+    axis = attn_mod.kv_pos_axis(pm.cfg)
+    cache["attn"] = jax.tree.map(
+        lambda c, r: jax.lax.dynamic_update_slice_in_dim(c, r, 0, axis=axis),
+        cache["attn"], kvs)
     cache["pos"] = jnp.asarray(s, jnp.int32)
     return (logits if full_logits else logits[:, -1:]), cache
 
 
 def decode_step_pruned(pm: PrunedModel, cache, tokens):
-    """One-token decode over heterogeneous pruned layers (unrolled).
+    """One-token decode over heterogeneous pruned layers (unrolled), each
+    layer ``transformer.decode_block``.
 
     ``cache["pos"]`` scalar (lockstep) or (B,) per-slot vector, same
     contract as ``transformer.decode_step``. Returns (logits, new_cache).
     """
     cfg = pm.cfg
     pos = cache["pos"]
-    if cfg.pos_emb == "learned":
-        positions = pos[:, None] if jnp.ndim(pos) == 1 else pos[None]
-    else:
-        positions = None
-    x = embed_tokens(cfg, pm.globals_["embed"], tokens, positions=positions)
-    new_attn = list(cache["attn"])
+    x = embed_at(cfg, pm.globals_["embed"], tokens, pos)
+    kv = list(cache["attn"])
     for i, lcfg in enumerate(pm.layers):
-        lp = lcfg.params
-        if lcfg.kv_groups > 0 and "attn" in lp:
-            h = apply_norm(cfg, lp["ln1"], x)
-            a, new_attn[i] = attn_mod.self_attention(
-                _vcfg(cfg, lcfg), lp["attn"], h,
-                cache=cache["attn"][i], cache_pos=pos)
-            x = x + a
-        if lcfg.expert_ff:
-            h2 = apply_norm(cfg, lp["ln2"], x)
-            x = x + _moe_forward(cfg, lcfg, lp["moe"], h2)
-        elif lcfg.d_ff > 0 and "ffn" in lp:
-            h2 = apply_norm(cfg, lp["ln2"], x)
-            x = x + _ffn_forward(cfg, lp["ffn"], h2)
-    x = apply_norm(cfg, pm.globals_["final_norm"], x)
-    logits = unembed(cfg, pm.globals_["embed"], pm.globals_.get("head", {}), x)
-    return logits, {"pos": pos + 1, "attn": new_attn}
+        x, kv[i], _ = decode_block(_vcfg(cfg, lcfg), _block(lcfg), x, kv[i],
+                                   pos)
+        x = _moe_forward(cfg, lcfg, x)
+    return final_logits(cfg, pm.globals_, x), {"pos": pos + 1, "attn": kv}

@@ -120,50 +120,78 @@ def model_init(cfg, key):
 def _self_block(cfg, lp, x, *, build_cache: bool, capture: bool,
                 attn_kind=None):
     """One standard block, its attention of kind ``attn_kind`` (default
-    ``cfg.attention``). Returns (x, aux, cache_kv, captures)."""
+    ``cfg.attention``). A pruned layer without ``attn`` skips that half
+    and caches nothing. Returns (x, aux, cache_kv, ssm_cache, captures)."""
     caps: Dict[str, Any] = {}
-    aux = jnp.zeros((), jnp.float32)
     cap_attn = {} if capture else None
-    h = apply_norm(cfg, lp["ln1"], x)
     kind = block_kind(cfg)
     attn_kind = attn_kind or cfg.attention
 
-    cache_kv = None
-    if build_cache:
-        # recompute k/v for the cache (prefill); attention itself reuses them
-        q, k, v = attn_mod._project_qkv(cfg, lp["attn"], h, h)
-        if cfg.pos_emb == "rope":
-            pos = jnp.arange(h.shape[1])[None, :]
-            k = attn_mod.apply_rope(k, pos, *layer_rope(cfg, attn_kind))
-        cache_kv = (attn_mod.cache_rows(cfg, k), attn_mod.cache_rows(cfg, v))
-
-    a, _ = attn_mod.self_attention(cfg, lp["attn"], h, capture=cap_attn,
-                                   kind=attn_kind)
-    ssm_cache = None
-    if kind == "hybrid":
-        m = ssm_mod.ssm_apply(cfg, lp["ssm"], h,
-                              capture=caps if capture else None,
-                              return_cache=build_cache)
+    cache_kv = ssm_cache = None
+    if "attn" in lp:
+        h = apply_norm(cfg, lp["ln1"], x)
         if build_cache:
-            m, ssm_cache = m
-        a = 0.5 * (a + m)
-    x = x + a
+            # recompute k/v for the cache (prefill); attention reuses them
+            q, k, v = attn_mod._project_qkv(cfg, lp["attn"], h, h)
+            if cfg.pos_emb == "rope":
+                pos = jnp.arange(h.shape[1])[None, :]
+                k = attn_mod.apply_rope(k, pos, *layer_rope(cfg, attn_kind))
+            cache_kv = (attn_mod.cache_rows(cfg, k),
+                        attn_mod.cache_rows(cfg, v))
+        a, _ = attn_mod.self_attention(cfg, lp["attn"], h, capture=cap_attn,
+                                       kind=attn_kind)
+        if kind == "hybrid":
+            m = ssm_mod.ssm_apply(cfg, lp["ssm"], h,
+                                  capture=caps if capture else None,
+                                  return_cache=build_cache)
+            if build_cache:
+                m, ssm_cache = m
+            a = 0.5 * (a + m)
+        x = x + a
     if capture:
         caps["attn"] = cap_attn
 
-    h2 = apply_norm(cfg, lp["ln2"], x)
     cap_ffn = {} if capture else None
-    if cfg.experts_held:
-        with jax.named_scope(MOE_SCOPE):
-            f, _ = moe_mod.moe_apply_held(cfg, lp["moe"], h2)
-    elif cfg.num_experts:
-        f, aux = moe_mod.moe_apply(cfg, lp["moe"], h2, capture=cap_ffn)
-    else:
-        f = ffn_mod.ffn_apply(cfg, lp["ffn"], h2, capture=cap_ffn)
-    x = x + f
+    x, aux, _ = _ffn_half(cfg, lp, x, capture=cap_ffn)
     if capture:
         caps["ffn"] = cap_ffn
     return x, aux, cache_kv, ssm_cache, caps
+
+
+def _ffn_half(cfg, lp, x, live=None, capture=None):
+    """A block's second half: norm, FFN / held-expert MoE / MoE, residual;
+    a pruned layer with neither ``ffn`` nor ``moe`` passes ``x`` through.
+    Returns (x, aux, the held experts' assignments of the tokens where
+    ``live`` holds, or None)."""
+    aux = jnp.zeros((), jnp.float32)
+    if "ffn" not in lp and "moe" not in lp:
+        return x, aux, None
+    h2 = apply_norm(cfg, lp["ln2"], x)
+    held = None
+    if cfg.experts_held:
+        with jax.named_scope(MOE_SCOPE):
+            f, held = moe_mod.moe_apply_held(cfg, lp["moe"], h2, live)
+    elif cfg.num_experts:
+        f, aux = moe_mod.moe_apply(cfg, lp["moe"], h2, capture=capture)
+    else:
+        f = ffn_mod.ffn_apply(cfg, lp["ffn"], h2, capture=capture)
+    return x + f, aux, held
+
+
+def decode_block(cfg, lp, x, kv, pos, attn_kind=None, live=None):
+    """One self-attention layer of a one-token decode, the body of both
+    runtimes' loops (``decode_step``'s scan over stacks, the pruned
+    runtime's unrolled list): norm, attention of kind ``attn_kind`` on the
+    layer's K/V ``kv`` at positions ``pos``, residual, then
+    :func:`_ffn_half`. A pruned layer without ``attn`` (its ``kv`` None)
+    skips that half. Returns (x, kv, held-expert assignments or None)."""
+    if "attn" in lp:
+        h = apply_norm(cfg, lp["ln1"], x)
+        a, kv = attn_mod.self_attention(cfg, lp["attn"], h, cache=kv,
+                                        cache_pos=pos, kind=attn_kind)
+        x = x + a
+    x, _, held = _ffn_half(cfg, lp, x, live)
+    return x, kv, held
 
 
 def _ssm_block(cfg, lp, x, *, build_cache: bool = False, capture: bool):
@@ -410,8 +438,7 @@ def forward(cfg, params, tokens, *, frontend_embeds=None, mode: str = "train",
         x, ys = _scan_stack(cfg, params["layers"], x, body,
                             collect_hiddens=collect_hiddens)
 
-    x = apply_norm(cfg, params["final_norm"], constrain_batch(x))
-    out["logits"] = unembed(cfg, params["embed"], params.get("head", {}), x)
+    out["logits"] = final_logits(cfg, params, constrain_batch(x))
     out["aux"] = jnp.mean(ys["aux"]) if "aux" in ys else jnp.zeros(())
     if collect_hiddens:
         out["hiddens"] = ys.get("hidden")
@@ -465,9 +492,10 @@ def init_cache(cfg, batch: int, seq_len: int, dtype=None, *,
     the cache is then a *list* of per-layer ``{k, v}`` buffers sized by the
     pruned structure (``None`` for fully-dropped attention modules), so a
     ZipLM-shrunk model pays KV-cache bytes only for the heads it kept.
-    The homogeneous ``decode_step`` scan consumes the stacked form; the
-    per-layer list form is consumed by the pruned serving runtime
-    (``models.pruned.decode_step_pruned``).
+    Both forms feed the same layer body, :func:`decode_block`: the
+    ``decode_step`` scan hands it one layer's slice of the stacked form,
+    the pruned runtime's unrolled loop
+    (``models.pruned.decode_step_pruned``) one buffer of the list.
 
     ``per_slot=True`` allocates a per-slot position vector ``pos: (B,)``
     (continuous-batching serving) instead of the scalar lockstep position.
@@ -515,6 +543,22 @@ def init_cache(cfg, batch: int, seq_len: int, dtype=None, *,
     return cache
 
 
+def embed_at(cfg, embed_p, tokens, pos):
+    """One token per row, embedded at position ``pos``: a scalar (lockstep
+    batch) or (B,) per-slot positions."""
+    positions = None
+    if cfg.pos_emb == "learned":
+        positions = pos[:, None] if jnp.ndim(pos) == 1 else pos[None]
+    return embed_tokens(cfg, embed_p, tokens, positions=positions)
+
+
+def final_logits(cfg, params, x):
+    """Final norm and unembedding of ``params`` (a model's tree, or the
+    globals of a pruned one): fp32 logits."""
+    x = apply_norm(cfg, params["final_norm"], x)
+    return unembed(cfg, params["embed"], params.get("head", {}), x)
+
+
 def decode_step(cfg, params, cache, tokens):
     """One-token decode. tokens: (B, 1). Returns (logits (B,1,V), new_cache).
 
@@ -534,12 +578,7 @@ def decode_step(cfg, params, cache, tokens):
     without it).
     """
     pos = cache["pos"]
-    if cfg.pos_emb == "learned":
-        positions = pos[:, None] if jnp.ndim(pos) == 1 else pos[None]
-    else:
-        positions = None
-    x = constrain_batch(embed_tokens(cfg, params["embed"], tokens,
-                                     positions=positions))
+    x = constrain_batch(embed_at(cfg, params["embed"], tokens, pos))
     kind = block_kind(cfg)
     period = cfg.attention_period
     live = cache.get("live")
@@ -555,35 +594,32 @@ def decode_step(cfg, params, cache, tokens):
             y, new_c["ssm"] = ssm_mod.ssm_decode_step(cfg, lp["ssm"], h,
                                                       lp["cache_ssm"])
             return x + y, kv
-        h = apply_norm(cfg, lp["ln1"], x)
         layer_kv = jax.tree.map(
             lambda a: jax.lax.dynamic_index_in_dim(a, i, keepdims=False), kv)
-        a, layer_kv = attn_mod.self_attention(
-            cfg, lp["attn"], h, cache=layer_kv, cache_pos=pos,
-            kind=attn_kind)
+        if kind == "self":
+            x, layer_kv, held = decode_block(cfg, lp, x, layer_kv, pos,
+                                             attn_kind, live)
+        else:   # hybrid or decoder: the halves around their own modules
+            h = apply_norm(cfg, lp["ln1"], x)
+            a, layer_kv = attn_mod.self_attention(
+                cfg, lp["attn"], h, cache=layer_kv, cache_pos=pos,
+                kind=attn_kind)
+            if kind == "hybrid":
+                m, new_c["ssm"] = ssm_mod.ssm_decode_step(
+                    cfg, lp["ssm"], h, lp["cache_ssm"])
+                a = 0.5 * (a + m)
+            x = x + a
+            if kind == "decoder":
+                hx = apply_norm(cfg, lp["lnx"], x)
+                x = x + attn_mod.cross_attention(cfg, lp["xattn"], hx,
+                                                 lp["cache_cross"])
+            x, _, held = _ffn_half(cfg, lp, x, live)
+        if held is not None:
+            new_c.setdefault("route", []).append(held)
         kv = jax.tree.map(
             lambda a, u: jax.lax.dynamic_update_index_in_dim(a, u, i, 0),
             kv, layer_kv)
-        if kind == "hybrid":
-            m, new_c["ssm"] = ssm_mod.ssm_decode_step(cfg, lp["ssm"], h,
-                                                      lp["cache_ssm"])
-            a = 0.5 * (a + m)
-        x = x + a
-        if kind == "decoder":
-            hx = apply_norm(cfg, lp["lnx"], x)
-            x = x + attn_mod.cross_attention(cfg, lp["xattn"], hx,
-                                             lp["cache_cross"])
-        h2 = apply_norm(cfg, lp["ln2"], x)
-        if cfg.experts_held:
-            with jax.named_scope(MOE_SCOPE):
-                f, tokens_held = moe_mod.moe_apply_held(cfg, lp["moe"], h2,
-                                                        live)
-            new_c.setdefault("route", []).append(tokens_held)
-        elif cfg.num_experts:
-            f, _ = moe_mod.moe_apply(cfg, lp["moe"], h2)
-        else:
-            f = ffn_mod.ffn_apply(cfg, lp["ffn"], h2)
-        return x + f, kv
+        return x, kv
 
     def body(carry, lp):
         x, kv, i = carry
@@ -631,8 +667,7 @@ def decode_step(cfg, params, cache, tokens):
     else:
         (x, new_attn, _), new_caches = jax.lax.scan(body, carry, scan_in)
 
-    x = apply_norm(cfg, params["final_norm"], x)
-    logits = unembed(cfg, params["embed"], params.get("head", {}), x)
+    logits = final_logits(cfg, params, x)
     new_cache = dict(cache)
     new_cache.pop("live", None)
     new_cache["pos"] = pos + 1

@@ -34,7 +34,16 @@ silently corrupt output. Pruned members allocate their cache from the
 *shrunk* per-layer structure (``init_cache(kv_heads=[...])``): a layer
 that kept ``g`` KV groups pays for ``g`` heads, a dropped attention
 module pays nothing — KV bytes, not just FLOPs, shrink with the model
-(asserted by ``benchmarks/run.py serve``).
+(asserted by ``tests/test_serve_engine.py``'s
+``test_pruned_cache_bytes_match_shrunk_structure``).
+
+Model adapter
+-------------
+One :class:`~repro.serve.engine.ServeModel` owns the jitted prefill, slot
+insert and decode step for both runtimes; :class:`DenseServeModel` (the
+scanned stack) and :class:`PrunedServeModel` (a shrunk member's unrolled
+per-layer list) only build what it serves. Both runtimes run the same
+layer body, ``models.transformer.decode_block``.
 
 Family routing
 --------------
@@ -50,13 +59,14 @@ K/V with the pre-step positions, which the step rewrites before reading
 (see ``ServeEngine._step_once``); chaos-tier runs recover bit-identically.
 """
 from .engine import (DenseServeModel, PrunedServeModel, RequestRecord,
-                     ServeEngine, ServeReport)
+                     ServeEngine, ServeModel, ServeReport)
 from .family import DENSE_TARGET, FamilyServer
 from .workload import (CLASS_SPEEDUP, LATENCY_CLASSES, Request,
                        synthetic_requests)
 
 __all__ = [
-    "DenseServeModel", "PrunedServeModel", "ServeEngine", "ServeReport",
+    "ServeModel", "DenseServeModel", "PrunedServeModel", "ServeEngine",
+    "ServeReport",
     "RequestRecord", "FamilyServer", "DENSE_TARGET", "Request",
     "synthetic_requests", "CLASS_SPEEDUP", "LATENCY_CLASSES",
 ]

@@ -1,16 +1,20 @@
 """Slot-based continuous-batching engine over jitted prefill/decode steps.
 
 See the package docstring (``repro.serve``) for the slot lifecycle and the
-cache sizing contract. Two model adapters share one engine:
+cache sizing contract. One model adapter, :class:`ServeModel`, owns the
+jitted prefill, insert and decode step; what it serves is built by
 
 * :class:`DenseServeModel` — stock params, ``transformer.decode_step``
-  over the stacked homogeneous cache;
+  (a scan over the stacked layers and their stacked cache);
 * :class:`PrunedServeModel` — a ZipLM-shrunk :class:`PrunedModel`,
-  ``models.pruned.decode_step_pruned`` over the per-layer pruned cache
-  (KV bytes follow the shrunk structure).
+  ``models.pruned.decode_step_pruned`` (an unrolled loop over the layers
+  and their per-layer cache, whose KV bytes follow the shrunk structure).
+
+Both loops run ``transformer``'s own layer body.
 """
 from __future__ import annotations
 
+import functools
 import gc
 import time
 from dataclasses import dataclass, field
@@ -22,7 +26,7 @@ import numpy as np
 from jax.profiler import TraceAnnotation as _span
 
 from ..models import model as model_mod
-from ..models.pruned import (PrunedLayer, PrunedModel, _check_decodable,
+from ..models.pruned import (PrunedModel, _check_decodable,
                              decode_step_pruned, init_cache_pruned,
                              prefill_pruned)
 from ..models.transformer import decode_step, forward, init_cache
@@ -75,54 +79,56 @@ def _kv_bytes(cache) -> int:
                for x in jax.tree.leaves(cache.get("attn")))
 
 
-class DenseServeModel:
-    """Engine adapter for stock (unpruned) params.
+class ServeModel:
+    """The engine's model adapter: the jitted prefill, insert and decode
+    step over one model's pieces.
 
-    Each layer's attention is of its kind in ``cfg.attention_period``
-    (full, or a ring of the window); the slot cache holds one K/V stack per
-    kind. With held experts (``cfg.experts_held``) the slot cache also
-    carries the routing counter ``route`` that ``transformer.decode_step``
-    adds to, over the slots the engine marks ``live``.
+    * ``weights``: the tree each executable takes as its first argument;
+    * ``decode(weights, cache, tokens) -> (logits, cache)``: one step of
+      the slot cache, whose entries other than ``attn`` and ``pos`` are the
+      step's state that is not donated (held experts' routing counter);
+    * ``prefill(weights, tokens, last) -> (logits, cache)``: logits at
+      every position of a (1, bucket) prompt whose last real token is at
+      ``last``, and its one-row cache;
+    * ``init_slots(nslots)``: an empty slot cache;
+    * ``slot_axis``: the slot axis of each K/V buffer (1 in a stack over
+      layers, 0 in a per-layer buffer).
+
+    The jitted functions' names are the executables' names in a profiler
+    trace: ``jit_serve_decode``, ``jit_serve_prefill`` (compiled once per
+    prompt bucket) and ``jit_serve_insert``.
     """
 
-    def __init__(self, cfg, params, max_len: int):
-        if (not cfg.causal or cfg.frontend != "none"
-                or not set(cfg.attention_period) <= {"full",
-                                                     "sliding_window"}):
-            raise NotImplementedError(
-                "serving engine covers causal text decoders of full and "
-                "sliding-window attention")
-        _check_decodable(cfg)
-        self.cfg, self.params, self.max_len = cfg, params, max_len
-        self._prefill_jit: Dict[int, Callable] = {}
+    # the argument of jit_serve_decode that holds the slot cache's K/V,
+    # donated off the CPU (``_donate_kv``)
+    kv_argnums = (1,)
 
-        # the jitted functions' names are the executables' names in a
-        # profiler trace (jit_serve_decode, jit_serve_prefill,
-        # jit_serve_insert), the same in both adapters
-        if cfg.experts_held:
-            def serve_decode(p, kv, pos, t, route, live):
-                return decode_step(cfg, p, {"pos": pos, "attn": kv,
-                                            "route": route, "live": live}, t)
-        else:
-            def serve_decode(p, kv, pos, t):
-                return decode_step(cfg, p, {"pos": pos, "attn": kv}, t)
+    def __init__(self, cfg, weights, max_len: int, *, decode, prefill,
+                 init_slots, slot_axis: int):
+        self.cfg, self.weights, self.max_len = cfg, weights, max_len
+        self.init_slots = init_slots
+        at = (slice(None),) * slot_axis
+
+        def serve_decode(p, kv, pos, tokens, state):
+            return decode(p, {"pos": pos, "attn": kv, **state}, tokens)
+
+        def serve_prefill(p, tokens, last):
+            logits, cache = prefill(p, tokens, last)
+            return jax.lax.dynamic_slice_in_dim(logits, last, 1, axis=1), \
+                cache
 
         def serve_insert(cache, row, slot, pos):
             # the row holds the prompt's K/V as the prefill placed it: the
-            # full layers' from position 0, the rings from the true length
+            # full layers' from position 0, the rings from the true length;
+            # a dropped layer's None passes through
             return dict(cache, pos=cache["pos"].at[slot].set(pos),
-                        attn=jax.tree.map(lambda c, r: c.at[:, slot].set(
-                            r[:, 0]), cache["attn"], row["attn"]))
+                        attn=jax.tree.map(lambda c, r: c.at[at + (slot,)].set(
+                            r[at + (0,)]), cache["attn"], row["attn"]))
 
-        self._step = jax.jit(serve_decode,
-                             donate_argnums=(1,) if _donate_kv() else ())
+        self._step = jax.jit(serve_decode, donate_argnums=(
+            self.kv_argnums if _donate_kv() else ()))
+        self._prefill = jax.jit(serve_prefill)     # one program per bucket
         self._insert = jax.jit(serve_insert)
-
-    def init_slots(self, nslots: int):
-        cache = init_cache(self.cfg, nslots, self.max_len, per_slot=True)
-        if self.cfg.experts_held:
-            cache["route"] = jnp.zeros((3,), jnp.int32)
-        return cache
 
     def prefill(self, tokens: np.ndarray):
         """(s,) prompt -> (last-token logits (1,1,V), single-row cache).
@@ -132,113 +138,81 @@ class DenseServeModel:
         prefill; during decode every position <= pos has been overwritten
         by a real token before the mask admits it).
         """
-        cfg = self.cfg
         s = int(tokens.shape[0])
-        bucket = _bucket(s, self.max_len)
-
-        if bucket not in self._prefill_jit:
-            def serve_prefill(p, toks, last):
-                out = forward(cfg, p, toks, mode="prefill")
-                cache = model_mod.assemble_prefill_cache(
-                    cfg, out, 1, last + 1, self.max_len)
-                logits = jax.lax.dynamic_slice_in_dim(out["logits"], last,
-                                                      1, axis=1)
-                return logits, cache
-            self._prefill_jit[bucket] = jax.jit(serve_prefill)
-
-        padded = np.zeros((1, bucket), np.int64)
+        padded = np.zeros((1, _bucket(s, self.max_len)), np.int64)
         padded[0, :s] = tokens
-        return self._prefill_jit[bucket](self.params, jnp.asarray(padded),
-                                         jnp.asarray(s - 1, jnp.int32))
+        return self._prefill(self.weights, jnp.asarray(padded),
+                             jnp.asarray(s - 1, jnp.int32))
 
     def insert(self, cache, row_cache, slot: int, pos: int):
         return self._insert(cache, row_cache, jnp.asarray(slot, jnp.int32),
                             jnp.asarray(pos, jnp.int32))
 
     def step(self, cache, tokens):
-        if self.cfg.experts_held:
-            live = cache.get("live")
-            if live is None:
-                live = jnp.ones(cache["pos"].shape, bool)
-            return self._step(self.params, cache["attn"], cache["pos"],
-                              tokens, cache["route"], live)
-        return self._step(self.params, cache["attn"], cache["pos"], tokens)
+        state = {k: v for k, v in cache.items() if k not in ("attn", "pos")}
+        return self._step(self.weights, cache["attn"], cache["pos"], tokens,
+                          state)
 
 
-class PrunedServeModel:
-    """Engine adapter for a ZipLM-shrunk :class:`PrunedModel`."""
+def _check_servable(cfg, kinds):
+    """Refuse a configuration other than a causal text decoder whose
+    layers' attention is of ``kinds``."""
+    if (not cfg.causal or cfg.frontend != "none"
+            or not set(cfg.attention_period) <= set(kinds)):
+        raise NotImplementedError(
+            "serving engine covers causal text decoders of "
+            f"{' and '.join(kinds)} attention")
+    _check_decodable(cfg)
+
+
+class DenseServeModel(ServeModel):
+    """A :class:`ServeModel` of stock (unpruned) params.
+
+    Each layer's attention is of its kind in ``cfg.attention_period``
+    (full, or a ring of the window); the slot cache holds one K/V stack per
+    kind. With held experts (``cfg.experts_held``) the slot cache also
+    carries the routing counter ``route`` that ``transformer.decode_step``
+    adds to, over the slots the engine marks ``live``.
+    """
+
+    def __init__(self, cfg, params, max_len: int):
+        _check_servable(cfg, ("full", "sliding_window"))
+
+        def prefill(p, tokens, last):
+            out = forward(cfg, p, tokens, mode="prefill")
+            return out["logits"], model_mod.assemble_prefill_cache(
+                cfg, out, 1, last + 1, max_len)
+
+        def init_slots(nslots: int):
+            cache = init_cache(cfg, nslots, max_len, per_slot=True)
+            if cfg.experts_held:
+                cache["route"] = jnp.zeros((3,), jnp.int32)
+            return cache
+
+        super().__init__(cfg, params, max_len,
+                         decode=functools.partial(decode_step, cfg),
+                         prefill=prefill, init_slots=init_slots, slot_axis=1)
+
+
+class PrunedServeModel(ServeModel):
+    """A :class:`ServeModel` of a ZipLM-shrunk :class:`PrunedModel`; its
+    weights are ``pm.weights()``, the structure rebuilt around them inside
+    each executable."""
 
     def __init__(self, pm: PrunedModel, max_len: int):
-        cfg = pm.cfg
-        if (not cfg.causal or cfg.attention != "full"
-                or cfg.frontend != "none"):
-            raise NotImplementedError(
-                "serving engine covers causal full-attention text decoders")
-        _check_decodable(cfg)
-        self.pm, self.cfg, self.max_len = pm, cfg, max_len
-        # jit over (layer params, globals, cache, tokens) pytrees; the
-        # static layer structure is rebuilt inside from host metadata so
-        # params are arguments, not baked-in constants
-        meta = [(l.kv_groups, l.d_ff, l.ssm_heads, tuple(l.expert_ff))
-                for l in pm.layers]
+        _check_servable(pm.cfg, ("full",))
 
-        def rebuild(lps, globals_):
-            layers = [PrunedLayer(kv_groups=m[0], d_ff=m[1], ssm_heads=m[2],
-                                  expert_ff=list(m[3]), params=lp)
-                      for m, lp in zip(meta, lps)]
-            return PrunedModel(cfg=cfg, layers=layers, globals_=globals_)
+        def decode(w, cache, tokens):
+            return decode_step_pruned(pm.with_weights(w), cache, tokens)
 
-        # named as in DenseServeModel
-        def serve_decode(lps, globals_, kv, pos, toks):
-            return decode_step_pruned(rebuild(lps, globals_),
-                                      {"pos": pos, "attn": kv}, toks)
+        def prefill(w, tokens, last):
+            return prefill_pruned(pm.with_weights(w), tokens, max_len,
+                                  full_logits=True)
 
-        def serve_prefill(lps, globals_, toks, last):
-            logits, cache = prefill_pruned(rebuild(lps, globals_), toks,
-                                           max_len, full_logits=True)
-            logits = jax.lax.dynamic_slice_in_dim(logits, last, 1, axis=1)
-            return logits, cache
-
-        self._lps = [l.params for l in pm.layers]
-        self._globals = pm.globals_
-        def serve_insert(cache, row, slot, pos):
-            attn = []
-            for buf, rbuf in zip(cache["attn"], row["attn"]):
-                if buf is None:
-                    attn.append(None)
-                else:
-                    attn.append({"k": buf["k"].at[slot].set(rbuf["k"][0]),
-                                 "v": buf["v"].at[slot].set(rbuf["v"][0])})
-            return {"pos": cache["pos"].at[slot].set(pos), "attn": attn}
-
-        self._step = jax.jit(serve_decode,
-                             donate_argnums=(2,) if _donate_kv() else ())
-        self._prefill_jit: Dict[int, Callable] = {}
-        self._prefill_fn = serve_prefill
-        self._insert = jax.jit(serve_insert)
-
-    def init_slots(self, nslots: int):
-        return init_cache_pruned(self.pm, nslots, self.max_len,
-                                 per_slot=True)
-
-    def prefill(self, tokens: np.ndarray):
-        s = int(tokens.shape[0])
-        bucket = _bucket(s, self.max_len)
-        if bucket not in self._prefill_jit:
-            self._prefill_jit[bucket] = jax.jit(self._prefill_fn)
-        padded = np.zeros((1, bucket), np.int64)
-        padded[0, :s] = tokens
-        return self._prefill_jit[bucket](self._lps, self._globals,
-                                         jnp.asarray(padded),
-                                         jnp.asarray(s - 1, jnp.int32))
-
-    def insert(self, cache, row_cache, slot: int, pos: int):
-        return self._insert(cache, row_cache, jnp.asarray(slot, jnp.int32),
-                            jnp.asarray(pos, jnp.int32))
-
-    def step(self, cache, tokens):
-        return self._step(self._lps, self._globals, cache["attn"],
-                          cache["pos"], tokens)
+        super().__init__(pm.cfg, pm.weights(), max_len, decode=decode,
+                         prefill=prefill, slot_axis=0,
+                         init_slots=lambda nslots: init_cache_pruned(
+                             pm, nslots, max_len, per_slot=True))
 
 
 @dataclass
@@ -418,13 +392,23 @@ class ServeEngine:
         for s in prompt_lens:
             s = min(int(s), self.max_len - 1)
             logits, row = self.model.prefill(np.zeros((s,), np.int64))
-            cache = self.model.insert(self.cache, row, 0, s)
+            cache = self._live(self.model.insert(self.cache, row, 0, s), [0])
             toks = jnp.zeros((self.num_slots, 1), jnp.int32)
             # sync: warmup barrier — wait for each bucket's compile
             jax.block_until_ready(_sample(self.model.step(cache, toks)[0]))
         # warmup state is discarded; self.cache was never mutated (the
         # insert does not donate, so a step consumes only the insert's
         # output)
+
+    def _live(self, cache, active_slots):
+        """``cache`` with the mask ``live`` of the active slots, whose
+        tokens the routing counter counts, where the model counts its
+        routing (``route``)."""
+        if "route" not in cache:
+            return cache
+        live = np.zeros(self.num_slots, bool)
+        live[active_slots] = True
+        return dict(cache, live=jnp.asarray(live))
 
     # ------------------------------------------------------------------
     # fault-handled decode step (site: serve.step)
@@ -457,11 +441,7 @@ class ServeEngine:
                 continue
             cache = self.cache
             with _span("serve.dispatch"):
-                if "route" in cache:
-                    # the routing counter counts the active slots' tokens
-                    live = np.zeros(self.num_slots, bool)
-                    live[active_slots] = True
-                    cache = dict(cache, live=jnp.asarray(live))
+                cache = self._live(cache, active_slots)
                 toks = jnp.asarray(tokens.reshape(-1, 1), jnp.int32)
                 logits, new_cache = self.model.step(cache, toks)
                 if mult != 1.0:

@@ -172,6 +172,65 @@ def test_pruned_engine_matches_sequential_decode(tiny_cfg, tiny_params,
         assert rec.tokens == toks, f"rid={req.rid}"
 
 
+def _identity_member(cfg, params):
+    """A ``PrunedModel`` that removes nothing: each layer's params sliced
+    from the dense stack."""
+    from repro.models.pruned import PrunedLayer, PrunedModel
+    layers = [PrunedLayer(kv_groups=cfg.num_kv_heads, d_ff=cfg.d_ff,
+                          params=jax.tree.map(lambda a: a[i],
+                                              params["layers"]))
+              for i in range(cfg.num_layers)]
+    return PrunedModel(cfg, layers, {k: v for k, v in params.items()
+                                     if k != "layers"})
+
+
+def _yarn_decoder():
+    from repro.configs import smoke_config
+    from repro.configs.base import Yarn
+    from repro.models import model_init
+    cfg = smoke_config("qwen2-72b").replace(
+        dtype="float32", full_rope_scaling=Yarn(
+            factor=4.0, original_max_position=16, attention_factor=1.3))
+    assert cfg.attention == "full" and cfg.pos_emb == "rope"
+    assert cfg.resolved_head_dim == 32
+    return cfg, model_init(cfg, jax.random.key(1))[0]
+
+
+@pytest.mark.parametrize("model", ["gpt2", "yarn"])
+def test_identity_member_serves_the_dense_tokens(tiny_cfg, tiny_params,
+                                                 model):
+    """A member that removes nothing, served by ``PrunedServeModel``, gives
+    the tokens ``DenseServeModel`` gives on the same backlog, and the same
+    prefill and step logits to float32 rounding: under YaRN on full layers
+    too, where the cached prompt keys must be rotated (and scaled by its
+    attention factor) as the keys decoded against them are."""
+    cfg, params = ((tiny_cfg, tiny_params) if model == "gpt2"
+                   else _yarn_decoder())
+    dense = DenseServeModel(cfg, params, MAX_LEN)
+    member = PrunedServeModel(_identity_member(cfg, params), MAX_LEN)
+    reqs = _requests(cfg, n=4, seed=19)
+    served = []
+    for m in (dense, member):
+        eng = ServeEngine(m, num_slots=2)
+        eng.warmup((8, 16))
+        served.append([r.tokens for r in eng.run(reqs).records])
+    assert served[0] == served[1]
+    for req, toks in zip(reqs, served[0]):
+        caches = []
+        for m in (dense, member):
+            logits, row = m.prefill(req.tokens)
+            caches.append([m.insert(m.init_slots(2), row, 1,
+                                    req.prompt_len), logits])
+        np.testing.assert_allclose(caches[1][1], caches[0][1], rtol=1e-5,
+                                   atol=1e-5)
+        for tok in toks[:-1]:
+            feed = jnp.zeros((2, 1), jnp.int32).at[1, 0].set(tok)
+            for c, m in zip(caches, (dense, member)):
+                c[1], c[0] = m.step(c[0], feed)
+            np.testing.assert_allclose(caches[1][1][1], caches[0][1][1],
+                                       rtol=1e-5, atol=1e-5)
+
+
 def test_pruned_cache_bytes_match_shrunk_structure(tiny_cfg, tiny_params,
                                                    mag_db, dense_engine):
     a = _half_heads_assignment(tiny_cfg, mag_db)
@@ -334,17 +393,15 @@ def test_executables_have_stable_names(tiny_cfg, tiny_params, mag_db,
     pm = shrink(tiny_cfg, tiny_params, mag_db,
                 _half_heads_assignment(tiny_cfg, mag_db))
     pruned = ServeEngine(PrunedServeModel(pm, MAX_LEN), num_slots=2)
-    for eng, weights in ((dense_engine, (dense_engine.model.params,)),
-                         (pruned, (pruned.model._lps,
-                                   pruned.model._globals))):
+    for eng in (dense_engine, pruned):
         model = eng.model
         _, row = model.prefill(np.zeros((5,), np.int64))
         i32 = jnp.asarray(0, jnp.int32)
         toks = jnp.zeros((2, 1), jnp.int32)
         padded = jnp.zeros((1, 8), jnp.int32)
-        assert module(model._step, *weights, eng.cache["attn"],
-                      eng.cache["pos"], toks) == "@jit_serve_decode"
-        assert module(model._prefill_jit[8], *weights, padded, i32) \
+        assert module(model._step, model.weights, eng.cache["attn"],
+                      eng.cache["pos"], toks, {}) == "@jit_serve_decode"
+        assert module(model._prefill, model.weights, padded, i32) \
             == "@jit_serve_prefill"
         assert module(model._insert, eng.cache, row, i32, i32) \
             == "@jit_serve_insert"

@@ -122,8 +122,7 @@ def _dense_decode(cfg):
     from repro.serve.engine import DenseServeModel
     params = jax.eval_shape(lambda k: model_init(cfg, k)[0],
                             jax.random.key(0))
-    model = DenseServeModel(cfg, params, SLOT_LEN)
-    return model, (params,)
+    return DenseServeModel(cfg, params, SLOT_LEN)
 
 
 def _member_decode(cfg, heads):
@@ -148,8 +147,7 @@ def _member_decode(cfg, heads):
     lps, globals_ = jax.eval_shape(member, jax.random.key(0))
     pm = PrunedModel(cfg, [PrunedLayer(kv_groups=h, d_ff=cfg.d_ff, params=lp)
                            for h, lp in zip(heads, lps)], globals_)
-    model = PrunedServeModel(pm, SLOT_LEN)
-    return model, (model._lps, model._globals)
+    return PrunedServeModel(pm, SLOT_LEN)
 
 
 @pytest.mark.parametrize("adapter", ["dense", "member"])
@@ -166,13 +164,13 @@ def test_serve_decode_writes_kv_in_place(one_chip, monkeypatch, adapter):
     monkeypatch.setattr(engine, "_donate_kv", lambda: True)
     if adapter == "dense":
         cfg = GPT2_SMALL.replace(num_layers=2)
-        model, weights = _dense_decode(cfg)
+        model = _dense_decode(cfg)
     else:
         cfg = GPT2_SMALL.replace(num_layers=3)
-        model, weights = _member_decode(cfg, (12, 2, 1))
+        model = _member_decode(cfg, (12, 2, 1))
     cache = jax.eval_shape(lambda: model.init_slots(SLOTS))
-    args = _on(one_chip, (*weights, cache["attn"], cache["pos"],
-                          jax.ShapeDtypeStruct((SLOTS, 1), jnp.int32)))
+    args = _on(one_chip, (model.weights, cache["attn"], cache["pos"],
+                          jax.ShapeDtypeStruct((SLOTS, 1), jnp.int32), {}))
     _assert_kv_in_place(model._step.lower(*args).compile(), cache["attn"])
 
 
@@ -219,8 +217,8 @@ def test_mellum_decode_writes_kv_in_place(one_chip, monkeypatch):
     cache = jax.eval_shape(lambda: model.init_slots(32))
     assert [x["k"].shape for x in cache["attn"]] == [
         (1, 32, 4, n, 128) for n in (1024, 1024, 1024, 4096)]
-    args = _on(one_chip, (params, cache["attn"], cache["pos"],
+    args = _on(one_chip, (model.weights, cache["attn"], cache["pos"],
                           jax.ShapeDtypeStruct((32, 1), jnp.int32),
-                          cache["route"],
-                          jax.ShapeDtypeStruct((32,), jnp.bool_)))
+                          {"route": cache["route"],
+                           "live": jax.ShapeDtypeStruct((32,), jnp.bool_)}))
     _assert_kv_in_place(model._step.lower(*args).compile(), cache["attn"])
